@@ -350,6 +350,9 @@ def main(argv=None):
                     help="skip the per-row planlint audit (one extra compile "
                          "per --compare row)")
     args = ap.parse_args(argv)
+    from repro.core.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     shape = tuple(int(s) for s in args.shape.split(","))
     if args.transforms and args.real:

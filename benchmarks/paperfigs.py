@@ -63,6 +63,9 @@ SIZES = {
 def run_point(shape, grid, method, ndev, *, real=True, measure="total",
               outer=None, inner=3):
     env = dict(os.environ)
+    # CPU-only sweep: the child never competes for an accelerator, and
+    # its record honestly says "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
     env["PYTHONPATH"] = str(REPO / "src") + os.pathsep + str(REPO)
     cmd = [sys.executable, "-m", "benchmarks.fftbench",

@@ -125,6 +125,9 @@ def run_point(shape, ndev: int, *, grid: str, method: str, measure: str,
               tune_cache: str | None = None, timeout: int = 1800) -> dict:
     """One fftbench worker subprocess at ``ndev`` virtual host devices."""
     env = dict(os.environ)
+    # CPU-only sweep: the child never competes for an accelerator, and
+    # its record honestly says "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
     env["PYTHONPATH"] = (str(REPO / "src") + os.pathsep + str(REPO)
                          + (os.pathsep + env["PYTHONPATH"]

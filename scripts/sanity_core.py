@@ -61,7 +61,9 @@ x1 = (rng.standard_normal((4, 96)) + 1j * rng.standard_normal((4, 96))).astype(n
 np.testing.assert_allclose(np.asarray(fops.fft_matmul(jnp.asarray(x1))), np.fft.fft(x1, axis=-1), rtol=2e-4, atol=2e-3)
 x2 = rng.standard_normal((4, 384)).astype(np.float32)
 np.testing.assert_allclose(np.asarray(fops.rfft_matmul(jnp.asarray(x2))), np.fft.rfft(x2, axis=-1), rtol=2e-4, atol=2e-2)
-np.testing.assert_allclose(np.asarray(fops.irfft_matmul(jnp.asarray(np.fft.rfft(x2)), n=384)), x2, rtol=2e-4, atol=2e-3)
+from repro.core.fftcore import BACKWARD, TransformSpec, local_transform
+back = local_transform(jnp.asarray(np.fft.rfft(x2)), 1, BACKWARD, TransformSpec.r2c(), n=384, impl="matmul")
+np.testing.assert_allclose(np.asarray(back), x2, rtol=2e-4, atol=2e-3)
 print("fft kernels ok")
 
 from repro.kernels.transpose.ops import transpose01

@@ -342,9 +342,11 @@ def _expected_contract(plan, direction: str, schedule4, nfields: int) -> dict:
                   if method == "pipelined" else 1)
         per_field_launches = slices * (2 if comm_dtype == "int8" else 1)
         # a pallas stage emits one encode + one decode kernel per
-        # payload collective side-pair (per slice for pipelined)
+        # payload collective side-pair (per slice for pipelined), plus
+        # int8's max-abs scale pass before the encode
         fused_kernel = impl == "pallas" and pallas_applicable(method, comm_dtype)
-        per_field_pcalls = 2 * slices if fused_kernel else 0
+        kernels = 3 if comm_dtype == "int8" else 2
+        per_field_pcalls = kernels * slices if fused_kernel else 0
         if nbatch and fusion != "stacked":
             launches = per_field_launches * nfields
             pcalls = per_field_pcalls * nfields

@@ -41,6 +41,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 import jax.numpy as jnp
+from jax import lax
 
 FORWARD = -1
 BACKWARD = +1
@@ -224,13 +225,13 @@ def _rfft(x, axis, impl):
 
 
 def _irfft(x, axis, n, impl):
-    if impl == "jnp":
-        return jnp.fft.irfft(x, n=n, axis=axis)
-    if impl == "matmul":
-        from repro.kernels.fft import ops as fft_ops
-
-        return fft_ops.irfft_matmul(x, n=n, axis=axis)
-    raise ValueError(f"unknown fft impl {impl!r}")
+    """c2r: the real part of the inverse complex DFT of ``x``'s Hermitian
+    extension to ``n`` bins (the imaginary parts of the DC and Nyquist
+    bins drop out, as in ``numpy.fft.irfft``).  XLA's own IRFFT is not
+    used: on a TPU v5e it returned a (384, 384, 193) -> n=384 c2r along
+    the last axis at relative L2 error 0.35, where this form gives 1.3e-7."""
+    tail = jnp.flip(jnp.conj(lax.slice_in_dim(x, 1, n - n // 2, axis=axis)), axis)
+    return jnp.real(_fft(jnp.concatenate([x, tail], axis=axis), axis, BACKWARD, impl))
 
 
 # -- pruning (truncated spectra / 3/2-rule dealiasing) ----------------------

@@ -1,50 +1,22 @@
-"""Mesh + shard_map compat helpers, version-adaptive across jax 0.4.x–0.8.x.
+"""Mesh + shard_map helpers: the one place the repo touches these jax APIs.
 
-Every ``shard_map`` / ``make_mesh`` / axis-size call in the repo routes
-through this module so the rest of the codebase can be written against one
-API surface:
-
-* ``jax.shard_map`` (0.8.x) vs ``jax.experimental.shard_map.shard_map``
-  (0.4.x–0.7.x) — resolved at import time.
-* ``check_vma`` (0.8.x) vs ``check_rep`` (older) — translated, or dropped
-  when the installed shard_map understands neither keyword.
-* ``jax.sharding.AxisType`` and the ``axis_types=`` kwarg of
-  ``jax.make_mesh`` — only passed when the installed jax has them.
-* ``lax.axis_size`` (0.6+) — falls back to ``lax.psum(1, axis)``, which
-  constant-folds to a static int for a concrete operand.
+Every ``shard_map`` / ``make_mesh`` / ambient-mesh / axis-size call in the
+repo routes through this module, so a jax API change lands here and
+nowhere else.  Written for the jax pinned in ``requirements.txt``.
 """
 
 from __future__ import annotations
 
-import inspect
-
 import jax
 from jax import lax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.6: explicit/auto/manual mesh axis types
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x/0.5.x: meshes have no axis types
-    AxisType = None
-
-if hasattr(jax, "shard_map"):  # jax >= 0.8 top-level export
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
+from jax.sharding import AxisType, Mesh
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """``shard_map`` with the replication-check kwarg translated per version
-    (``check_vma`` on 0.8.x, ``check_rep`` before, dropped if unknown)."""
-    kwargs = {}
-    if check_vma is not None:
-        if "check_vma" in _SHARD_MAP_PARAMS:
-            kwargs["check_vma"] = check_vma
-        elif "check_rep" in _SHARD_MAP_PARAMS:
-            kwargs["check_rep"] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
+    """``jax.shard_map``; ``check_vma=None`` keeps jax's default."""
+    kwargs = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         **kwargs)
 
 
 def balanced_dims(ndev: int) -> tuple[int, int]:
@@ -56,25 +28,19 @@ def balanced_dims(ndev: int) -> tuple[int, int]:
     return a, ndev // a
 
 
-def make_mesh(shape: tuple[int, ...], axis_names: tuple[str, ...]) -> Mesh:
-    """``jax.make_mesh`` with explicit Auto axis types where supported
-    (stable across 0.8→0.9); plain mesh on jax < 0.6."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axis_names, axis_types=(AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(shape, axis_names)
+def make_mesh(shape: tuple[int, ...], axis_names: tuple[str, ...], *,
+              devices=None) -> Mesh:
+    """``jax.make_mesh`` with explicit Auto axis types, over ``devices``
+    (default: all of ``jax.devices()``)."""
+    return jax.make_mesh(shape, axis_names, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def set_mesh(mesh: Mesh):
-    """Context manager installing ``mesh`` as the ambient mesh:
-    ``jax.set_mesh`` on 0.8.x; on older jax the Mesh object is itself the
-    context manager."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager installing ``mesh`` as the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
 def axis_size(axis_name) -> int:
     """Static size of a named mesh axis (or tuple of axes) inside shard_map."""
-    if hasattr(lax, "axis_size"):
-        return int(lax.axis_size(axis_name))
-    return int(lax.psum(1, axis_name))
+    return int(lax.axis_size(axis_name))

@@ -315,7 +315,7 @@ def exchange_shard(
                 sx = lax.all_to_all(scale, axis_name, split_axis=0,
                                     concat_axis=0, tiled=True)
                 sx = _faults.tap_wire(sx, "scale")
-            out = _xk.unpack_chunks(y, v=v, w=w, m=m, nbatch=nbatch,
+            out = _xk.unpack_chunks(y, w=w, m=m, nbatch=nbatch,
                                     scale=sx, codec=d,
                                     iscomplex=jnp.iscomplexobj(block))
             return (out, stats) if guard else out

@@ -361,10 +361,16 @@ def quarantine(path, key: str, reason: str) -> int:
         entry["bad"] = {"reason": reason}
         entry["quarantines"] = int(entry.get("quarantines", 0)) + 1
         save_cache(path, {key: entry}, lock=False)
+    forget(key)
+    return entry["quarantines"]
+
+
+def forget(key: str) -> None:
+    """Drop this process's memos for the plan ``key`` (and the stage-timing
+    memo), so the next schedule resolve reads the disk cache again."""
     for k in [k for k in _MEMO if k.endswith("|" + key)]:
         del _MEMO[k]
     _STAGE_MEMO.clear()
-    return entry["quarantines"]
 
 
 def _parse_entry(entry, n_exchanges: int, candidates=None):

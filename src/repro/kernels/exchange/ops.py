@@ -15,8 +15,8 @@ traditional engine (payload is chunk-major, paper Eqs. 15-17):
     :func:`unpack_chunks`   — inverse scatter fused with dequantize: the
                               unpack realignment costs no extra HBM pass.
 
-Every wrapper reshapes its operand to the kernels' canonical
-``(P, F, A, M, B, R)`` view — stride-only, free — and reshapes the result
+Every wrapper collapses its operand to the kernels' canonical
+``(P, F, A, M, Z)`` view — stride-only, free — and reshapes the result
 back.  Complex blocks travel as a leading (re, im) plane pair built by the
 module-local :func:`_to_planes` / :func:`_from_planes` (same math as
 :mod:`repro.core.quant`'s helpers, duplicated *here* so planlint's source
@@ -75,23 +75,37 @@ def _from_planes(p: jax.Array, iscomplex: bool) -> jax.Array:
 
 
 def _stats_dict(st: jax.Array | None) -> dict | None:
-    """Per-(field, chunk) kernel counters -> the executor's stats dict
-    (summed host-of-shard side, matching health.payload_stats' shape)."""
+    """The kernel's whole-grid ``(nonfinite, saturated)`` counters -> the
+    executor's stats dict (health.payload_stats' shape)."""
     if st is None:
         return None
-    return {"nonfinite": jnp.sum(st[..., 0]), "saturated": jnp.sum(st[..., 1])}
+    return {"nonfinite": st[0], "saturated": st[1]}
 
 
 def _payload_view(shape: tuple[int, ...], axis: int, m: int,
                   nbatch: int) -> tuple[int, ...]:
     """Collapse a planes shape ``(P, *s)`` around split/concat axis ``axis``
-    (block coords) into the canonical ``(P, F, A, M, B, R)``."""
+    (block coords) into the canonical ``(P, F, A, M, Z)``: ``Z`` is one
+    chunk's contiguous run (its slice of ``axis`` times the trailing axes)."""
     P, s = shape[0], shape[1:]
     n = s[axis]
     if n % m != 0:
         raise ValueError(f"axis extent {n} not divisible by group size {m}")
-    return (P, _prod(s[:nbatch]), _prod(s[nbatch:axis]), m, n // m,
-            _prod(s[axis + 1:]))
+    return (P, _prod(s[:nbatch]), _prod(s[nbatch:axis]), m,
+            n // m * _prod(s[axis + 1:]))
+
+
+def _encode(planes, view, *, codec, chunk_major, guard, scale_div, interpret):
+    """Run the encode kernels: ``(payload, flat scales | None, stats)``."""
+    scale = ()
+    if codec == "int8":
+        scale = (_k.scale_pallas_call(view, chunk_major=chunk_major,
+                                      scale_div=scale_div,
+                                      interpret=interpret)(planes),)
+    call = _k.encode_pallas_call(view, codec=codec, chunk_major=chunk_major,
+                                 guard=guard, interpret=interpret)
+    outs = call(planes, *scale)
+    return outs[0], (scale[0] if scale else None), _stats_dict(outs[1] if guard else None)
 
 
 # ---------------------------------------------------------------------------
@@ -112,13 +126,12 @@ def encode_payload(y: jax.Array, *, axis: int, m: int, nbatch: int = 0,
     if interpret is None:
         interpret = _interpret_default()
     planes = _to_planes(y)
-    view = _payload_view(planes.shape, axis, m, nbatch)
-    call = _k.encode_pallas_call(view, codec=codec, pack=False, guard=guard,
-                                 scale_div=scale_div, interpret=interpret)
-    outs = call(planes.reshape(view))
-    q, rest = outs[0], list(outs[1:])
-    scale = rest.pop(0) if codec == "int8" else None
-    stats = _stats_dict(rest.pop(0) if guard else None)
+    P, F, A, M, Z = view = _payload_view(planes.shape, axis, m, nbatch)
+    q, scale, stats = _encode(planes, view, codec=codec, chunk_major=False,
+                              guard=guard, scale_div=scale_div,
+                              interpret=interpret)
+    if scale is not None:
+        scale = scale.reshape(F, M)
     return q.reshape(planes.shape), scale, stats
 
 
@@ -133,9 +146,9 @@ def decode_payload(p: jax.Array, *, axis: int, m: int, nbatch: int = 0,
     if interpret is None:
         interpret = _interpret_default()
     view = _payload_view(p.shape, axis, m, nbatch)
-    call = _k.decode_pallas_call(view, codec=codec, interpret=interpret)
-    args = (p.reshape(view),) if codec != "int8" else (p.reshape(view), scale)
-    (out,) = call(*args)
+    call = _k.decode_pallas_call(view, codec=codec, chunk_major=False,
+                                 interpret=interpret)
+    out = call(p, *(() if scale is None else (scale.reshape(-1),)))
     return _from_planes(out.reshape(p.shape), iscomplex)
 
 
@@ -156,48 +169,37 @@ def pack_chunks(y: jax.Array, *, axis: int, m: int, nbatch: int = 0,
     if interpret is None:
         interpret = _interpret_default()
     planes = _to_planes(y)
-    P, F, A, M, B, R = view = _payload_view(planes.shape, axis, m, nbatch)
-    call = _k.encode_pallas_call(view, codec=codec, pack=True, guard=guard,
-                                 scale_div=scale_div, interpret=interpret)
-    outs = call(planes.reshape(view))
-    q, rest = outs[0], list(outs[1:])
-    scale = rest.pop(0) if codec == "int8" else None
-    stats = _stats_dict(rest.pop(0) if guard else None)
+    P, F, A, M, Z = view = _payload_view(planes.shape, axis, m, nbatch)
+    q, scale, stats = _encode(planes, view, codec=codec, chunk_major=True,
+                              guard=guard, scale_div=scale_div,
+                              interpret=interpret)
+    if scale is not None:
+        scale = scale.reshape(M, F)
     s = list(planes.shape[1:])
-    s[axis] = B
+    s[axis] //= M
     return q.reshape((M, P, *s)), scale, stats
 
 
-def unpack_chunks(p: jax.Array, *, v: int, w: int, m: int, nbatch: int = 0,
+def unpack_chunks(p: jax.Array, *, w: int, m: int, nbatch: int = 0,
                   scale: jax.Array | None, codec: str, iscomplex: bool,
                   interpret: bool | None = None) -> jax.Array:
     """Inverse of :func:`pack_chunks` for the received chunk-major payload:
     scatter chunk ``j`` into w-slot ``j`` (chunk-major == global w order,
     the Eq. 17 realignment) fused with dequantize/widen, and rebuild the
-    block — w axis full, v axis holding this rank's shard.  ``v``/``w``
-    are block coords of the inner shape ``p.shape[2:]``."""
+    block — w axis full, the v axis holding this rank's shard.  ``w`` is
+    a block coord of the inner shape ``p.shape[2:]``."""
     if interpret is None:
         interpret = _interpret_default()
     M, P = p.shape[0], p.shape[1]
     s = p.shape[2:]
-    bv, bw = v + nbatch, w + nbatch
+    bw = w + nbatch
     F = _prod(s[:nbatch])
-    if bw < bv:
-        a1, wl = _prod(s[nbatch:bw]), s[bw]
-        a2, b, r = _prod(s[bw + 1:bv]), s[bv], _prod(s[bv + 1:])
-        in_view = (M, P, F, a1, wl, a2, b, r)
-        out_view = (P, F, a1, M, wl, a2, b, r)
-        m_out = 3
-    else:
-        a1, b = _prod(s[nbatch:bv]), s[bv]
-        a2, wl, r = _prod(s[bv + 1:bw]), s[bw], _prod(s[bw + 1:])
-        in_view = (M, P, F, a1, b, a2, wl, r)
-        out_view = (P, F, a1, b, a2, M, wl, r)
-        m_out = 5
-    call = _k.unpack_decode_pallas_call(in_view, out_view, m_out=m_out,
-                                        codec=codec, interpret=interpret)
-    args = (p.reshape(in_view),) if codec != "int8" else (p.reshape(in_view), scale)
-    (out,) = call(*args)
+    # chunk j lands just before the w axis (chunk-major == global w order):
+    # (M, P, F, A, Z) -> (P, F, A, M, Z) with A everything before w
+    view = (P, F, _prod(s[nbatch:bw]), M, _prod(s[bw:]))
+    call = _k.decode_pallas_call(view, codec=codec, chunk_major=True,
+                                 interpret=interpret)
+    out = call(p, *(() if scale is None else (scale.reshape(-1),)))
     final = list(s)
     final[bw] = M * s[bw]
     return _from_planes(out.reshape((P, *final)), iscomplex)

@@ -1,13 +1,16 @@
 """Jit'd wrappers for the four-step matmul DFT Pallas kernel.
 
 ``fft_matmul(x, axis, inverse)``   — complex-to-complex, any axis.
-``rfft_matmul(x, axis)``           — real input, Hermitian-reduced output.
-``irfft_matmul(x, n, axis)``       — inverse of the above.
+``rfft_matmul(x, axis)``           — real input, Hermitian-reduced output
+                                     (its inverse is ``fftcore``'s c2r, a
+                                     Hermitian extension + inverse
+                                     ``fft_matmul``).
 
 Factorization policy (``plan_factors``): N = n1·n2 with n1 ≥ n2, both as
 close to √N (and MXU-friendly multiples of 8/128) as possible; prime or tiny
 N degenerates to a single (N,N) DFT matmul.  Inverse transforms use
 ifft(x) = conj(fft(conj(x)))/N so one kernel serves both directions.
+Every DFT matmul here runs at ``Precision.HIGHEST``.
 """
 
 from __future__ import annotations
@@ -22,7 +25,6 @@ import numpy as np
 from repro.kernels.fft import ref
 from repro.kernels.fft.kernel import fourstep_pallas_call
 
-_DEFAULT_BLOCK_B = 8
 _SINGLE_MATMUL_MAX = 256  # below this, one (N,N) DFT matmul beats two steps
 
 
@@ -49,10 +51,14 @@ def fft_matmul(
     axis: int = -1,
     inverse: bool = False,
     karatsuba: bool = True,
-    block_b: int = _DEFAULT_BLOCK_B,
+    block_b: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Complex 1-D DFT along ``axis`` via the four-step Pallas kernel."""
+    """Complex 1-D DFT along ``axis`` via the four-step Pallas kernel.
+
+    ``block_b`` is the number of transforms per grid step (the kernel's
+    lane tile; default :func:`tile_width`).  On the chip it must be a
+    multiple of 128 unless it covers the whole batch."""
     x = jnp.asarray(x, jnp.complex64)
     axis = axis % x.ndim
     n = x.shape[axis]
@@ -60,46 +66,23 @@ def fft_matmul(
         y = fft_matmul(jnp.conj(x), axis=axis, inverse=False, karatsuba=karatsuba,
                        block_b=block_b, interpret=interpret)
         return jnp.conj(y) / n
-    xr, xi = jnp.real(x), jnp.imag(x)
-    yr, yi = _fourstep_lastaxis_real(
-        _to_last(xr, axis), _to_last(xi, axis), n,
-        karatsuba=karatsuba, block_b=block_b, interpret=interpret, real_input=False,
-    )
-    return _from_last(jax.lax.complex(yr, yi), axis)
+    yr, yi = _fourstep(jnp.real(x), jnp.imag(x), axis, n, karatsuba=karatsuba,
+                       block_b=block_b, interpret=interpret)
+    return jax.lax.complex(yr, yi)
 
 
 @functools.partial(jax.jit, static_argnames=("axis", "karatsuba", "block_b", "interpret"))
 def rfft_matmul(
     x: jax.Array, *, axis: int = -1, karatsuba: bool = True,
-    block_b: int = _DEFAULT_BLOCK_B, interpret: bool | None = None,
+    block_b: int | None = None, interpret: bool | None = None,
 ) -> jax.Array:
     """Real-input DFT; returns the n//2+1 non-redundant bins (rfft)."""
     x = jnp.asarray(x, jnp.float32)
     axis = axis % x.ndim
     n = x.shape[axis]
-    yr, yi = _fourstep_lastaxis_real(
-        _to_last(x, axis), None, n,
-        karatsuba=karatsuba, block_b=block_b, interpret=interpret, real_input=True,
-    )
-    y = jax.lax.complex(yr, yi)[..., : n // 2 + 1]
-    return _from_last(y, axis)
-
-
-@functools.partial(jax.jit, static_argnames=("n", "axis", "karatsuba", "block_b", "interpret"))
-def irfft_matmul(
-    x: jax.Array, *, n: int, axis: int = -1, karatsuba: bool = True,
-    block_b: int = _DEFAULT_BLOCK_B, interpret: bool | None = None,
-) -> jax.Array:
-    """Inverse of rfft_matmul: Hermitian-extend, full iDFT, take real part."""
-    x = jnp.asarray(x, jnp.complex64)
-    axis = axis % x.ndim
-    xl = _to_last(x, axis)
-    # Hermitian extension of the reduced spectrum back to length n.
-    tail = jnp.conj(xl[..., 1 : n - n // 2])[..., ::-1]
-    full = jnp.concatenate([xl, tail], axis=-1)
-    y = fft_matmul(full, axis=-1, inverse=True, karatsuba=karatsuba,
-                   block_b=block_b, interpret=interpret)
-    return _from_last(jnp.real(y), axis)
+    yr, yi = _fourstep(x, None, axis, n // 2 + 1, karatsuba=karatsuba,
+                       block_b=block_b, interpret=interpret)
+    return jax.lax.complex(yr, yi)
 
 
 @functools.partial(jax.jit, static_argnames=("axis", "trig_type"))
@@ -138,46 +121,54 @@ def _trig_matmul(x, axis, mat):
 # ---------------------------------------------------------------------------
 
 
-def _to_last(x, axis):
-    return jnp.moveaxis(x, axis, -1)
+def tile_width(n: int, nbatch: int) -> int:
+    """Transforms per grid step: a multiple of 128 lanes keeping one f32
+    plane tile near 512 KiB, or the whole batch when it is smaller."""
+    tile = 128
+    while tile * 2 * n <= 131072 and tile * 2 <= nbatch:
+        tile *= 2
+    return min(tile, nbatch)
 
 
-def _from_last(y, axis):
-    return jnp.moveaxis(y, -1, axis)
+@functools.lru_cache(maxsize=None)
+def _constants(n1: int, n2: int) -> tuple[np.ndarray, ...]:
+    """Kernel operands: ``G[i2] = diag(twiddle[:, i2]) · F1`` and ``F2``,
+    computed in float64 and rounded once to f32 re/im planes."""
+    f1 = ref.dft_matrix(n1, np.complex128)
+    tw = ref.twiddle_matrix(n1, n2, np.complex128)
+    g = tw.T[:, :, None] * f1[None]  # (n2, n1, n1)
+    f2 = ref.dft_matrix(n2, np.complex128)
+    return tuple(a.astype(np.float32) for a in
+                 (g.real, g.imag, f2.real, f2.imag))
 
 
-def _fourstep_lastaxis_real(xr, xi, n, *, karatsuba, block_b, interpret, real_input):
-    """Flatten batch, pad to block multiple, run the kernel, restore shape."""
+def _fourstep(xr, xi, axis, keep, *, karatsuba, block_b, interpret):
+    """DFT of the (re, im) planes along ``axis``, keeping the first
+    ``keep`` output bins.  The layout pass into the kernel's
+    ``(tiles, n2, n1, tile_b)`` form (transform axis leading, digit-
+    permuted; batch on lanes) and the pass back are each one XLA
+    transpose."""
     if interpret is None:
         interpret = _interpret_default()
+    n = xr.shape[axis]
     n1, n2 = plan_factors(n)
-    *batch_shape, _ = xr.shape
-    b = int(np.prod(batch_shape, dtype=np.int64)) if batch_shape else 1
-    bb = min(block_b, max(b, 1))
-    b_pad = -(-b // bb) * bb
+    rest = xr.shape[:axis] + xr.shape[axis + 1:]
+    b = int(np.prod(rest, dtype=np.int64))
+    tb = tile_width(n, b) if block_b is None else max(1, min(block_b, b))
+    ntiles = -(-b // tb)
 
     def prep(a):
-        a = a.reshape(b, n1, n2)
-        if b_pad != b:
-            a = jnp.pad(a, ((0, b_pad - b), (0, 0), (0, 0)))
-        return a
+        a = jnp.moveaxis(a, axis, 0).reshape(n1, n2, b)
+        if ntiles * tb != b:
+            a = jnp.pad(a, ((0, 0), (0, 0), (0, ntiles * tb - b)))
+        return a.reshape(n1, n2, ntiles, tb).transpose(2, 1, 0, 3)
 
-    xr2 = prep(xr)
-    # real_input path (xi is None): no imaginary plane is materialized or
-    # fed to the kernel at all — the pallas_call drops the operand.
-    planes = (xr2,) if xi is None else (xr2, prep(xi))
+    def post(y):  # (tiles, k1, k2, t) -> bin k = k1 + n1*k2 along axis
+        y = y.transpose(2, 1, 0, 3).reshape(n, ntiles * tb)[:keep, :b]
+        return jnp.moveaxis(y.reshape(keep, *rest), 0, axis)
 
-    f1 = ref.dft_matrix(n1)
-    f2 = ref.dft_matrix(n2)
-    tw = ref.twiddle_matrix(n1, n2)
-    consts = [jnp.asarray(np.real(f1)), jnp.asarray(np.imag(f1)),
-              jnp.asarray(np.real(f2)), jnp.asarray(np.imag(f2)),
-              jnp.asarray(np.real(tw)), jnp.asarray(np.imag(tw))]
-
-    call = fourstep_pallas_call(b_pad, n1, n2, block_b=bb, karatsuba=karatsuba,
-                                real_input=real_input, interpret=interpret)
-    yr, yi = call(*planes, *consts)
-    # output tile layout (b, k2=n2, k1=n1) flattens row-major to k = k1 + n1*k2
-    yr = yr.reshape(b_pad, n)[:b].reshape(*batch_shape, n)
-    yi = yi.reshape(b_pad, n)[:b].reshape(*batch_shape, n)
-    return yr, yi
+    planes = (prep(xr),) if xi is None else (prep(xr), prep(xi))
+    call = fourstep_pallas_call(ntiles, n1, n2, tile_b=tb, karatsuba=karatsuba,
+                                real_input=xi is None, interpret=interpret)
+    yr, yi = call(*planes, *map(jnp.asarray, _constants(n1, n2)))
+    return post(yr), post(yi)

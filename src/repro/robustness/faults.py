@@ -176,13 +176,16 @@ class FaultPlan:
     @staticmethod
     def poison_cache(path, plan, schedule, *, nfields: int = 1) -> str:
         """Write a structurally valid tuner-cache entry for ``plan``'s key
-        naming ``schedule`` (which the tuner never timed); returns the key."""
+        naming ``schedule`` (which the tuner never timed); returns the key.
+        The process's memo for the key is dropped, so the next resolve
+        replays the poisoned entry even if this key was resolved before."""
         from repro.core import tuner
 
         key = tuner.plan_key(plan, nfields=nfields)
         entry = {"schedule": [list(s) for s in schedule],
                  "timings": {"poisoned": {}}}
         tuner.save_cache(path, {key: entry})
+        tuner.forget(key)
         return key
 
     # -- context ------------------------------------------------------------

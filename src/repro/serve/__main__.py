@@ -54,10 +54,13 @@ def main(argv=None):
     import jax
     import numpy as np
 
+    from repro.core.compile_cache import enable_compile_cache
     from repro.core.meshutil import balanced_dims, make_mesh
     from repro.core.planconfig import PlanConfig
     from repro.robustness import faults
     from repro.serve import ServeConfig, SpectralServer
+
+    enable_compile_cache()
 
     ndev = len(jax.devices())
     if args.grid == "slab":

@@ -13,8 +13,10 @@ SRC = REPO / "src"
 
 
 def run_devices(code: str, ndev: int = 8, timeout: int = 900) -> str:
-    """Run ``code`` in a fresh python with ``ndev`` virtual host devices."""
+    """Run ``code`` in a fresh python with ``ndev`` virtual host (CPU)
+    devices — never on an accelerator the parent may hold."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
     env["PYTHONPATH"] = str(SRC)
     proc = subprocess.run([sys.executable, "-c", code], env=env, timeout=timeout,

@@ -114,7 +114,7 @@ def test_unpack_inverts_pack_both_orders(codec, iscomplex, shape, v, w, m, nbatc
     bv, bw = v + nbatch, w + nbatch
     payload, scale, _ = xk.pack_chunks(jnp.asarray(y), axis=bv, m=m,
                                        nbatch=nbatch, codec=codec)
-    out = np.asarray(xk.unpack_chunks(payload, v=v, w=w, m=m, nbatch=nbatch,
+    out = np.asarray(xk.unpack_chunks(payload, w=w, m=m, nbatch=nbatch,
                                       scale=scale, codec=codec,
                                       iscomplex=iscomplex))
     # reference: same codec loss, then the jnp pack/unpack layout ops
@@ -146,6 +146,45 @@ def test_guard_stats_ride_the_fused_codec(codec):
     _, _, pstats = xk.pack_chunks(jnp.asarray(y), axis=0, m=4, codec=codec,
                                   guard=True)
     assert int(pstats["nonfinite"]) == 3
+
+
+@pytest.mark.parametrize("pack", [False, True])
+@pytest.mark.parametrize("shape,axis,m,nbatch,tile_bytes", [
+    ((3, 2, 16384), 1, 2, 0, 40000),     # A tiles x S tiles per chunk
+    ((2, 8, 4, 1024), 2, 4, 1, 16384),   # stacked fields, A tiles
+])
+def test_int8_tiled_grid_bitwise(monkeypatch, pack, shape, axis, m, nbatch,
+                                 tile_bytes):
+    """With the VMEM tile budget shrunk so each (field, chunk) spans
+    several grid steps, the max-abs accumulated across tiles must still
+    give the reference codec's scales and payload bit for bit, and the
+    guard counters must equal the reference counts."""
+    from repro.kernels.exchange import kernel as xkernel
+
+    monkeypatch.setattr(xkernel, "_TILE_BYTES", tile_bytes)
+    y = _rand(shape, True, seed=len(shape)).copy()
+    y.flat[5] = np.inf
+    y.flat[77] = np.nan
+    planes = quant.complex_to_planes(jnp.asarray(y))
+    P = planes.shape[0]
+    F = int(np.prod(shape[:nbatch]))
+    A = int(np.prod(shape[nbatch:axis]))
+    view = (P, F, A, m, planes.size // (P * F * A * m))
+    assert np.prod(xkernel._Layout(view).grid[2:]) > 1
+    q_ref, s_ref, st_ref = quant.quantize_int8(planes.reshape(view),
+                                               block_axis=(1, 3), with_stats=True)
+    fn = xk.pack_chunks if pack else xk.encode_payload
+    q, scale, stats = fn(jnp.asarray(y), axis=axis, m=m, nbatch=nbatch,
+                         codec="int8", guard=True)
+    s_ref = np.asarray(s_ref).reshape(F, m)
+    q_ref = np.asarray(q_ref)
+    if pack:
+        s_ref = s_ref.T
+        q_ref = np.moveaxis(q_ref, 3, 0)
+    np.testing.assert_array_equal(np.asarray(scale), s_ref)
+    np.testing.assert_array_equal(np.asarray(q).reshape(q_ref.shape), q_ref)
+    assert float(stats["nonfinite"]) == float(st_ref["nonfinite"]) == 2
+    assert float(stats["saturated"]) == float(st_ref["saturated"])
 
 
 def test_pallas_applicable_gate():

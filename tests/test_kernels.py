@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 from _hyp import given, settings, strategies as st
 
+from repro.core.fftcore import BACKWARD, TransformSpec, local_transform
 from repro.kernels.fft import ops as fops
 from repro.kernels.fft import ref as fref
 from repro.kernels.transpose.ops import transpose01
@@ -64,8 +65,26 @@ def test_rfft_irfft_matmul(n):
     tol = 3e-3 * max(1, n // 256)
     np.testing.assert_allclose(np.asarray(got), np.fft.rfft(x, axis=-1),
                                rtol=tol, atol=tol * 20)
-    back = fops.irfft_matmul(jnp.asarray(np.fft.rfft(x).astype(np.complex64)), n=n)
+    back = local_transform(jnp.asarray(np.fft.rfft(x).astype(np.complex64)), 1,
+                           BACKWARD, TransformSpec.r2c(), n=n, impl="matmul")
     np.testing.assert_allclose(np.asarray(back), x, rtol=tol, atol=tol * 10)
+
+
+@pytest.mark.parametrize("impl", ["jnp", "matmul"])
+@pytest.mark.parametrize("n", [12, 384, 385])
+def test_c2r_matches_numpy_irfft(impl, n):
+    """The plan's c2r (Hermitian extension + inverse complex DFT) equals
+    numpy's irfft, including dropping the imaginary parts of the DC and
+    Nyquist bins of a non-Hermitian input, on a non-last axis."""
+    rng = np.random.default_rng(n)
+    shape = (n // 2 + 1, 3, 5)
+    spec = (rng.standard_normal(shape) + 1j * rng.standard_normal(shape)
+            ).astype(np.complex64)
+    got = local_transform(jnp.asarray(spec), 0, BACKWARD, TransformSpec.r2c(),
+                          n=n, impl=impl)
+    want = np.fft.irfft(spec.astype(np.complex128), n=n, axis=0)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-5)
 
 
 @pytest.mark.parametrize("block_b", [1, 4, 16])
@@ -75,6 +94,31 @@ def test_fft_matmul_block_invariance(block_b):
     got = fops.fft_matmul(jnp.asarray(x), block_b=block_b)
     np.testing.assert_allclose(np.asarray(got), np.fft.fft(x, axis=-1),
                                rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("n", [64, 512])
+def test_fft_matmul_dots_run_at_highest_precision(n):
+    """Every dot inside the four-step kernel asks for HIGHEST precision:
+    at the MXU's default bf16 passes an f32 DFT loses ~3 digits."""
+    import jax
+    from jax import lax
+
+    jaxpr = jax.make_jaxpr(lambda x: fops.fft_matmul(x))(
+        jnp.zeros((4, n), jnp.complex64))
+    dots = []
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "dot_general":
+                dots.append(eqn.params["precision"])
+            for v in eqn.params.values():
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns"):
+                    walk(inner)
+
+    walk(jaxpr.jaxpr)
+    assert dots
+    assert all(p == (lax.Precision.HIGHEST,) * 2 for p in dots), set(dots)
 
 
 # -- transpose kernel ----------------------------------------------------------

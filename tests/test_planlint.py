@@ -286,7 +286,7 @@ def test_srclint_collective_reachability(tmp_path):
     same collective in an orphan function is SRC101."""
     findings = _lint(tmp_path, **{"mod.py": """
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 def helper(x):
     return lax.psum(x, "p0")
@@ -316,7 +316,7 @@ def axis_size(mesh, name):
 """,
         "b.py": """
 from a import axis_size as _mesh_axis_size
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 def body(x):
     return _mesh_axis_size(None, "p0") * x
@@ -333,7 +333,7 @@ def test_srclint_undeclared_axis_name(tmp_path):
     findings = _lint(tmp_path, **{"mod.py": """
 from jax import lax
 from jax.sharding import Mesh
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 def body(x):
     return lax.psum(x, "rows")
@@ -349,7 +349,7 @@ def build(devices):
     sub.mkdir()
     findings = _lint(sub, **{"mod.py": """
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 def body(x):
     return lax.psum(x, "rows")

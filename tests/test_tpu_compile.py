@@ -1,0 +1,120 @@
+"""Ahead-of-time compiles of the Pallas kernels for a described TPU v5e.
+
+Nothing runs: each test lowers a kernel at the sizes users run and
+compiles it for a v5e chip that is described, not attached, then checks
+that the compiled program holds the kernel (``tpu_custom_call``) and fits
+the chip's HBM.  This catches what interpret mode cannot: block shapes the
+TPU tiling refuses, layouts Mosaic cannot lower, and tiles that overflow
+the scoped VMEM.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels import exchange as xk
+from repro.kernels.fft.ops import fft_matmul, rfft_matmul
+
+#: one v5e chip's HBM
+V5E_HBM_BYTES = 16 * 10**9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache here: keep it out of the cache entirely
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    m = compiled.memory_analysis()
+    used = m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes
+    assert used <= V5E_HBM_BYTES, used
+    return compiled
+
+
+@pytest.mark.parametrize("n", [512, 2048])
+def test_fourstep_kernel_compiles(one_chip, n):
+    """The four-step kernel (n = 32x16 and 64x32) along a batch of 512
+    transforms, complex and real input."""
+    x = jax.ShapeDtypeStruct((512, n), jnp.complex64, sharding=one_chip)
+    _compile(functools.partial(fft_matmul, axis=1, interpret=False), x)
+    xr = jax.ShapeDtypeStruct((512, n), jnp.float32, sharding=one_chip)
+    _compile(functools.partial(rfft_matmul, axis=1, interpret=False), xr)
+
+
+#: per-shard exchange views of a 512^3 complex64 plan:
+#: (block shape, split axis v, concat axis w, group size m)
+STAGE_VIEWS = {
+    "one-chip": ((512, 512, 512), 1, 0, 1),
+    "pencil2x2-stage1": ((256, 256, 512), 2, 1, 2),
+    "pencil2x2-stage2": ((256, 512, 256), 1, 0, 2),
+    "slab4": ((128, 512, 512), 1, 0, 4),
+}
+
+KERNEL_OPS = ["encode-bf16", "encode-bf16-guard", "encode-int8",
+              "encode-int8-guard", "pack-bf16-guard", "pack-int8-guard",
+              "decode-bf16", "decode-int8", "unpack-bf16", "unpack-int8"]
+
+
+@pytest.mark.parametrize("op", KERNEL_OPS)
+@pytest.mark.parametrize("view", sorted(STAGE_VIEWS))
+def test_exchange_kernel_compiles(one_chip, view, op):
+    """Every encode/decode/pack/unpack kernel at each stage view."""
+    shape, v, w, m = STAGE_VIEWS[view]
+    kind, codec, *guard = op.split("-")
+    guard = bool(guard)
+    wire = jnp.int8 if codec == "int8" else jnp.bfloat16
+
+    def sds(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    if kind in ("encode", "pack"):
+        fn = xk.encode_payload if kind == "encode" else xk.pack_chunks
+        _compile(lambda y: fn(y, axis=v, m=m, codec=codec, guard=guard,
+                              interpret=False), sds(shape, jnp.complex64))
+        return
+    if kind == "decode":
+        received = list(shape)
+        received[v] //= m
+        received[w] *= m
+        scale = (sds((1, m), jnp.float32),) if codec == "int8" else ()
+        _compile(lambda q, *s: xk.decode_payload(
+            q, axis=w, m=m, scale=s[0] if s else None, codec=codec,
+            iscomplex=True, interpret=False),
+            sds((2, *received), wire), *scale)
+        return
+    chunk = list(shape)
+    chunk[v] //= m
+    scale = (sds((m, 1), jnp.float32),) if codec == "int8" else ()
+    _compile(lambda q, *s: xk.unpack_chunks(
+        q, w=w, m=m, scale=s[0] if s else None, codec=codec, iscomplex=True,
+        interpret=False),
+        sds((m, 2, *chunk), wire), *scale)

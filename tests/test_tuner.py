@@ -438,3 +438,28 @@ print("WRITER-DONE")
     expect = {f"w{i}-k{j}" for i in range(nproc) for j in range(nkeys)}
     missing = expect - set(final)
     assert not missing, f"lost {len(missing)} updates: {sorted(missing)[:5]}"
+
+
+def test_poison_cache_replaces_a_memoized_schedule(subproc, tmp_path):
+    """A schedule this process already resolved is memoized; poisoning the
+    cache entry afterwards must still be what the next resolve replays
+    (otherwise a fault wave silently runs the earlier winner)."""
+    cache = tmp_path / "fft_tuner.json"
+    out = subproc(f"""
+from repro.core import tuner
+from repro.core.meshutil import make_mesh
+from repro.core.pfft import ParallelFFT
+from repro.core.planconfig import PlanConfig
+from repro.robustness.faults import FaultPlan
+
+mesh = make_mesh((2,), ("p0",))
+cfg = PlanConfig(method="auto", comm_dtype="bf16", tuner_cache={str(cache)!r})
+first = ParallelFFT(mesh, (8, 8, 8), ("p0",), config=cfg).schedule
+poison = tuple(("traditional" if s.method != "traditional" else "fused", 1,
+                "bf16", "jnp", "stacked") for s in first)
+FaultPlan.poison_cache({str(cache)!r}, ParallelFFT(mesh, (8, 8, 8), ("p0",), config=cfg), poison)
+again = ParallelFFT(mesh, (8, 8, 8), ("p0",), config=cfg).schedule
+assert [tuple(s) for s in again] == list(poison), (first, again)
+print("POISON REPLAYED")
+""", ndev=2)
+    assert "POISON REPLAYED" in out
