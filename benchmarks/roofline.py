@@ -117,7 +117,7 @@ def analyze(mesh_filter="single"):
         if rec["mesh"] != mesh_filter or rec.get("sp_mode", "none") != "none":
             continue
         if rec.get("opt") or rec.get("flags"):
-            continue  # §Perf variants live in perf_report, not the baseline table
+            continue  # §Perf variants are left out of the baseline table
         t = term_seconds(rec)
         mf = model_flops(rec)
         ideal = mf / (t["chips"] * PEAK)

@@ -43,6 +43,8 @@ from dataclasses import dataclass
 import jax.numpy as jnp
 from jax import lax
 
+from repro.core import spans
+
 FORWARD = -1
 BACKWARD = +1
 
@@ -169,6 +171,10 @@ def local_transform(x, axis: int, sign: int, spec: TransformSpec, *, n: int,
     ``axis`` stays field-relative (the batched plan executor transforms
     all N fields of a stacked block in one vectorized call — every kernel
     here is axis-generic, so the batch rides for free).
+
+    The work is named in the program (:mod:`repro.core.spans`): the
+    transform ``xform``, the pruning ``prune``, the c2r's Hermitian
+    extension ``c2r_extend``.
     """
     axis = axis + nbatch
     if spec.kind == "c2c":
@@ -186,24 +192,28 @@ def local_transform(x, axis: int, sign: int, spec: TransformSpec, *, n: int,
         if sign == FORWARD:
             y = _rfft(x, axis, impl)
             if spec.n_keep is not None:
-                y = jnp.take(y, jnp.arange(spec.n_keep), axis=axis)
+                with spans.kind("prune"):
+                    y = jnp.take(y, jnp.arange(spec.n_keep), axis=axis)
             return y
         if spec.n_keep is not None and spec.n_keep < nbins:
             pads = [(0, 0)] * x.ndim
             pads[axis] = (0, nbins - spec.n_keep)
-            x = jnp.pad(x, pads)
+            with spans.kind("prune"):
+                x = jnp.pad(x, pads)
         return _irfft(x, axis, n, impl)
 
     # dct / dst: real-to-real, forward type 2 or 3, backward its inverse
     inverse = sign == BACKWARD
     trig_type = spec.trig_type if not inverse else {2: 3, 3: 2}[spec.trig_type]
     fn = _dct_complex_safe if spec.kind == "dct" else _dst_complex_safe
-    return fn(x, axis, trig_type, impl, scale=(1.0 / (2 * n)) if inverse else 1.0)
+    with spans.kind("xform"):
+        return fn(x, axis, trig_type, impl, scale=(1.0 / (2 * n)) if inverse else 1.0)
 
 
 # -- FFT vendor dispatch ----------------------------------------------------
 
 
+@spans.under("xform")
 def _fft(x, axis, sign, impl):
     if impl == "jnp":
         return jnp.fft.fft(x, axis=axis) if sign == FORWARD else jnp.fft.ifft(x, axis=axis)
@@ -214,6 +224,7 @@ def _fft(x, axis, sign, impl):
     raise ValueError(f"unknown fft impl {impl!r}")
 
 
+@spans.under("xform")
 def _rfft(x, axis, impl):
     if impl == "jnp":
         return jnp.fft.rfft(x, axis=axis)
@@ -230,13 +241,18 @@ def _irfft(x, axis, n, impl):
     bins drop out, as in ``numpy.fft.irfft``).  XLA's own IRFFT is not
     used: on a TPU v5e it returned a (384, 384, 193) -> n=384 c2r along
     the last axis at relative L2 error 0.35, where this form gives 1.3e-7."""
-    tail = jnp.flip(jnp.conj(lax.slice_in_dim(x, 1, n - n // 2, axis=axis)), axis)
-    return jnp.real(_fft(jnp.concatenate([x, tail], axis=axis), axis, BACKWARD, impl))
+    with spans.kind("c2r_extend"):
+        tail = jnp.flip(jnp.conj(lax.slice_in_dim(x, 1, n - n // 2, axis=axis)), axis)
+        x = jnp.concatenate([x, tail], axis=axis)
+    y = _fft(x, axis, BACKWARD, impl)
+    with spans.kind("c2r_extend"):
+        return jnp.real(y)
 
 
 # -- pruning (truncated spectra / 3/2-rule dealiasing) ----------------------
 
 
+@spans.under("prune")
 def _keep_centered(y, axis, k):
     """Keep the ``k`` lowest-|frequency| modes of an fft-ordered axis:
     the first ceil(k/2) (non-negative) and last floor(k/2) (negative)."""
@@ -252,6 +268,7 @@ def _keep_centered(y, axis, k):
     return jnp.concatenate([lo, hi], axis=axis)
 
 
+@spans.under("prune")
 def _scatter_centered(y, axis, n, k):
     """Inverse of :func:`_keep_centered`: zero-pad the retained modes back
     into an ``n``-long fft-ordered axis."""

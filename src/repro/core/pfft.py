@@ -70,7 +70,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core import fftcore
+from repro.core import fftcore, spans
 from repro.core.fftcore import TransformSpec, as_spec
 from repro.core.meshutil import shard_map
 from repro.core.decomp import pad_to_multiple
@@ -813,49 +813,55 @@ def _run_stages(block, *, stages, pencils, schedule, impl, sign, nbatch=0,
     the pre/post block-energy Parseval bracket and the per-stage
     non-finite/saturation counters.  No collective is emitted for it —
     the guarded executor's sharded out_spec hands the runner every
-    shard's partial and the host sums them."""
-    cur = pencils[0]
-    per_stage = []
-    lossy = guard and _health.schedule_is_lossy(as_schedule(schedule))
-    energy_in = _health.block_energy(block) if lossy else jnp.float32(0.0)
-    ex_i = i = 0
-    while i < len(stages):
-        st = stages[i]
-        if isinstance(st, ExchangeStage):
-            entry = StageEntry.make(schedule[ex_i])
-            nxt_st = stages[i + 1] if i + 1 < len(stages) else None
-            fft_st = nxt_st if isinstance(nxt_st, FFTStage) and nxt_st.axis == st.w else None
-            block, used_fft, stats = _run_exchange_stage(
-                block, st, fft_st, pencils[i + 1],
-                pencils[i + 2] if fft_st is not None else None,
-                entry, impl=impl, sign=sign, nbatch=nbatch, guard=guard,
-                stage_index=ex_i)
-            ex_i += 1
-            if guard:
-                per_stage.append(stats)
-            i += 2 if used_fft else 1
-        else:
-            block = _fft_padded_axis(block, st, cur, pencils[i + 1], impl=impl,
-                                     sign=sign, nbatch=nbatch)
-            i += 1
-        cur = pencils[i]
-    if not guard:
-        return block
-    energy_out = _health.block_energy(block) if lossy else jnp.float32(0.0)
-    last = stages[-1]
-    probe_axis = last.axis + nbatch if isinstance(last, FFTStage) else None
-    probe = _health.output_probe(block, probe_axis)
-    return block, _health.pack_stats(per_stage, energy_in, energy_out, probe)
+    shard's partial and the host sums them.
+
+    The work is named in the program (:mod:`repro.core.spans`): the whole
+    executor under ``pfft.fwd``/``pfft.bwd``, stage ``i``'s work under
+    ``stage{i}.<kind>``."""
+    with spans.direction(sign):
+        cur = pencils[0]
+        per_stage = []
+        lossy = guard and _health.schedule_is_lossy(as_schedule(schedule))
+        energy_in = _health.block_energy(block) if lossy else jnp.float32(0.0)
+        ex_i = i = 0
+        while i < len(stages):
+            st = stages[i]
+            if isinstance(st, ExchangeStage):
+                entry = StageEntry.make(schedule[ex_i])
+                nxt_st = stages[i + 1] if i + 1 < len(stages) else None
+                fft_st = nxt_st if isinstance(nxt_st, FFTStage) and nxt_st.axis == st.w else None
+                block, used_fft, stats = _run_exchange_stage(
+                    block, st, fft_st, pencils[i + 1],
+                    pencils[i + 2] if fft_st is not None else None,
+                    entry, impl=impl, sign=sign, nbatch=nbatch, at=i, guard=guard,
+                    stage_index=ex_i)
+                ex_i += 1
+                if guard:
+                    per_stage.append(stats)
+                i += 2 if used_fft else 1
+            else:
+                block = _fft_padded_axis(block, st, cur, pencils[i + 1], impl=impl,
+                                         sign=sign, nbatch=nbatch, at=i)
+                i += 1
+            cur = pencils[i]
+        if not guard:
+            return block
+        energy_out = _health.block_energy(block) if lossy else jnp.float32(0.0)
+        last = stages[-1]
+        probe_axis = last.axis + nbatch if isinstance(last, FFTStage) else None
+        probe = _health.output_probe(block, probe_axis)
+        return block, _health.pack_stats(per_stage, energy_in, energy_out, probe)
 
 
 def _run_exchange_stage(block, ex: ExchangeStage, fft_st: FFTStage | None,
                         mid: Pencil, after: Pencil | None, entry, *,
-                        impl, sign, nbatch, guard=False, stage_index=None):
+                        impl, sign, nbatch, at: int, guard=False, stage_index=None):
     """One exchange stage (+ the FFT of its newly-aligned axis, when
     ``fft_st`` is given), under one :class:`StageEntry` schedule entry.  Returns ``(block, used_fft, stats)``
     where ``stats`` is the stage's guard-counter dict (None unless
     ``guard``).  The fault-injection taps are free no-ops without an armed
-    :class:`repro.robustness.FaultPlan`.
+    :class:`repro.robustness.FaultPlan`.  ``at`` is the exchange's index
+    in the executed plan (its scopes' stage); the FFT is stage ``at + 1``.
 
     batch_fusion (stacked ``nbatch=1`` blocks only):
 
@@ -874,24 +880,27 @@ def _run_exchange_stage(block, ex: ExchangeStage, fft_st: FFTStage | None,
         block = _faults.tap_stage_input(block)
         if nbatch and fusion != "stacked":
             nf = block.shape[0]
-            fields = [jax.lax.index_in_dim(block, f, axis=0, keepdims=False)
-                      for f in range(nf)]
-            stats = _health.zero_stats() if guard else None
+            with spans.stage(at), spans.kind("encode"):
+                fields = [jax.lax.index_in_dim(block, f, axis=0, keepdims=False)
+                          for f in range(nf)]
+                stats = _health.zero_stats() if guard else None
 
             def do_exchange(fb):
                 nonlocal stats
-                r = exchange_shard(fb, ex.v, ex.w, ex.group, method=method,
-                                   chunks=chunks, comm_dtype=comm_dtype,
-                                   impl=ex_impl, guard=guard)
-                if guard:
-                    r, s = r
-                    stats = _health.add_stats(stats, s)
+                with spans.stage(at):
+                    r = exchange_shard(fb, ex.v, ex.w, ex.group, method=method,
+                                       chunks=chunks, comm_dtype=comm_dtype,
+                                       impl=ex_impl, guard=guard)
+                    if guard:
+                        r, s = r
+                        stats = _health.add_stats(stats, s)
                 return r
 
             def do_fft(fb):
                 if fft_st is None:
                     return fb
-                return _fft_padded_axis(fb, fft_st, mid, after, impl=impl, sign=sign)
+                return _fft_padded_axis(fb, fft_st, mid, after, impl=impl, sign=sign,
+                                        at=at + 1)
 
             outs = []
             if fusion == "per-field":
@@ -900,10 +909,11 @@ def _run_exchange_stage(block, ex: ExchangeStage, fft_st: FFTStage | None,
                         r = _exchange_then_fft(
                             fb, ex, fft_st, mid, after, chunks=chunks,
                             comm_dtype=comm_dtype, exchange_impl=ex_impl,
-                            impl=impl, sign=sign, guard=guard)
+                            impl=impl, sign=sign, at=at, guard=guard)
                         if guard:
                             r, s = r
-                            stats = _health.add_stats(stats, s)
+                            with spans.stage(at):
+                                stats = _health.add_stats(stats, s)
                         outs.append(r)
                     else:
                         outs.append(do_fft(do_exchange(fb)))
@@ -914,27 +924,30 @@ def _run_exchange_stage(block, ex: ExchangeStage, fft_st: FFTStage | None,
                     if f:  # field f's collective emitted before field f-1's FFT
                         outs.append(do_fft(exchanged[f - 1]))
                 outs.append(do_fft(exchanged[-1]))
-            return jnp.stack(outs), fft_st is not None, stats
+            with spans.stage(at), spans.kind("decode"):
+                out = jnp.stack(outs)
+            return out, fft_st is not None, stats
 
         if fft_st is not None and method == "pipelined" and chunks > 1:
             res = _exchange_then_fft(block, ex, fft_st, mid, after,
                                      chunks=chunks, comm_dtype=comm_dtype,
                                      exchange_impl=ex_impl, impl=impl,
-                                     sign=sign, nbatch=nbatch, guard=guard)
+                                     sign=sign, at=at, nbatch=nbatch, guard=guard)
             block, stats = res if guard else (res, None)
             return block, True, stats
-        res = exchange_shard(block, ex.v, ex.w, ex.group, method=method,
-                             chunks=chunks, comm_dtype=comm_dtype,
-                             impl=ex_impl, nbatch=nbatch, guard=guard)
+        with spans.stage(at):
+            res = exchange_shard(block, ex.v, ex.w, ex.group, method=method,
+                                 chunks=chunks, comm_dtype=comm_dtype,
+                                 impl=ex_impl, nbatch=nbatch, guard=guard)
         block, stats = res if guard else (res, None)
         if fft_st is not None:
             block = _fft_padded_axis(block, fft_st, mid, after, impl=impl,
-                                     sign=sign, nbatch=nbatch)
+                                     sign=sign, nbatch=nbatch, at=at + 1)
         return block, fft_st is not None, stats
 
 
 def _exchange_then_fft(block, ex: ExchangeStage, fft_st: FFTStage,
-                       mid: Pencil, after: Pencil, *, chunks, impl, sign,
+                       mid: Pencil, after: Pencil, *, chunks, impl, sign, at: int,
                        comm_dtype=None, exchange_impl="jnp", nbatch=0,
                        guard=False):
     """Pipelined exchange fused with the next stage's 1-D FFT: issue the
@@ -944,18 +957,27 @@ def _exchange_then_fft(block, ex: ExchangeStage, fft_st: FFTStage,
     result (bitwise for lossless ``comm_dtype``, to the codec's error bound
     for bf16/int8 since slices quantize independently); the payoff is that
     XLA may run slice i+1's collective DMA under slice i's FFT compute.
-    With ``nbatch=1`` each slice carries every field's sub-range."""
-    res = exchange_shard_sliced(block, ex.v, ex.w, ex.group, chunks=chunks,
-                                comm_dtype=comm_dtype, impl=exchange_impl,
-                                nbatch=nbatch, guard=guard)
+    With ``nbatch=1`` each slice carries every field's sub-range.  The
+    exchange is stage ``at``, the FFT stage ``at + 1``; the concatenation
+    of the slices is the exchange's decode."""
+    with spans.stage(at):
+        res = exchange_shard_sliced(block, ex.v, ex.w, ex.group, chunks=chunks,
+                                    comm_dtype=comm_dtype, impl=exchange_impl,
+                                    nbatch=nbatch, guard=guard)
     pieces, stats = res if guard else (res, None)
-    out = [_fft_padded_axis(p, fft_st, mid, after, impl=impl, sign=sign, nbatch=nbatch)
+    out = [_fft_padded_axis(p, fft_st, mid, after, impl=impl, sign=sign, nbatch=nbatch,
+                            at=at + 1)
            for p in pieces]
-    out = out[0] if len(out) == 1 else jnp.concatenate(out, axis=ex.v + nbatch)
+    if len(out) == 1:
+        out = out[0]
+    else:
+        with spans.stage(at), spans.kind("decode"):
+            out = jnp.concatenate(out, axis=ex.v + nbatch)
     return (out, stats) if guard else out
 
 
-def _fft_padded_axis(block, st: FFTStage, cur: Pencil, nxt: Pencil, *, impl, sign, nbatch=0):
+def _fft_padded_axis(block, st: FFTStage, cur: Pencil, nxt: Pencil, *, impl, sign, at: int,
+                     nbatch=0):
     """One transform stage along a locally-complete axis, honouring padding:
     slice to the logical extent, transform at the true length (pruning
     gather/scatter folded in by :func:`fftcore.local_transform`), re-pad.
@@ -963,20 +985,25 @@ def _fft_padded_axis(block, st: FFTStage, cur: Pencil, nxt: Pencil, *, impl, sig
     XLA fuses them with the adjacent exchange's unpack — dealiasing rides
     the existing exchange path instead of costing separate HBM passes.
     ``nbatch`` leading batch axes transform vectorized (``st.axis`` stays
-    field-relative, matching the pencil traces)."""
+    field-relative, matching the pencil traces).  ``at`` is the stage's
+    index in the executed plan: the slice and pad are its ``repad``, the
+    transform's own scopes are numbered with it."""
     axis = st.axis + nbatch
     n_log_in = cur.logical[st.axis]
     if block.shape[axis] != cur.physical[st.axis]:
         raise AssertionError(
             f"axis {st.axis}: local extent {block.shape[axis]} != physical {cur.physical[st.axis]}"
         )
-    if n_log_in != block.shape[axis]:
-        block = jax.lax.slice_in_dim(block, 0, n_log_in, axis=axis)
-    block = fftcore.local_transform(block, st.axis, sign, st.spec, n=st.n,
-                                    impl=impl, nbatch=nbatch)
-    n_phys_out = nxt.physical[st.axis]
-    if block.shape[axis] != n_phys_out:
-        pads = [(0, 0)] * block.ndim
-        pads[axis] = (0, n_phys_out - block.shape[axis])
-        block = jnp.pad(block, pads)
+    with spans.stage(at):
+        if n_log_in != block.shape[axis]:
+            with spans.kind("repad"):
+                block = jax.lax.slice_in_dim(block, 0, n_log_in, axis=axis)
+        block = fftcore.local_transform(block, st.axis, sign, st.spec, n=st.n,
+                                        impl=impl, nbatch=nbatch)
+        n_phys_out = nxt.physical[st.axis]
+        if block.shape[axis] != n_phys_out:
+            pads = [(0, 0)] * block.ndim
+            pads[axis] = (0, n_phys_out - block.shape[axis])
+            with spans.kind("repad"):
+                block = jnp.pad(block, pads)
     return block

@@ -90,7 +90,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro.core import quant
+from repro.core import quant, spans
 from repro.core.decomp import local_lengths
 from repro.core.meshutil import axis_size as _mesh_axis_size, shard_map
 from repro.core.pencil import Group, Pencil, group_names, group_size
@@ -153,13 +153,18 @@ def _all_to_all_comm(
     payload (the single-host CPU backend widens the jnp bf16 wire back to
     f32; see planlint PLAN002).  A lossless payload has no codec to fuse
     and always takes the jnp path below (``pallas_applicable``).
+
+    The work is named in the program (:mod:`repro.core.spans`): the codec
+    before the collective ``encode``, every all-to-all ``a2a``, the codec
+    after it ``decode``.
     """
     d = canonical_comm_dtype(comm_dtype)
     if d == "complex64":
         stats = _health.zero_stats() if guard else None
-        out = lax.all_to_all(y, axis_name, split_axis=split_axis,
-                             concat_axis=concat_axis, tiled=True)
-        out = _faults.tap_wire(out, "payload")
+        with spans.kind("a2a"):
+            out = lax.all_to_all(y, axis_name, split_axis=split_axis,
+                                 concat_axis=concat_axis, tiled=True)
+            out = _faults.tap_wire(out, "payload")
         return (out, stats) if guard else out
     iscomplex = jnp.iscomplexobj(y)
     if impl == "pallas":
@@ -168,65 +173,76 @@ def _all_to_all_comm(
                              f"got {batch_axes}")
         m = _axis_size(axis_name)
         sd = _faults.scale_div() if d == "int8" else None
-        q, scale, stats = _xk.encode_payload(
-            y, axis=split_axis, m=m, nbatch=len(batch_axes), codec=d,
-            guard=guard, scale_div=sd)
-        # payload is (P, *y.shape) re/im planes: split/concat shift past P
-        qx = lax.all_to_all(q, axis_name, split_axis=split_axis + 1,
-                            concat_axis=concat_axis + 1, tiled=True)
-        qx = _faults.tap_wire(qx, "payload")
-        sx = None
-        if scale is not None:  # int8: (F, M) per-(field, chunk) scales
-            sx = lax.all_to_all(scale, axis_name, split_axis=1,
-                                concat_axis=1, tiled=True)
-            sx = _faults.tap_wire(sx, "scale")
-        out = _xk.decode_payload(qx, axis=concat_axis, m=m,
-                                 nbatch=len(batch_axes), scale=sx, codec=d,
-                                 iscomplex=iscomplex)
+        with spans.kind("encode"):
+            q, scale, stats = _xk.encode_payload(
+                y, axis=split_axis, m=m, nbatch=len(batch_axes), codec=d,
+                guard=guard, scale_div=sd)
+        with spans.kind("a2a"):
+            # payload is (P, *y.shape) re/im planes: split/concat shift past P
+            qx = lax.all_to_all(q, axis_name, split_axis=split_axis + 1,
+                                concat_axis=concat_axis + 1, tiled=True)
+            qx = _faults.tap_wire(qx, "payload")
+            sx = None
+            if scale is not None:  # int8: (F, M) per-(field, chunk) scales
+                sx = lax.all_to_all(scale, axis_name, split_axis=1,
+                                    concat_axis=1, tiled=True)
+                sx = _faults.tap_wire(sx, "scale")
+        with spans.kind("decode"):
+            out = _xk.decode_payload(qx, axis=concat_axis, m=m,
+                                     nbatch=len(batch_axes), scale=sx, codec=d,
+                                     iscomplex=iscomplex)
         return (out, stats) if guard else out
-    planes = quant.complex_to_planes(y) if iscomplex else y[None].astype(jnp.float32)
+    with spans.kind("encode"):
+        planes = quant.complex_to_planes(y) if iscomplex else y[None].astype(jnp.float32)
     sa, ca = split_axis + 1, concat_axis + 1
     ba = tuple(b + 1 for b in batch_axes)  # planes coords
 
     if d == "bf16":
-        stats = _health.payload_stats(planes) if guard else None
-        p = lax.all_to_all(quant.encode_bf16(planes), axis_name,
-                           split_axis=sa, concat_axis=ca, tiled=True)
-        p = quant.decode_bf16(_faults.tap_wire(p, "payload"))
-        out = quant.planes_to_complex(p) if iscomplex else p[0]
+        with spans.kind("encode"):
+            stats = _health.payload_stats(planes) if guard else None
+            wire = quant.encode_bf16(planes)
+        with spans.kind("a2a"):
+            p = lax.all_to_all(wire, axis_name, split_axis=sa, concat_axis=ca, tiled=True)
+            p = _faults.tap_wire(p, "payload")
+        with spans.kind("decode"):
+            p = quant.decode_bf16(p)
+            out = quant.planes_to_complex(p) if iscomplex else p[0]
         return (out, stats) if guard else out
 
     # int8: one scale per (field, destination chunk) of the split axis.
     m = _axis_size(axis_name)
-    nv = planes.shape[sa]
-    if nv % m != 0:
-        raise ValueError(f"split axis extent {nv} not divisible by group size {m}")
-    view = list(planes.shape)
-    view[sa : sa + 1] = [m, nv // m]
-    # block axes in view coords: the m-chunk axis plus every batch axis
-    # (axes past the inserted nv//m axis shift right by one)
-    block_axes = (sa,) + tuple(b if b < sa else b + 1 for b in ba)
-    qargs = dict(block_axis=block_axes, scale_div=_faults.scale_div())
-    if guard:
-        q, scale, stats = quant.quantize_int8(planes.reshape(view),
-                                              with_stats=True, **qargs)
-    else:
-        q, scale = quant.quantize_int8(planes.reshape(view), **qargs)
-        stats = None
-    q = q.reshape(planes.shape)
-    # scale keepdims (view coords) -> planes coords: drop the nv//m axis
-    s = scale.reshape([e for i, e in enumerate(scale.shape) if i != sa + 1])
-    qx = lax.all_to_all(q, axis_name, split_axis=sa, concat_axis=ca, tiled=True)
-    sx = lax.all_to_all(s, axis_name, split_axis=sa, concat_axis=ca, tiled=True)
-    qx = _faults.tap_wire(qx, "payload")
-    sx = _faults.tap_wire(sx, "scale")
-    # received chunk j along the concat axis was quantized with sender j's
-    # scale: view ca as (m, ca_out/m) and broadcast sx over the chunk
-    out_view = list(qx.shape)
-    out_view[ca : ca + 1] = [m, qx.shape[ca] // m]
-    dq = quant.dequantize_int8(qx.reshape(out_view), jnp.expand_dims(sx, ca + 1))
-    p = dq.reshape(qx.shape)
-    out = quant.planes_to_complex(p) if iscomplex else p[0]
+    with spans.kind("encode"):
+        nv = planes.shape[sa]
+        if nv % m != 0:
+            raise ValueError(f"split axis extent {nv} not divisible by group size {m}")
+        view = list(planes.shape)
+        view[sa : sa + 1] = [m, nv // m]
+        # block axes in view coords: the m-chunk axis plus every batch axis
+        # (axes past the inserted nv//m axis shift right by one)
+        block_axes = (sa,) + tuple(b if b < sa else b + 1 for b in ba)
+        qargs = dict(block_axis=block_axes, scale_div=_faults.scale_div())
+        if guard:
+            q, scale, stats = quant.quantize_int8(planes.reshape(view),
+                                                  with_stats=True, **qargs)
+        else:
+            q, scale = quant.quantize_int8(planes.reshape(view), **qargs)
+            stats = None
+        q = q.reshape(planes.shape)
+        # scale keepdims (view coords) -> planes coords: drop the nv//m axis
+        s = scale.reshape([e for i, e in enumerate(scale.shape) if i != sa + 1])
+    with spans.kind("a2a"):
+        qx = lax.all_to_all(q, axis_name, split_axis=sa, concat_axis=ca, tiled=True)
+        sx = lax.all_to_all(s, axis_name, split_axis=sa, concat_axis=ca, tiled=True)
+        qx = _faults.tap_wire(qx, "payload")
+        sx = _faults.tap_wire(sx, "scale")
+    with spans.kind("decode"):
+        # received chunk j along the concat axis was quantized with sender j's
+        # scale: view ca as (m, ca_out/m) and broadcast sx over the chunk
+        out_view = list(qx.shape)
+        out_view[ca : ca + 1] = [m, qx.shape[ca] // m]
+        dq = quant.dequantize_int8(qx.reshape(out_view), jnp.expand_dims(sx, ca + 1))
+        p = dq.reshape(qx.shape)
+        out = quant.planes_to_complex(p) if iscomplex else p[0]
     return (out, stats) if guard else out
 
 
@@ -291,7 +307,11 @@ def exchange_shard(
                                   comm_dtype=comm_dtype, nbatch=nbatch,
                                   guard=guard, impl=impl)
         pieces, stats = r if guard else (r, None)
-        out = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, axis=bv)
+        if len(pieces) == 1:
+            out = pieces[0]
+        else:
+            with spans.kind("decode"):
+                out = jnp.concatenate(pieces, axis=bv)
         return (out, stats) if guard else out
 
     if method == "traditional":
@@ -304,28 +324,32 @@ def exchange_shard(
             # One kernel packs chunk-major AND encodes (Eqs. 15-16 cost no
             # extra pass); the inverse kernel scatters + dequantizes (Eq. 17).
             sd = _faults.scale_div() if d == "int8" else None
-            payload, scale, stats = _xk.pack_chunks(
-                block, axis=bv, m=m, nbatch=nbatch, codec=d, guard=guard,
-                scale_div=sd)
-            y = lax.all_to_all(payload, axis_name, split_axis=0,
-                               concat_axis=0, tiled=True)
-            y = _faults.tap_wire(y, "payload")
-            sx = None
-            if scale is not None:  # int8: (M, F) scales, chunk-major like the payload
-                sx = lax.all_to_all(scale, axis_name, split_axis=0,
-                                    concat_axis=0, tiled=True)
-                sx = _faults.tap_wire(sx, "scale")
-            out = _xk.unpack_chunks(y, w=w, m=m, nbatch=nbatch,
-                                    scale=sx, codec=d,
-                                    iscomplex=jnp.iscomplexobj(block))
+            with spans.kind("encode"):
+                payload, scale, stats = _xk.pack_chunks(
+                    block, axis=bv, m=m, nbatch=nbatch, codec=d, guard=guard,
+                    scale_div=sd)
+            with spans.kind("a2a"):
+                y = lax.all_to_all(payload, axis_name, split_axis=0,
+                                   concat_axis=0, tiled=True)
+                y = _faults.tap_wire(y, "payload")
+                sx = None
+                if scale is not None:  # int8: (M, F) scales, chunk-major like the payload
+                    sx = lax.all_to_all(scale, axis_name, split_axis=0,
+                                        concat_axis=0, tiled=True)
+                    sx = _faults.tap_wire(sx, "scale")
+            with spans.kind("decode"):
+                out = _xk.unpack_chunks(y, w=w, m=m, nbatch=nbatch,
+                                        scale=sx, codec=d,
+                                        iscomplex=jnp.iscomplexobj(block))
             return (out, stats) if guard else out
-        # Eq. (15): reshape v -> (m, nv/m); stride change only, free.
-        shape = list(block.shape)
-        shape[bv : bv + 1] = [m, nv // m]
-        y = block.reshape(shape)
-        # Eq. (16): bring the chunk axis to the front — the materialized
-        # local transpose (the costly pack step traditional codes pay for).
-        y = jnp.moveaxis(y, bv, 0)
+        with spans.kind("encode"):
+            # Eq. (15): reshape v -> (m, nv/m); stride change only, free.
+            shape = list(block.shape)
+            shape[bv : bv + 1] = [m, nv // m]
+            y = block.reshape(shape)
+            # Eq. (16): bring the chunk axis to the front — the materialized
+            # local transpose (the costly pack step traditional codes pay for).
+            y = jnp.moveaxis(y, bv, 0)
         # Eq. (17)+ALLTOALL: contiguous exchange on the leading chunk axis.
         r = _all_to_all_comm(y, axis_name, split_axis=0, concat_axis=0,
                              comm_dtype=comm_dtype,
@@ -336,12 +360,15 @@ def exchange_shard(
         if transposed_out:
             # FFTW "transposed out": keep chunk-major layout, caller handles it.
             return (y, stats) if guard else y
-        # Insert the chunk axis just before w (chunk-major == global w order)
-        # and merge (m, w_shard) -> w_full: the second materialized copy.
-        z = jnp.moveaxis(y, 0, bw)
-        shape = list(z.shape)
-        shape[bw : bw + 2] = [shape[bw] * shape[bw + 1]]
-        return (z.reshape(shape), stats) if guard else z.reshape(shape)
+        with spans.kind("decode"):
+            # Insert the chunk axis just before w (chunk-major == global w
+            # order) and merge (m, w_shard) -> w_full: the second
+            # materialized copy.
+            z = jnp.moveaxis(y, 0, bw)
+            shape = list(z.shape)
+            shape[bw : bw + 2] = [shape[bw] * shape[bw + 1]]
+            z = z.reshape(shape)
+        return (z, stats) if guard else z
 
     raise ValueError(f"unknown method {method!r}")
 
@@ -392,13 +419,15 @@ def exchange_shard_sliced(
     # view v as (m, b); the concat axis shifts right if it follows v
     shape = list(block.shape)
     shape[bv : bv + 1] = [m, b]
-    y = block.reshape(shape)
+    with spans.kind("encode"):
+        y = block.reshape(shape)
     w_eff = bw if bw < bv else bw + 1
     pieces = []
     stats = _health.zero_stats() if guard else None
     off = 0
     for n in sizes:
-        piece = lax.slice_in_dim(y, off, off + n, axis=bv + 1)
+        with spans.kind("encode"):
+            piece = lax.slice_in_dim(y, off, off + n, axis=bv + 1)
         off += n
         r = _all_to_all_comm(piece, axis_name, split_axis=bv, concat_axis=w_eff,
                              comm_dtype=comm_dtype,
@@ -412,7 +441,8 @@ def exchange_shard_sliced(
         # p's m-factor axis now has extent 1: merge (1, n) -> (n,)
         pshape = list(p.shape)
         pshape[bv : bv + 2] = [n]
-        pieces.append(p.reshape(pshape))
+        with spans.kind("decode"):
+            pieces.append(p.reshape(pshape))
     return (pieces, stats) if guard else pieces
 
 

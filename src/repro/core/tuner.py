@@ -517,7 +517,7 @@ def _time_stage(plan, si: int, method: str, chunks: int, comm_dtype: str,
         out, _, _ = _run_exchange_stage(
             block, st, follow if has_fft else None, plan.pencil_trace[si + 1],
             out_pen if has_fft else None, entry, impl=plan.impl,
-            sign=fftcore.FORWARD, nbatch=nbatch)
+            sign=fftcore.FORWARD, nbatch=nbatch, at=si)
         return out
 
     fn = jax.jit(shard_map(run, mesh=plan.mesh,

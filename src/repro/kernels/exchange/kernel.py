@@ -178,7 +178,8 @@ def scale_pallas_call(view, *, chunk_major: bool, scale_div, interpret: bool):
 
     call = pl.pallas_call(
         body, grid=lay.grid, in_specs=[lay.spec(False)], out_specs=s_spec,
-        out_shape=s_shape, compiler_params=lay.params(), interpret=interpret)
+        out_shape=s_shape, compiler_params=lay.params(), interpret=interpret,
+        name="repro_exchange_scale")
     return lambda x: call(x.reshape(lay.shape(False)))
 
 
@@ -233,7 +234,8 @@ def encode_pallas_call(view, *, codec: str, chunk_major: bool, guard: bool,
 
     call = pl.pallas_call(
         body, grid=lay.grid, in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shapes, compiler_params=lay.params(), interpret=interpret)
+        out_shape=out_shapes, compiler_params=lay.params(), interpret=interpret,
+        name=f"repro_exchange_encode_{codec}")
     return lambda x, *scale: call(x.reshape(lay.shape(False)), *scale)
 
 
@@ -261,5 +263,6 @@ def decode_pallas_call(view, *, codec: str, chunk_major: bool, interpret: bool):
     call = pl.pallas_call(
         body, grid=lay.grid, in_specs=in_specs, out_specs=lay.spec(False),
         out_shape=jax.ShapeDtypeStruct(lay.shape(False), jnp.float32),
-        compiler_params=lay.params(), interpret=interpret)
+        compiler_params=lay.params(), interpret=interpret,
+        name=f"repro_exchange_decode_{codec}")
     return lambda q, *scale: call(q.reshape(lay.shape(chunk_major)), *scale)

@@ -122,5 +122,6 @@ def fourstep_pallas_call(
         scratch_shapes=[pltpu.VMEM((n1, n2, tile_b), jnp.float32)] * 2 if n2 > 1 else [],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",), vmem_limit_bytes=limit),
+        name="repro_fft_fourstep",
         interpret=interpret,
     )

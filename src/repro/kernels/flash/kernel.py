@@ -99,4 +99,5 @@ def flash_pallas_call(bh: int, sq: int, skv: int, dh: int, *, block_q: int,
             pltpu.VMEM((block_q, dh), jnp.float32),   # acc
         ],
         interpret=interpret,
+        name="repro_flash_attention",
     )

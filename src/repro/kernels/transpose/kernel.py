@@ -32,4 +32,5 @@ def transpose01_pallas_call(a: int, b: int, c: int, *, block_a: int, block_b: in
         out_specs=pl.BlockSpec((block_b, block_a, c), lambda i, j: (j, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, a, c), dtype),
         interpret=interpret,
+        name="repro_transpose01",
     )

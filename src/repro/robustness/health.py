@@ -37,11 +37,13 @@ Two halves:
   unnormalized-FFT factor ``prod(shape)``).
 
 This module must not import :mod:`repro.core` at module scope (the plan
-executor imports it); the one plan-shape helper does so lazily.
+executor imports it); the one plan-shape helper, and the ``guard`` scope
+that names the traced guard ops in the program, do so lazily.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass, field
 
@@ -68,17 +70,31 @@ PARSEVAL_TOL = {"complex64": 1e-3, "bf16": 5e-2, "int8": 2e-1}
 # ---------------------------------------------------------------------------
 
 
+def _guard_scope(fn):
+    """Run ``fn`` under the plan's ``guard`` scope (:mod:`repro.core.spans`)."""
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        from repro.core import spans
+
+        with spans.kind("guard"):
+            return fn(*args, **kwargs)
+    return scoped
+
+
+@_guard_scope
 def count_nonfinite(x) -> jnp.ndarray:
     """f32 scalar count of non-finite elements (complex: either part)."""
     return jnp.sum(~jnp.isfinite(x), dtype=jnp.float32)
 
 
+@_guard_scope
 def payload_stats(x) -> dict:
     """Guard stats for a bf16 exchange payload: non-finite count only
     (saturation is an int8-codec concept; the codec reports its own)."""
     return {"nonfinite": count_nonfinite(x), "saturated": jnp.zeros((), jnp.float32)}
 
 
+@_guard_scope
 def output_probe(block, axis: int | None) -> jnp.ndarray:
     """Near-free non-finite detector for the executor's output block: the
     sum over the index-0 plane along the final FFT stage's ``axis``.
@@ -101,6 +117,7 @@ def output_probe(block, axis: int | None) -> jnp.ndarray:
     return s.astype(jnp.float32)
 
 
+@_guard_scope
 def block_energy(x) -> jnp.ndarray:
     """f32 scalar sum |x|^2 over one shard (zero padding contributes 0, so
     padded and logical blocks have identical energy).  Computed as
@@ -114,15 +131,18 @@ def block_energy(x) -> jnp.ndarray:
     return jnp.sum(x * x)
 
 
+@_guard_scope
 def zero_stats() -> dict:
     return {"nonfinite": jnp.zeros((), jnp.float32),
             "saturated": jnp.zeros((), jnp.float32)}
 
 
+@_guard_scope
 def add_stats(a: dict, b: dict) -> dict:
     return {k: a[k] + b[k] for k in a}
 
 
+@_guard_scope
 def pack_stats(per_stage: list, energy_in, energy_out, probe) -> jnp.ndarray:
     """Pack one shard's guard stats into the executor's flat f32 output
     vector ``[energy_in, energy_out, probe, nonfinite_0..S-1,

@@ -38,6 +38,7 @@ import time
 from collections import deque
 from dataclasses import dataclass
 
+from repro.core import spans
 from repro.robustness import faults
 from repro.robustness.runner import GuardError, run_guarded
 from repro.serve.lifecycle import (
@@ -138,6 +139,7 @@ class SpectralServer:
             out = dict(self._stats)
         out["queue_depth"] = len(self._queue)
         out["registry"] = self.registry.stats()
+        out["compile"] = spans.compile_totals()
         return out
 
     def drain(self, timeout: float = 60.0) -> bool:

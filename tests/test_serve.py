@@ -169,7 +169,8 @@ print("CLEAN=" + json.dumps({
     "match": match,
     "coalesced_batches": stats["coalesced_batches"],
     "batched_requests": stats["batched_requests"],
-    "plans": stats["registry"]["plans"]}))
+    "plans": stats["registry"]["plans"],
+    "compile": stats["compile"]}))
 """
 
 
@@ -182,6 +183,9 @@ def test_serve_clean_coalescing(subproc):
     assert out["batched_requests"] >= 4
     assert max(out["batched"]) >= 4
     assert out["plans"] == 1
+    # the operator sees the process's compiles: at least the plan's own
+    assert out["compile"]["xla_compiles"] >= 1
+    assert out["compile"]["xla_compile_s"] > 0 and out["compile"]["trace_lower_s"] > 0
 
 
 _LRU_SCRIPT = r"""

@@ -118,3 +118,39 @@ def test_exchange_kernel_compiles(one_chip, view, op):
         q, w=w, m=m, scale=s[0] if s else None, codec=codec, iscomplex=True,
         interpret=False),
         sds((m, 2, *chunk), wire), *scale)
+
+
+#: each kernel's stable name, and a lowering that holds it
+KERNEL_NAMES = {
+    "repro_fft_fourstep": ("fft", None),
+    "repro_exchange_scale": ("encode", "int8"),
+    "repro_exchange_encode_bf16": ("encode", "bf16"),
+    "repro_exchange_encode_int8": ("encode", "int8"),
+    "repro_exchange_decode_bf16": ("decode", "bf16"),
+    "repro_exchange_decode_int8": ("decode", "int8"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNEL_NAMES))
+def test_kernel_names_reach_the_lowered_module(one_chip, name):
+    """Each Pallas kernel keeps its stable name in the module lowered for
+    the chip, where a trace reader finds it."""
+    kind, codec = KERNEL_NAMES[name]
+
+    def sds(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    if kind == "fft":
+        lowered = jax.jit(functools.partial(fft_matmul, axis=1, interpret=False)).lower(
+            sds((512, 512), jnp.complex64))
+    elif kind == "encode":
+        lowered = jax.jit(lambda y: xk.encode_payload(
+            y, axis=1, m=2, codec=codec, interpret=False)).lower(
+            sds((256, 256, 512), jnp.complex64))
+    else:
+        wire = jnp.int8 if codec == "int8" else jnp.bfloat16
+        scale = (sds((1, 2), jnp.float32),) if codec == "int8" else ()
+        lowered = jax.jit(lambda q, *s: xk.decode_payload(
+            q, axis=0, m=2, scale=s[0] if s else None, codec=codec, iscomplex=True,
+            interpret=False)).lower(sds((2, 256, 128, 512), wire), *scale)
+    assert name in lowered.as_text()
