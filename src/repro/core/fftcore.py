@@ -163,7 +163,7 @@ def local_transform(x, axis: int, sign: int, spec: TransformSpec, *, n: int,
 
     Forward (``sign == FORWARD``): input logical length ``n`` ->
     ``spec.spectral_extent(n)``.  Backward: the exact reverse.  Pruning
-    (``spec.n_keep``) is folded in here — the forward gather / backward
+    (``spec.n_keep``) is folded in here — the forward keep / backward
     zero-scatter is emitted adjacent to the transform so it fuses with the
     surrounding exchange unpack instead of costing a separate HBM pass.
 
@@ -191,9 +191,9 @@ def local_transform(x, axis: int, sign: int, spec: TransformSpec, *, n: int,
         nbins = n // 2 + 1
         if sign == FORWARD:
             y = _rfft(x, axis, impl)
-            if spec.n_keep is not None:
+            if spec.n_keep is not None and spec.n_keep < nbins:
                 with spans.kind("prune"):
-                    y = jnp.take(y, jnp.arange(spec.n_keep), axis=axis)
+                    y = lax.slice_in_dim(y, 0, spec.n_keep, axis=axis)
             return y
         if spec.n_keep is not None and spec.n_keep < nbins:
             pads = [(0, 0)] * x.ndim
@@ -255,34 +255,37 @@ def _irfft(x, axis, n, impl):
 @spans.under("prune")
 def _keep_centered(y, axis, k):
     """Keep the ``k`` lowest-|frequency| modes of an fft-ordered axis:
-    the first ceil(k/2) (non-negative) and last floor(k/2) (negative)."""
+    the first ceil(k/2) (non-negative) and last floor(k/2) (negative).
+
+    Static slices, not an index gather: XLA on the TPU lowers a gather of
+    a contiguous range to a ``while`` loop of row copies."""
     n = y.shape[axis]
     if k == n:
         return y
     head = (k + 1) // 2
     tail = k - head
-    lo = jnp.take(y, jnp.arange(head), axis=axis)
+    lo = lax.slice_in_dim(y, 0, head, axis=axis)
     if tail == 0:
         return lo
-    hi = jnp.take(y, jnp.arange(n - tail, n), axis=axis)
+    hi = lax.slice_in_dim(y, n - tail, n, axis=axis)
     return jnp.concatenate([lo, hi], axis=axis)
 
 
 @spans.under("prune")
 def _scatter_centered(y, axis, n, k):
     """Inverse of :func:`_keep_centered`: zero-pad the retained modes back
-    into an ``n``-long fft-ordered axis."""
+    into an ``n``-long fft-ordered axis (head, ``n - k`` zeros, tail)."""
     if k == n:
         return y
     head = (k + 1) // 2
     tail = k - head
-    lo = jnp.take(y, jnp.arange(head), axis=axis)
+    lo = lax.slice_in_dim(y, 0, head, axis=axis)
     mid_shape = list(y.shape)
     mid_shape[axis] = n - k
     mid = jnp.zeros(mid_shape, y.dtype)
     if tail == 0:
         return jnp.concatenate([lo, mid], axis=axis)
-    hi = jnp.take(y, jnp.arange(head, k), axis=axis)
+    hi = lax.slice_in_dim(y, head, k, axis=axis)
     return jnp.concatenate([lo, mid, hi], axis=axis)
 
 

@@ -980,7 +980,7 @@ def _fft_padded_axis(block, st: FFTStage, cur: Pencil, nxt: Pencil, *, impl, sig
                      nbatch=0):
     """One transform stage along a locally-complete axis, honouring padding:
     slice to the logical extent, transform at the true length (pruning
-    gather/scatter folded in by :func:`fftcore.local_transform`), re-pad.
+    keep/zero-scatter folded in by :func:`fftcore.local_transform`), re-pad.
     Because the slice/pad bracket the transform inside the shard function,
     XLA fuses them with the adjacent exchange's unpack — dealiasing rides
     the existing exchange path instead of costing separate HBM passes.

@@ -23,7 +23,7 @@ called on its own).  The kinds:
 ``guard``      the runtime health checks of :mod:`repro.robustness.health`.
 
 The names are :func:`jax.named_scope` s: they reach the compiled HLO's
-``op_name`` metadata (``jit(step)/pfft.fwd/stage0.prune/jit(_take)/gather``)
+``op_name`` metadata (``jit(step)/pfft.fwd/stage0.prune/slice``)
 and from there a profiler trace, and change metadata only, never an op.
 No name is a path component that names an FFT or a data-movement
 primitive (``fft``, ``gather``, ...), so a reader that classes an op by

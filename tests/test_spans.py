@@ -67,14 +67,29 @@ out = {"ops": {}, "plain": {}, "scoped": {}}
 for case in CASES:
     nfields = 2 if case.rsplit("-", 1)[0] in FUSIONS else 1
     out["ops"][case] = {d: hlo(case, d, nfields) for d in ("forward", "backward")}
+
+def both(case, nfields):
+    return "\n".join(hlo(case, d, nfields) for d in ("backward", "forward"))
+
 for case, nfields in (("fused-int8", 1), ("pruned-r2c", 3)):
-    out["scoped"][case] = hlo(case, "backward", nfields)
+    out["scoped"][case] = both(case, nfields)
     real = spans.scope
     spans.scope = lambda name: contextlib.nullcontext()
     try:
-        out["plain"][case] = hlo(case, "backward", nfields)
+        out["plain"][case] = both(case, nfields)
     finally:
         spans.scope = real
+
+from repro.core import fftcore
+
+def unreachable(*args, **kwargs):
+    raise AssertionError("an unpruned plan reached the pruning")
+pruning = fftcore._keep_centered, fftcore._scatter_centered
+fftcore._keep_centered = fftcore._scatter_centered = unreachable
+try:
+    out["unpruned"] = {d: hlo("fused-complex64", d) for d in ("forward", "backward")}
+finally:
+    fftcore._keep_centered, fftcore._scatter_centered = pruning
 print("HLO=" + json.dumps(out))
 """
 
@@ -121,14 +136,22 @@ def test_every_op_of_an_exchange_sits_under_its_kind(compiled, case):
     payload = case.rsplit("-", 1)[1]
     for direction, text in compiled["ops"][case].items():
         kinds = {}
+        pruned = 0  # slices, concatenates and pads under ``prune``
         for name, rtype, opcode, op_name in instructions(text):
             kind = innermost_kind(op_name)
             kinds.setdefault(kind, 0)
             kinds[kind] += 1
             if opcode.startswith("all-to-all"):
                 assert kind == "a2a", (direction, name, op_name)
-            if op_name.endswith("/gather"):  # the pruning's jnp.take
-                assert kind == "prune", (direction, name, op_name)
+            if case == "pruned-r2c":
+                # the pruning is static slices: no gather, no loop, and each
+                # slice, concatenate or pad is the pruning's, the c2r's
+                # Hermitian extension's or the padded axis's, under its kind
+                assert opcode not in ("gather", "while"), (direction, name, op_name)
+                assert not op_name.endswith("/gather"), (direction, name, op_name)
+                if op_name.split("/")[-1] in ("slice", "concatenate", "pad"):
+                    assert kind in ("prune", "c2r_extend", "repad"), (direction, name, op_name)
+                    pruned += kind == "prune"
             if op_name and opcode != "constant" and re.match(r"\(?(bf16|s8)\[", rtype):
                 # the narrow wire payload (the CPU compiler's own widening
                 # converts carry no op_name)
@@ -147,6 +170,7 @@ def test_every_op_of_an_exchange_sits_under_its_kind(compiled, case):
             assert kinds.get("encode", 0) > 0 and kinds.get("decode", 0) > 0, direction
         if case == "pruned-r2c":
             assert kinds.get("prune", 0) > 0 and kinds.get("repad", 0) > 0
+            assert pruned > 0, direction
             if direction == "backward":
                 assert kinds.get("c2r_extend", 0) > 0
         if case == "fused-int8":  # the guarded plan
@@ -167,14 +191,25 @@ def _without_metadata(text: str) -> list[str]:
 
 @pytest.mark.parametrize("case", ["fused-int8", "pruned-r2c"])
 def test_names_change_metadata_only(compiled, case):
-    """Compiled with every scope a null context, the optimized HLO is the
-    same, op for op."""
+    """Compiled with every scope a null context, the optimized HLO of both
+    directions is the same, op for op."""
     scoped = _without_metadata(compiled["scoped"][case])
     plain = _without_metadata(compiled["plain"][case])
     assert "pfft.bwd" in compiled["scoped"][case]
     assert "pfft.bwd" not in compiled["plain"][case]
     assert len(scoped) > 100
     assert scoped == plain
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_unpruned_plan_emits_no_pruning(compiled, direction):
+    """A plain c2c plan has no op under ``prune``, and compiles to the same
+    HLO, op for op, with the pruning helpers made unreachable."""
+    text = compiled["ops"]["fused-complex64"][direction]
+    assert not any(innermost_kind(op) == "prune" for *_, op in instructions(text))
+    ops = _without_metadata(text)
+    assert len(ops) > 50
+    assert ops == _without_metadata(compiled["unpruned"][direction])
 
 
 _RECORDER = r"""

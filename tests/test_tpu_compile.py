@@ -154,3 +154,31 @@ def test_kernel_names_reach_the_lowered_module(one_chip, name):
             q, axis=0, m=2, scale=s[0] if s else None, codec=codec, iscomplex=True,
             interpret=False)).lower(sds((2, 256, 128, 512), wire), *scale)
     assert name in lowered.as_text()
+
+
+@pytest.mark.parametrize("direction", ["backward", "forward"])
+def test_pruning_compiles_to_no_loop(one_chip, direction):
+    """The DNS cell's dealiased plan (``pruned, pruned, r2c``), small (N = 32
+    modes on M = 48 points, 3 fields), compiled for the chip: the pruning is
+    static slices, so no ``gather`` and no ``while`` loop of row copies is
+    left in the program, and its ops keep their ``stage{i}.prune`` names."""
+    from test_spans import innermost_kind, instructions
+
+    from repro.core.fftcore import TransformSpec
+    from repro.core.meshutil import make_mesh
+    from repro.core.pfft import ParallelFFT
+
+    n, m = 32, 48
+    mesh = make_mesh((1, 1), ("p0", "p1"), devices=list(one_chip.device_set))
+    plan = ParallelFFT(mesh, (m, m, m), ("p0", "p1"), transforms=(
+        TransformSpec.pruned(n), TransformSpec.pruned(n), TransformSpec.r2c(n // 2 + 1)))
+    if direction == "backward":
+        x = jax.ShapeDtypeStruct((3, n, n, n // 2 + 1), jnp.complex64,
+                                 sharding=plan.output_pencil.batched_sharding(1))
+    else:
+        x = jax.ShapeDtypeStruct((3, m, m, m), jnp.float32,
+                                 sharding=plan.input_pencil.batched_sharding(1))
+    text = jax.jit(getattr(plan, direction)).lower(x).compile().as_text()
+    ops = [(opcode, innermost_kind(op_name)) for _, _, opcode, op_name in instructions(text)]
+    assert not [op for op in ops if op[0] in ("gather", "while")], direction
+    assert any(kind == "prune" for _, kind in ops), direction

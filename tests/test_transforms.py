@@ -141,6 +141,92 @@ def test_local_trig_transforms_vs_scipy():
                     np.testing.assert_allclose(back, x, rtol=1e-4, atol=1e-4)
 
 
+#: (spec, n, axis, nbatch): even and odd keeps, a keep of one mode (no
+#: negative half), the whole spectrum, r2c keeps below and at n//2+1, and
+#: a stacked field axis
+PRUNE_CASES = {
+    "c2c-even": (TransformSpec.pruned(8), 12, 1, 0),
+    "c2c-odd": (TransformSpec.pruned(7), 12, 0, 0),
+    "c2c-one": (TransformSpec.pruned(1), 6, 2, 0),
+    "c2c-whole": (TransformSpec.pruned(8), 8, 1, 0),
+    "c2c-stacked": (TransformSpec.pruned(8), 12, 1, 1),
+    "r2c-pruned": (TransformSpec.r2c(5), 12, 2, 0),
+    "r2c-whole": (TransformSpec.r2c(7), 12, 2, 0),
+    "r2c-stacked": (TransformSpec.r2c(5), 12, 1, 1),
+}
+
+
+def _bits(a):
+    return np.ascontiguousarray(a).view(np.uint32)
+
+
+def _primitives(jaxpr):
+    """Every primitive of a jaxpr, sub-jaxprs included."""
+    import jax
+
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(sub)
+
+
+@pytest.mark.parametrize("case", sorted(PRUNE_CASES))
+def test_pruning_is_exact_static_slicing(case):
+    """The keep, the zero-scatter and the r2c keep equal a NumPy ``np.take``
+    of the retained modes bit for bit, and a pruned stage traces no
+    ``gather`` in either direction."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import fftcore
+
+    spec, n, axis, nbatch = PRUNE_CASES[case]
+    k = spec.spectral_extent(n)
+    ax = axis + nbatch
+    full = TransformSpec(spec.kind)
+    if spec.kind == "c2c":
+        kept = np.r_[0:(k + 1) // 2, n - k // 2:n]
+    else:
+        kept = np.arange(k)
+    rng = np.random.default_rng(0)
+    shape = [5, 6, 7]
+    shape[axis] = n
+    shape = [2] * nbatch + shape
+    x = rng.standard_normal(shape).astype(np.float32)
+    if spec.kind == "c2c":
+        x = (x + 1j * rng.standard_normal(shape)).astype(np.complex64)
+
+    def stage(v, sign, s):
+        return fftcore.local_transform(jnp.asarray(v), axis, sign, s, n=n, nbatch=nbatch)
+
+    got = np.asarray(stage(x, fftcore.FORWARD, spec))
+    want = np.take(np.asarray(stage(x, fftcore.FORWARD, full)), kept, axis=ax)
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+
+    # the retained modes back in place: a take of them and one zero
+    y = rng.standard_normal(got.shape) + 1j * rng.standard_normal(got.shape)
+    y = y.astype(np.complex64)
+    y.flat[::5] = -0.0  # signed zeros keep their sign
+    slot = np.full(full.spectral_extent(n), k)
+    slot[kept] = np.arange(k)
+    zero_shape = list(y.shape)
+    zero_shape[ax] = 1
+    scattered = np.take(np.concatenate([y, np.zeros(zero_shape, y.dtype)], axis=ax), slot,
+                        axis=ax)
+    got = np.asarray(stage(y, fftcore.BACKWARD, spec))
+    want = np.asarray(stage(scattered, fftcore.BACKWARD, full))
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    if spec.kind == "c2c":
+        np.testing.assert_array_equal(
+            _bits(fftcore._keep_centered(jnp.asarray(scattered), ax, k)), _bits(y))
+        np.testing.assert_array_equal(
+            _bits(fftcore._scatter_centered(jnp.asarray(y), ax, n, k)), _bits(scattered))
+
+    for sign, v in ((fftcore.FORWARD, x), (fftcore.BACKWARD, y)):
+        jaxpr = jax.make_jaxpr(lambda v, sign=sign: stage(v, sign, spec))(v)
+        assert "gather" not in set(_primitives(jaxpr.jaxpr)), (case, sign)
+
+
 # ---------------------------------------------------------------------------
 # Distributed plans (subprocess, 8 fake devices)
 # ---------------------------------------------------------------------------
