@@ -817,8 +817,10 @@ def _run_stages(block, *, stages, pencils, schedule, impl, sign, nbatch=0,
 
     The work is named in the program (:mod:`repro.core.spans`): the whole
     executor under ``pfft.fwd``/``pfft.bwd``, stage ``i``'s work under
-    ``stage{i}.<kind>``."""
-    with spans.direction(sign):
+    ``stage{i}.<kind>``; its all-to-alls are counted in the executor's
+    record of :func:`repro.core.spans.exchange_totals`, keyed by what fixes
+    them."""
+    with spans.executor(sign, repr((block.shape, block.dtype, stages, schedule, guard))):
         cur = pencils[0]
         per_stage = []
         lossy = guard and _health.schedule_is_lossy(as_schedule(schedule))

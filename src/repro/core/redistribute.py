@@ -107,6 +107,18 @@ Impl = str  # "jnp" | "pallas" (exchange-local implementation, see planconfig)
 PIPELINE_CHUNK_CANDIDATES = (2, 4, 8)
 
 
+def _a2a(x: jax.Array, axis_name, *, split_axis: int, concat_axis: int,
+         wire: str = "payload") -> jax.Array:
+    """Every engine's all-to-all: ``lax.all_to_all(..., tiled=True)``,
+    counted in the traced executor's exchange record
+    (:func:`repro.core.spans.count_all_to_all`; ``wire="scale"`` for int8's
+    scale exchange), then the received buffer's fault tap."""
+    spans.count_all_to_all(x, _axis_size(axis_name), scale=wire == "scale")
+    out = lax.all_to_all(x, axis_name, split_axis=split_axis,
+                         concat_axis=concat_axis, tiled=True)
+    return _faults.tap_wire(out, wire)
+
+
 def _all_to_all_comm(
     y: jax.Array,
     axis_name,
@@ -162,9 +174,7 @@ def _all_to_all_comm(
     if d == "complex64":
         stats = _health.zero_stats() if guard else None
         with spans.kind("a2a"):
-            out = lax.all_to_all(y, axis_name, split_axis=split_axis,
-                                 concat_axis=concat_axis, tiled=True)
-            out = _faults.tap_wire(out, "payload")
+            out = _a2a(y, axis_name, split_axis=split_axis, concat_axis=concat_axis)
         return (out, stats) if guard else out
     iscomplex = jnp.iscomplexobj(y)
     if impl == "pallas":
@@ -179,14 +189,11 @@ def _all_to_all_comm(
                 guard=guard, scale_div=sd)
         with spans.kind("a2a"):
             # payload is (P, *y.shape) re/im planes: split/concat shift past P
-            qx = lax.all_to_all(q, axis_name, split_axis=split_axis + 1,
-                                concat_axis=concat_axis + 1, tiled=True)
-            qx = _faults.tap_wire(qx, "payload")
+            qx = _a2a(q, axis_name, split_axis=split_axis + 1,
+                      concat_axis=concat_axis + 1)
             sx = None
             if scale is not None:  # int8: (F, M) per-(field, chunk) scales
-                sx = lax.all_to_all(scale, axis_name, split_axis=1,
-                                    concat_axis=1, tiled=True)
-                sx = _faults.tap_wire(sx, "scale")
+                sx = _a2a(scale, axis_name, split_axis=1, concat_axis=1, wire="scale")
         with spans.kind("decode"):
             out = _xk.decode_payload(qx, axis=concat_axis, m=m,
                                      nbatch=len(batch_axes), scale=sx, codec=d,
@@ -202,8 +209,7 @@ def _all_to_all_comm(
             stats = _health.payload_stats(planes) if guard else None
             wire = quant.encode_bf16(planes)
         with spans.kind("a2a"):
-            p = lax.all_to_all(wire, axis_name, split_axis=sa, concat_axis=ca, tiled=True)
-            p = _faults.tap_wire(p, "payload")
+            p = _a2a(wire, axis_name, split_axis=sa, concat_axis=ca)
         with spans.kind("decode"):
             p = quant.decode_bf16(p)
             out = quant.planes_to_complex(p) if iscomplex else p[0]
@@ -231,10 +237,8 @@ def _all_to_all_comm(
         # scale keepdims (view coords) -> planes coords: drop the nv//m axis
         s = scale.reshape([e for i, e in enumerate(scale.shape) if i != sa + 1])
     with spans.kind("a2a"):
-        qx = lax.all_to_all(q, axis_name, split_axis=sa, concat_axis=ca, tiled=True)
-        sx = lax.all_to_all(s, axis_name, split_axis=sa, concat_axis=ca, tiled=True)
-        qx = _faults.tap_wire(qx, "payload")
-        sx = _faults.tap_wire(sx, "scale")
+        qx = _a2a(q, axis_name, split_axis=sa, concat_axis=ca)
+        sx = _a2a(s, axis_name, split_axis=sa, concat_axis=ca, wire="scale")
     with spans.kind("decode"):
         # received chunk j along the concat axis was quantized with sender j's
         # scale: view ca as (m, ca_out/m) and broadcast sx over the chunk
@@ -329,14 +333,10 @@ def exchange_shard(
                     block, axis=bv, m=m, nbatch=nbatch, codec=d, guard=guard,
                     scale_div=sd)
             with spans.kind("a2a"):
-                y = lax.all_to_all(payload, axis_name, split_axis=0,
-                                   concat_axis=0, tiled=True)
-                y = _faults.tap_wire(y, "payload")
+                y = _a2a(payload, axis_name, split_axis=0, concat_axis=0)
                 sx = None
                 if scale is not None:  # int8: (M, F) scales, chunk-major like the payload
-                    sx = lax.all_to_all(scale, axis_name, split_axis=0,
-                                        concat_axis=0, tiled=True)
-                    sx = _faults.tap_wire(sx, "scale")
+                    sx = _a2a(scale, axis_name, split_axis=0, concat_axis=0, wire="scale")
             with spans.kind("decode"):
                 out = _xk.unpack_chunks(y, w=w, m=m, nbatch=nbatch,
                                         scale=sx, codec=d,

@@ -34,6 +34,11 @@ The compile recorder (:func:`compile_totals`), installed on import,
 listens to ``jax.monitoring``: seconds spent tracing and lowering, in
 XLA's compile (a persistent-cache load included, which JAX times inside
 it), and the persistent cache's hits and misses, for the whole process.
+
+The exchange counter (:func:`exchange_totals`) is written while an
+executor is traced: for each traced executor, its all-to-all launches and
+the bytes each device sends to the others.  Plain Python at trace time,
+it adds no op and no metadata to the program.
 """
 
 from __future__ import annotations
@@ -45,6 +50,7 @@ import functools
 import threading
 
 import jax
+import numpy as np
 from jax import monitoring
 
 FORWARD = "pfft.fwd"
@@ -59,10 +65,25 @@ def scope(name: str):
     return jax.named_scope(name)
 
 
-def direction(sign: int):
-    """Scope of one executor: ``pfft.fwd`` for a forward sign (< 0),
-    ``pfft.bwd`` otherwise."""
-    return scope(FORWARD if sign < 0 else BACKWARD)
+@contextlib.contextmanager
+def executor(sign: int, key: str):
+    """Scope of one executor, ``pfft.fwd`` for a forward sign (< 0) and
+    ``pfft.bwd`` otherwise, and its record in the exchange counter: the
+    all-to-alls traced inside are counted under ``"<direction> <key>"``,
+    ``key`` naming what the executor exchanges.  On a clean exit a record
+    with any exchange replaces what an earlier trace of the same executor
+    left."""
+    name = FORWARD if sign < 0 else BACKWARD
+    rec = {"direction": name, "launches": 0, "scale_launches": 0, "bytes": 0}
+    token = _exchange.set(rec)
+    try:
+        with scope(name):
+            yield
+    finally:
+        _exchange.reset(token)
+    if rec["launches"] + rec["scale_launches"]:
+        with _lock:
+            _exchanges[f"{name} {key}"] = rec
 
 
 @contextlib.contextmanager
@@ -96,6 +117,39 @@ def under(name: str):
                 return fn(*args, **kwargs)
         return scoped
     return wrap
+
+
+# ---------------------------------------------------------------------------
+# exchange counter
+
+_exchange: contextvars.ContextVar[dict | None] = contextvars.ContextVar(
+    "repro_exchange", default=None)
+_exchanges: dict[str, dict] = {}
+
+
+def count_all_to_all(x, m: int, *, scale: bool = False):
+    """Count one tiled all-to-all of ``x`` over an axis of ``m`` devices in
+    the record of the executor being traced: one launch (``scale``: the
+    int8 scale exchange, counted apart), and ``x``'s bytes × (m−1)/m, what
+    each device sends to the others at the wire's dtype.  Nothing outside
+    an executor, or where ``m`` is 1 and nothing leaves the device."""
+    rec = _exchange.get()
+    if rec is None or m <= 1:
+        return
+    rec["scale_launches" if scale else "launches"] += 1
+    nbytes = int(np.prod(x.shape, dtype=np.int64)) * np.dtype(x.dtype).itemsize
+    rec["bytes"] += nbytes * (m - 1) // m
+
+
+def exchange_totals() -> dict:
+    """``{"<direction> <executor>": {"direction", "launches",
+    "scale_launches", "bytes"}}``: one record per executor traced in this
+    process that exchanged anything, its latest trace's.  ``direction`` is
+    ``pfft.fwd`` or ``pfft.bwd``; ``launches`` counts the payload
+    all-to-alls, ``scale_launches`` int8's scale exchanges; ``bytes`` is
+    what one device sends to the others in one run of the executor."""
+    with _lock:
+        return {k: dict(v) for k, v in _exchanges.items()}
 
 
 # ---------------------------------------------------------------------------
