@@ -23,7 +23,8 @@ workloads):
 ``pruned`` / ``n_keep`` — truncated spectrum: the forward transform keeps
                      only ``n_keep`` retained modes (centered ±k/2 split
                      for c2c, the leading bins for r2c); backward
-                     zero-scatters them back before the inverse transform.
+                     zero-scatters them back before the inverse transform
+                     (an r2c axis's zeros are built into its c2r spectrum).
                      With ``n = 3·n_keep/2`` this is exactly the 3/2-rule
                      dealiased transform of pseudo-spectral solvers.
 
@@ -173,8 +174,8 @@ def local_transform(x, axis: int, sign: int, spec: TransformSpec, *, n: int,
     here is axis-generic, so the batch rides for free).
 
     The work is named in the program (:mod:`repro.core.spans`): the
-    transform ``xform``, the pruning ``prune``, the c2r's Hermitian
-    extension ``c2r_extend``.
+    transform ``xform``, the pruning ``prune``, the c2r's spectrum
+    building and unpacking ``c2r_extend``.
     """
     axis = axis + nbatch
     if spec.kind == "c2c":
@@ -195,12 +196,7 @@ def local_transform(x, axis: int, sign: int, spec: TransformSpec, *, n: int,
                 with spans.kind("prune"):
                     y = lax.slice_in_dim(y, 0, spec.n_keep, axis=axis)
             return y
-        if spec.n_keep is not None and spec.n_keep < nbins:
-            pads = [(0, 0)] * x.ndim
-            pads[axis] = (0, nbins - spec.n_keep)
-            with spans.kind("prune"):
-                x = jnp.pad(x, pads)
-        return _irfft(x, axis, n, impl)
+        return _irfft(x, axis, n, impl, nbatch)
 
     # dct / dst: real-to-real, forward type 2 or 3, backward its inverse
     inverse = sign == BACKWARD
@@ -235,18 +231,86 @@ def _rfft(x, axis, impl):
     raise ValueError(f"unknown fft impl {impl!r}")
 
 
-def _irfft(x, axis, n, impl):
-    """c2r: the real part of the inverse complex DFT of ``x``'s Hermitian
-    extension to ``n`` bins (the imaginary parts of the DC and Nyquist
-    bins drop out, as in ``numpy.fft.irfft``).  XLA's own IRFFT is not
-    used: on a TPU v5e it returned a (384, 384, 193) -> n=384 c2r along
-    the last axis at relative L2 error 0.35, where this form gives 1.3e-7."""
+def _irfft(x, axis, n, impl, nbatch):
+    """c2r: the real inverse DFT of length ``n`` of the ``x.shape[axis]``
+    leading bins of a Hermitian spectrum, the bins past them zero (a
+    pruned r2c axis's zero-pad, folded in).  The imaginary parts of the DC
+    and Nyquist bins drop out, as in ``numpy.fft.irfft``.
+
+    Two real rows share one complex inverse FFT: the block is split in
+    halves A and B along the axis :func:`_c2r_split` picks, and
+    ``ifft(A_ext + i·B_ext)`` is ``a + i·b`` for the Hermitian extensions
+    of A and B.  A block with no such axis takes one inverse FFT per row
+    of its own extension.
+
+    XLA's own IRFFT is not used: on a TPU v5e it returned a (384, 384,
+    193) -> n=384 c2r along the last axis at relative L2 error 0.35, where
+    the extension form gives 1.3e-7."""
+    split = _c2r_split(x.shape, axis, nbatch)
+    if split is None:
+        return _c2r_full(x, axis, n, impl)
+    return _c2r_packed(x, axis, n, impl, split)
+
+
+def _c2r_split(shape, axis, nbatch):
+    """The axis whose halves share the c2r's inverse FFTs: the outermost
+    spatial axis of even length other than the c2r ``axis``, or None.
+    The ``nbatch`` leading batch axes are never split: a packed pair
+    shares rounding of the order of its larger row, so pairing a small
+    field with a large one would cost the small one the digits of their
+    ratio."""
+    return next((a for a in range(nbatch, len(shape)) if a != axis and shape[a] % 2 == 0),
+                None)
+
+
+def _c2r_full(x, axis, n, impl):
+    """One inverse FFT per row, of the row's own Hermitian extension: the
+    form of a block with no even spatial axis to split (1-D, or every
+    other spatial axis odd), where packing would need a zero partner row
+    and a pad and a slice pass over the block to make one."""
     with spans.kind("c2r_extend"):
-        tail = jnp.flip(jnp.conj(lax.slice_in_dim(x, 1, n - n // 2, axis=axis)), axis)
-        x = jnp.concatenate([x, tail], axis=axis)
-    y = _fft(x, axis, BACKWARD, impl)
+        z = _hermitian_extension(x, jnp.conj(x), axis, n)
+    y = _fft(z, axis, BACKWARD, impl)
     with spans.kind("c2r_extend"):
         return jnp.real(y)
+
+
+def _c2r_packed(x, axis, n, impl, split):
+    """One inverse FFT per pair of rows, the halves of ``split``."""
+    m = x.shape[axis]
+    with spans.kind("c2r_extend"):
+        half = x.shape[split] // 2
+        a = lax.slice_in_dim(x, 0, half, axis=split)
+        b = lax.slice_in_dim(x, half, 2 * half, axis=split)
+        ar, ai, br, bi = jnp.real(a), jnp.imag(a), jnp.real(b), jnp.imag(b)
+        # A + iB, without the DC and Nyquist bins' imaginary parts, which
+        # would leak from A into B's output and from B into A's
+        k = lax.broadcasted_iota(jnp.int32, [m if d == axis else 1 for d in range(x.ndim)],
+                                 axis)
+        edge = (k == 0) | (k == n // 2) if n % 2 == 0 else k == 0
+        head = lax.complex(ar - jnp.where(edge, 0, bi), jnp.where(edge, 0, ai) + br)
+        mirror = lax.complex(ar + bi, br - ai)  # conj(A) + i conj(B)
+        z = _hermitian_extension(head, mirror, axis, n)
+    y = _fft(z, axis, BACKWARD, impl)
+    with spans.kind("c2r_extend"):
+        return jnp.concatenate([jnp.real(y), jnp.imag(y)], axis=split)
+
+
+def _hermitian_extension(head, mirror, axis, n):
+    """The ``n`` bins of a c2r spectrum from its ``m`` kept bins: bins
+    ``0..m-1`` from ``head``, zeros, and bin ``n-k`` from ``mirror[k]``
+    for each kept ``k >= 1`` below ``n - n//2``.  With ``mirror =
+    conj(head)`` this is the Hermitian extension of the kept bins."""
+    m = head.shape[axis]
+    t = min(m - 1, n - n // 2 - 1)
+    parts = [head]
+    if n - m - t:
+        shape = list(head.shape)
+        shape[axis] = n - m - t
+        parts.append(jnp.zeros(shape, head.dtype))
+    if t:
+        parts.append(jnp.flip(lax.slice_in_dim(mirror, 1, 1 + t, axis=axis), axis))
+    return jnp.concatenate(parts, axis=axis) if len(parts) > 1 else head
 
 
 # -- pruning (truncated spectra / 3/2-rule dealiasing) ----------------------

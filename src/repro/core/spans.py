@@ -10,9 +10,12 @@ called on its own).  The kinds:
 ``xform``      the 1-D transform proper (FFT, its inverse, DCT/DST pre- and
                post-processing, the four-step kernel);
 ``prune``      the truncated spectrum's keep and zero-scatter, the r2c keep
-               and zero-pad;
-``c2r_extend`` the c2r's Hermitian extension (flip, conj, concatenate) and
-               its real part;
+               (its zero-pad is the c2r's);
+``c2r_extend`` the c2r's work around its inverse FFT: building the
+               spectrum from the kept bins (the folded zero-pad, the
+               Hermitian mirror, the packing of two real rows into one
+               complex row) and unpacking the result (real and imaginary
+               parts, their concatenate);
 ``repad``      the slice to the logical extent and the pad back to the
                physical one around a stage;
 ``encode``     all local work of an exchange before its collective (pack,

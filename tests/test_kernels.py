@@ -87,6 +87,90 @@ def test_c2r_matches_numpy_irfft(impl, n):
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-5)
 
 
+#: (other axes with the c2r axis at ``axis``, n, n_keep, axis, nbatch,
+#: the axis whose halves are packed together, None for one FFT per row)
+C2R_CASES = {
+    "even-split": ((4, 6), 12, None, 2, 0, 0),
+    "even-inner-split": ((3, 6), 12, None, 0, 0, 2),
+    "odd-others": ((3, 5), 12, None, 1, 0, None),
+    "one-d": ((), 12, None, 0, 0, None),
+    "odd-n": ((4, 2), 13, None, 1, 0, 0),
+    "odd-n-full": ((3,), 385, None, 1, 0, None),
+    "pruned-packed": ((6, 3), 12, 5, 2, 0, 0),
+    "pruned-odd-n": ((2,), 15, 5, 1, 0, 0),
+    "pruned-full": ((5, 3), 48, 17, 0, 0, None),
+    "stacked": ((3, 6, 5), 48, 17, 2, 1, 1),
+    "stacked-all-odd": ((9, 5, 3), 48, 25, 2, 1, None),
+    "stacked-even-fields": ((4, 6, 3), 12, None, 2, 1, 1),
+    "stacked-even-fields-odd-space": ((2, 5, 3), 12, None, 2, 1, None),
+}
+
+
+@pytest.mark.parametrize("impl", ["jnp", "matmul"])
+@pytest.mark.parametrize("case", sorted(C2R_CASES))
+def test_packed_c2r_matches_numpy_irfft(impl, case):
+    """The c2r packs two real rows into one complex inverse FFT where the
+    block has a spatial axis of even length besides the c2r axis (the
+    outermost; batch axes are never split), and falls back to one per row
+    where it has none: both equal numpy's irfft of the kept bins
+    zero-padded to ``n//2+1``, on a non-Hermitian spectrum (complex DC and
+    Nyquist bins)."""
+    from repro.core import fftcore
+
+    others, n, n_keep, axis, nbatch, split = C2R_CASES[case]
+    m = n // 2 + 1 if n_keep is None else n_keep
+    shape = list(others)
+    shape.insert(axis + nbatch, m)
+    assert fftcore._c2r_split(tuple(shape), axis + nbatch, nbatch) == split
+    rng = np.random.default_rng(len(case) * n)
+    spec = (rng.standard_normal(shape) + 1j * rng.standard_normal(shape)
+            ).astype(np.complex64)
+    got = local_transform(jnp.asarray(spec), axis, BACKWARD, TransformSpec.r2c(n_keep),
+                          n=n, impl=impl, nbatch=nbatch)
+    pads = [(0, 0)] * spec.ndim
+    pads[axis + nbatch] = (0, n // 2 + 1 - m)
+    want = np.fft.irfft(np.pad(spec.astype(np.complex128), pads), n=n, axis=axis + nbatch)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("impl", ["jnp", "matmul"])
+@pytest.mark.parametrize("others", [(2, 6, 5), (2, 5, 3)], ids=["packed", "full"])
+def test_c2r_keeps_each_fields_precision(impl, others):
+    """Two stacked fields, the second 1e-5 times the first: each keeps its
+    own relative precision, because the packed c2r pairs rows of one field
+    (here halves of its even spatial axis), never rows of two fields."""
+    n, m = 12, 7
+    shape = (*others, m)
+    rng = np.random.default_rng(17)
+    spec = rng.standard_normal(shape) + 1j * rng.standard_normal(shape)
+    spec[1] *= 1e-5
+    spec = spec.astype(np.complex64)
+    got = np.asarray(local_transform(jnp.asarray(spec), 2, BACKWARD, TransformSpec.r2c(),
+                                     n=n, impl=impl, nbatch=1))
+    want = np.fft.irfft(spec.astype(np.complex128), n=n, axis=3)
+    for f in range(2):
+        err = np.linalg.norm(got[f] - want[f]) / np.linalg.norm(want[f])
+        assert err < 2e-6, (f, err)
+
+
+@pytest.mark.parametrize("impl", ["jnp", "matmul"])
+@pytest.mark.parametrize("n", [12, 13])
+def test_packed_c2r_drops_edge_imaginary_parts(impl, n):
+    """A spectrum of only a DC and a Nyquist bin, each with an imaginary
+    part, in one half of the packed pair: nothing leaks into the other
+    half, whose spectrum is zero."""
+    m = n // 2 + 1
+    spec = np.zeros((2, m), np.complex64)
+    spec[0, 0] = 1.5 + 2.0j
+    spec[0, m - 1] = -0.5 + 3.0j  # Nyquist for even n, an ordinary bin for odd
+    got = np.asarray(local_transform(jnp.asarray(spec), 1, BACKWARD, TransformSpec.r2c(),
+                                     n=n, impl=impl))
+    want = np.fft.irfft(spec.astype(np.complex128), n=n, axis=1)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    assert np.abs(got[1]).max() < 1e-6
+
+
 @pytest.mark.parametrize("block_b", [1, 4, 16])
 def test_fft_matmul_block_invariance(block_b):
     rng = np.random.default_rng(3)

@@ -182,3 +182,50 @@ def test_pruning_compiles_to_no_loop(one_chip, direction):
     ops = [(opcode, innermost_kind(op_name)) for _, _, opcode, op_name in instructions(text)]
     assert not [op for op in ops if op[0] in ("gather", "while")], direction
     assert any(kind == "prune" for _, kind in ops), direction
+
+
+def _cost(compiled):
+    cost = compiled.cost_analysis()
+    return cost[0] if isinstance(cost, list) else cost
+
+
+def test_packed_c2r_halves_the_work(one_chip):
+    """The DNS cell's 9-field c2r block, (9, 384, 384, 129) kept bins to
+    n = 384 along the last axis: packed two rows to a complex inverse FFT,
+    it costs at most 0.55x the FLOPs of one inverse FFT per row of the
+    full Hermitian extension, in fewer temporaries."""
+    from repro.core import fftcore
+
+    x = jax.ShapeDtypeStruct((9, 384, 384, 129), jnp.complex64, sharding=one_chip)
+    packed = jax.jit(lambda b: fftcore._irfft(b, 3, 384, "jnp", 1)).lower(x).compile()
+    full = jax.jit(lambda b: fftcore._c2r_full(b, 3, 384, "jnp")).lower(x).compile()
+    assert _cost(packed)["flops"] <= 0.55 * _cost(full)["flops"]
+    assert (packed.memory_analysis().temp_size_in_bytes
+            < full.memory_analysis().temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("direction", ["backward", "forward"])
+def test_c2c_plan_has_no_c2r_extend(one_chip, direction):
+    """A pure c2c plan, compiled for the chip, holds no op under
+    ``c2r_extend``; the dealiased plan's backward does."""
+    from test_spans import innermost_kind, instructions
+
+    from repro.core.fftcore import TransformSpec
+    from repro.core.meshutil import make_mesh
+    from repro.core.pfft import ParallelFFT
+
+    mesh = make_mesh((1, 1), ("p0", "p1"), devices=list(one_chip.device_set))
+    kinds = {}
+    for name, m, transforms in (
+            ("c2c", 32, (TransformSpec.c2c(),) * 3),
+            ("dealiased", 48, (TransformSpec.pruned(32), TransformSpec.pruned(32),
+                               TransformSpec.r2c(17)))):
+        plan = ParallelFFT(mesh, (m, m, m), ("p0", "p1"), transforms=transforms)
+        pen, dt = ((plan.input_pencil, plan.input_dtype) if direction == "forward"
+                   else (plan.output_pencil, plan.spectral_dtype))
+        x = jax.ShapeDtypeStruct((3, *pen.physical), dt, sharding=pen.batched_sharding(1))
+        text = jax.jit(getattr(plan, direction)).lower(x).compile().as_text()
+        kinds[name] = {innermost_kind(op) for *_, op in instructions(text)}
+    assert "xform" in kinds["c2c"]
+    assert "c2r_extend" not in kinds["c2c"]
+    assert ("c2r_extend" in kinds["dealiased"]) == (direction == "backward")
