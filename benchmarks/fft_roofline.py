@@ -38,7 +38,7 @@ def lower_fft(shape, mesh_shape, axis_names, grid, *, real, method, impl="jnp"):
     from repro.launch.hlo_account import account
 
     mesh = make_mesh(mesh_shape, axis_names)
-    # real=True spelled as an explicit transform list (r2c on the last axis)
+    # real: r2c on the last axis, c2c elsewhere
     transforms = (("c2c",) * (len(shape) - 1) + ("r2c",)) if real else None
     plan = ParallelFFT(mesh, shape, grid, transforms=transforms,
                        config=PlanConfig(method=method, impl=impl))
@@ -46,7 +46,7 @@ def lower_fft(shape, mesh_shape, axis_names, grid, *, real, method, impl="jnp"):
     x = jax.ShapeDtypeStruct(plan.input_pencil.physical, dtype)
 
     def fwd_bwd(v):
-        return plan.backward_padded(plan.forward_padded(v))
+        return plan.executor("backward")(plan.executor("forward")(v))
 
     jfn = jax.jit(fwd_bwd,
                   in_shardings=plan.input_pencil.sharding,

@@ -67,8 +67,7 @@ def build_plan(shape, gridspec, ndev, *, real, method, impl, chunks=4,
     else:
         raise ValueError(gridspec)
     if not transforms and real:
-        # --real sugar, spelled as an explicit transform list (the real=
-        # ParallelFFT kwarg is deprecated)
+        # --real: r2c on the last axis, c2c elsewhere
         transforms = ("c2c",) * (len(shape) - 1) + ("r2c",)
     cfg = PlanConfig(method=method, impl=impl, exchange_impl=exchange_impl,
                      chunks=chunks, comm_dtype=comm_dtype,
@@ -173,15 +172,11 @@ def _time_plan(plan, shape, args):
     x = _make_input(plan, shape, nf)
     from repro.core.pencil import pad_global
 
-    if nf > 1:
-        xg = jax.device_put(pad_global(jnp.asarray(x), plan.input_pencil, nbatch=1),
-                            plan.input_pencil.batched_sharding())
-        fwd = jax.jit(plan.forward_many_padded(nf))
-        bwd = jax.jit(plan.backward_many_padded(nf))
-    else:
-        xg = jax.device_put(pad_global(jnp.asarray(x), plan.input_pencil),
-                            plan.input_pencil.sharding)
-        fwd, bwd = jax.jit(plan.forward_padded), jax.jit(plan.backward_padded)
+    nbatch, stack = (1, nf) if nf > 1 else (0, None)
+    xg = jax.device_put(pad_global(jnp.asarray(x), plan.input_pencil, nbatch=nbatch),
+                        plan.input_pencil.batched_sharding(nbatch))
+    fwd = jax.jit(plan.executor("forward", stack))
+    bwd = jax.jit(plan.executor("backward", stack))
     return _timed(lambda v: bwd(fwd(v)), xg, outer=args.outer, inner=args.inner)
 
 
@@ -194,23 +189,20 @@ def _time_guard_pair(plan, shape, args):
     over a sweep easily exceed the real overhead).  The guarded jits
     return the stats vector, so XLA cannot dead-code-eliminate the guard
     ops — this measures the real ``guard != "off"`` overhead."""
+    from repro.core.pencil import pad_global
+    from repro.core.pfft import ParallelFFT
+
     nf = args.fields
     x = _make_input(plan, shape, nf)
-    from repro.core.pencil import pad_global
-
-    if nf > 1:
-        xg = jax.device_put(pad_global(jnp.asarray(x), plan.input_pencil, nbatch=1),
-                            plan.input_pencil.batched_sharding())
-        ufwd = jax.jit(plan.forward_many_padded(nf))
-        ubwd = jax.jit(plan.backward_many_padded(nf))
-        gfwd = jax.jit(plan.guarded_padded("forward", nfields=nf))
-        gbwd = jax.jit(plan.guarded_padded("backward", nfields=nf))
-    else:
-        xg = jax.device_put(pad_global(jnp.asarray(x), plan.input_pencil),
-                            plan.input_pencil.sharding)
-        ufwd, ubwd = jax.jit(plan.forward_padded), jax.jit(plan.backward_padded)
-        gfwd = jax.jit(plan.guarded_padded("forward"))
-        gbwd = jax.jit(plan.guarded_padded("backward"))
+    gplan = ParallelFFT(plan.mesh, plan.shape, plan.grid, transforms=plan.transforms,
+                        config=plan.config.replace(guard=args.guard))
+    nbatch, stack = (1, nf) if nf > 1 else (0, None)
+    xg = jax.device_put(pad_global(jnp.asarray(x), plan.input_pencil, nbatch=nbatch),
+                        plan.input_pencil.batched_sharding(nbatch))
+    ufwd = jax.jit(plan.executor("forward", stack))
+    ubwd = jax.jit(plan.executor("backward", stack))
+    gfwd = jax.jit(gplan.executor("forward", stack))
+    gbwd = jax.jit(gplan.executor("backward", stack))
 
     def unguarded(v):
         return ubwd(ufwd(v))
@@ -460,7 +452,7 @@ def main(argv=None):
         }
     print(json.dumps({
         "shape": shape, "grid": args.grid, "method": args.method,
-        "comm_dtype": plan.comm_dtype,
+        "comm_dtype": plan.config.comm_dtype,
         "exchange_impl": args.exchange_impl,
         "fields": nf,
         "batch_fusion": args.batch_fusion if nf > 1 else None,

@@ -263,18 +263,6 @@ def _jaxpr_stats(jaxpr) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def _plan_walk(plan, direction: str, schedule4):
-    """(stages, pencils, dtypes, schedule) in execution order."""
-    from repro.core.pfft import _reverse_plan
-
-    if direction == "forward":
-        return plan.stages, plan.pencil_trace, plan.dtype_trace, schedule4
-    if direction == "backward":
-        stages, pencils = _reverse_plan(plan.stages, plan.pencil_trace)
-        return stages, pencils, plan.dtype_trace[::-1], schedule4[::-1]
-    raise ValueError(f"unknown direction {direction!r}")
-
-
 def _stage_payload_multiset(src_pen, v, w, isz, comm_dtype, nfields, fusion,
                             method, chunks, nbatch) -> list[int]:
     """Per-collective wire bytes this stage should put on the wire, one
@@ -327,7 +315,7 @@ def _expected_contract(plan, direction: str, schedule4, nfields: int) -> dict:
         exchange_engine_ops, exchange_wire_bytes, pipeline_slices)
     from repro.kernels.exchange import pallas_applicable
 
-    stages, pencils, dtypes, sched = _plan_walk(plan, direction, schedule4)
+    stages, pencils, dtypes, *_, sched = plan._walk(direction, schedule4)
     nbatch = 1 if nfields > 1 else 0
     per_stage = []
     ex_i = 0
@@ -423,23 +411,13 @@ def audit_plan(plan, *, nfields: int = 1, direction: str = "forward",
         raise ValueError(f"claimed schedule has {len(claimed)} entries for "
                          f"{plan.n_exchanges} exchange stages")
 
-    guard = getattr(plan, "guard", "off")
-    if direction == "forward":
-        in_pen, dtype = plan.input_pencil, plan.input_dtype
-        fn = (plan.forward_many_padded(nfields) if nfields > 1
-              else plan.forward_padded)
-    elif direction == "backward":
-        in_pen, dtype = plan.output_pencil, plan.spectral_dtype
-        fn = (plan.backward_many_padded(nfields) if nfields > 1
-              else plan.backward_padded)
-    else:
-        raise ValueError(f"unknown direction {direction!r}")
-    if guard != "off":
-        # audit the executor a guarded plan actually runs (its (block,
-        # stats) output is fine for make_jaxpr/lower)
-        fn = plan.guarded_padded(direction, nfields=nfields)
-    shape = ((nfields,) if nfields > 1 else ()) + tuple(in_pen.physical)
-    aval = jax.ShapeDtypeStruct(shape, dtype)
+    guard = plan.config.guard
+    walk = plan._walk(direction)
+    # a guarded plan's executor returns (block, stats), which is fine for
+    # make_jaxpr/lower
+    fn = plan.executor(direction, nfields if nfields > 1 else None)
+    shape = ((nfields,) if nfields > 1 else ()) + tuple(walk.in_pencil.physical)
+    aval = jax.ShapeDtypeStruct(shape, walk.dtypes[0])
 
     expected = _expected_contract(plan, direction, claimed, nfields)
     observed = _jaxpr_stats(jax.make_jaxpr(fn)(aval).jaxpr)
@@ -557,7 +535,7 @@ def audit_plan(plan, *, nfields: int = 1, direction: str = "forward",
                 f"wide dtypes in optimized HLO: {observed['hlo_wide_dtypes']}"))
 
     return AuditReport(
-        label=label or f"{plan.shape}:{plan.method}", direction=direction,
+        label=label or f"{plan.shape}:{plan.config.method}", direction=direction,
         nfields=nfields, schedule=list(claimed), expected=expected,
         observed=observed, collectives=collectives, violations=violations)
 

@@ -8,8 +8,8 @@ Public API:
                                       "int8" wire payloads)
   exchange_shard_sliced            — the pipelined (sliced) exchange engine
   ParallelFFT                      — slab/pencil/d-dim distributed FFT
-                                     (method="fused"|"traditional"|
-                                      "pipelined"|"auto")
+                                     (config=PlanConfig(method="fused"|
+                                      "traditional"|"pipelined"|"auto"))
   quant                            — shared quantization codecs (bf16/int8)
   tuner                            — per-stage exchange-engine autotuner
 """

@@ -18,8 +18,7 @@ FFTs ("pipelined", comm/compute overlap), or the autotuned per-stage mix
 
 Per-axis transforms (``transforms=``): each axis carries a
 :class:`repro.core.fftcore.TransformSpec` — c2c, r2c, DCT-II/III, DST-II/III,
-or a pruned/truncated spectrum (``n_keep``).  ``real=True`` stays as sugar
-for "r2c on the last axis, c2c elsewhere".  Pruned axes fold 3/2-rule
+or a pruned/truncated spectrum (``n_keep``).  Pruned axes fold 3/2-rule
 dealiasing into the plan itself: the truncation happens inside the FFT
 stage right next to the exchange unpack, so downstream exchanges ship only
 the retained modes (the dealiased Navier–Stokes pipeline pays *less* wire
@@ -31,6 +30,8 @@ every stage and all analytic models read them.
 The whole plan executes inside a single ``shard_map``, so XLA sees the
 entire FFT↔collective pipeline and can schedule/overlap it (the TPU
 equivalent of taking data rearrangement off the critical path).
+:meth:`ParallelFFT.executor` builds that ``shard_map`` once per direction,
+stacking and schedule; every entry point runs through it.
 
 Batched multi-field execution (``forward_many``/``backward_many``): real
 spectral workloads run the *same* plan over many fields at once (the
@@ -62,9 +63,9 @@ comm_dtype, impl, batch_fusion)`` — per stage, cached per batch size
 from __future__ import annotations
 
 import math
-import warnings
 from dataclasses import dataclass
 from functools import cached_property, partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -80,27 +81,8 @@ from repro.core.quant import canonical_comm_dtype
 from repro.core.redistribute import exchange_shard, exchange_shard_sliced
 from repro.robustness import faults as _faults, health as _health
 
-#: StageEntry per ExchangeStage, in forward stage order (legacy raw
-#: 3/4-tuples are upgraded on entry via StageEntry.make — see planconfig)
+#: StageEntry per ExchangeStage, in forward stage order
 Schedule = tuple[StageEntry, ...]
-
-#: alias kept for the batch-aware schedule of a multi-field execution
-#: (see batched_schedule); since StageEntry carries batch_fusion, the two
-#: schedule types are now the same shape
-BatchedSchedule = tuple[StageEntry, ...]
-
-_UNSET = object()
-
-# once-per-process deprecation flags (module state, not per-plan)
-_legacy_kwargs_warned = False
-_real_kwarg_warned = False
-
-
-def _warn_once(flag_name: str, msg: str):
-    g = globals()
-    if not g[flag_name]:
-        g[flag_name] = True
-        warnings.warn(msg, DeprecationWarning, stacklevel=3)
 
 # ---------------------------------------------------------------------------
 # Plan construction
@@ -124,6 +106,20 @@ class ExchangeStage:
 Stage = FFTStage | ExchangeStage
 
 
+class _Walk(NamedTuple):
+    """One direction of a plan in execution order: ``pencils[i]`` and
+    ``dtypes[i]`` describe the block before ``stages[i]``; ``schedule``
+    holds one entry per exchange stage, in the same order."""
+
+    stages: tuple
+    pencils: tuple
+    dtypes: tuple
+    in_pencil: Pencil
+    out_pencil: Pencil
+    sign: int
+    schedule: tuple
+
+
 class ParallelFFT:
     """Plan + executor for a distributed d-dim transform.
 
@@ -136,29 +132,16 @@ class ParallelFFT:
       config: a :class:`~repro.core.planconfig.PlanConfig` carrying every
               execution knob — method, FFT impl, exchange_impl, chunks,
               comm_dtype, batch_fusion, tuner_cache, guard (see its
-              docstring for field semantics).  This is the supported
-              surface; ``config=None`` means ``PlanConfig()`` defaults.
+              docstring for field semantics); ``config=None`` means
+              ``PlanConfig()`` defaults.  The plan reads it as
+              ``plan.config``.
       transforms: per-axis :class:`TransformSpec` (or tag strings "c2c",
-              "r2c", "dct2", "dct3", "dst2", "dst3"), length d.  Transforms
-              are applied in descending axis order; an r2c axis must come
-              before any complex-producing axis in that order (i.e. every
-              axis to its right is dct/dst), and at most one r2c is
-              allowed.  Mutually exclusive with ``real=True``.
+              "r2c", "dct2", "dct3", "dst2", "dst3"), length d; ``None``
+              means c2c on every axis.  Transforms are applied in
+              descending axis order; an r2c axis must come before any
+              complex-producing axis in that order (i.e. every axis to its
+              right is dct/dst), and at most one r2c is allowed.
 
-    Deprecated (still functional, each warns once per process):
-
-      real:   sugar for ``transforms`` = all-c2c with r2c on the last
-              axis; pass the explicit ``transforms=`` spec instead.
-      method / impl / exchange_impl / chunks / comm_dtype / batch_fusion /
-      tuner_cache / guard: the pre-PlanConfig kwarg sprawl.  Passing any
-              of them forwards into ``PlanConfig.from_legacy_kwargs`` (so
-              behavior is identical to the config= path); combining them
-              with ``config=`` is an error.
-
-    The resolved config is ``plan.config``; its fields stay mirrored as
-    ``plan.method`` / ``plan.impl`` / ``plan.exchange_impl`` /
-    ``plan.chunks`` / ``plan.comm_dtype`` / ``plan.batch_fusion`` /
-    ``plan.tuner_cache`` / ``plan.guard`` for downstream consumers.
     Guarded plans' ``forward``/``backward`` (and the ``_many`` variants)
     return ``(result, HealthReport)``.
     """
@@ -171,54 +154,16 @@ class ParallelFFT:
         *,
         config: PlanConfig | None = None,
         transforms=None,
-        real: bool = False,
-        method: str | None = None,
-        impl: str | None = None,
-        exchange_impl: str | None = None,
-        chunks: int | None = None,
-        comm_dtype=_UNSET,
-        batch_fusion: str | None = None,
-        tuner_cache=_UNSET,
-        guard: str | None = None,
     ):
         d, k = len(shape), len(grid)
         if not 1 <= k <= d - 1:
             raise ValueError(f"need 1 <= len(grid)={k} <= d-1={d - 1}")
-        legacy = {k_: v for k_, v in dict(
-            method=method, impl=impl, exchange_impl=exchange_impl,
-            chunks=chunks, batch_fusion=batch_fusion, guard=guard).items()
-            if v is not None}
-        if comm_dtype is not _UNSET:
-            legacy["comm_dtype"] = comm_dtype
-        if tuner_cache is not _UNSET:
-            legacy["tuner_cache"] = tuner_cache
-        if legacy:
-            if config is not None:
-                raise ValueError(
-                    f"pass either config= or the legacy kwargs {sorted(legacy)}, not both")
-            _warn_once(
-                "_legacy_kwargs_warned",
-                f"ParallelFFT execution kwargs ({sorted(legacy)}) are deprecated; "
-                "pass config=PlanConfig(...) instead")
-            config = PlanConfig.from_legacy_kwargs(**legacy)
-        elif config is None:
-            config = PlanConfig()
-        if real:
-            _warn_once(
-                "_real_kwarg_warned",
-                "ParallelFFT(real=True) is deprecated; pass transforms= "
-                "('c2c', ..., 'r2c') instead")
-        if transforms is not None:
-            if real:
-                raise ValueError("pass either real=True or transforms=, not both")
+        if transforms is None:
+            specs = (TransformSpec.c2c(),) * d
+        else:
             specs = tuple(as_spec(s) for s in transforms)
             if len(specs) != d:
                 raise ValueError(f"transforms must have one spec per axis: got {len(specs)}, need {d}")
-        else:
-            specs = tuple(
-                TransformSpec.r2c() if (real and a == d - 1) else TransformSpec.c2c()
-                for a in range(d)
-            )
         # dtype legality in apply order (axis d-1 → 0): r2c must see real data
         seen_complex = False
         for a in range(d - 1, -1, -1):
@@ -232,23 +177,15 @@ class ParallelFFT:
                 seen_complex = True
         self.transforms = specs
         self.mesh, self.shape, self.grid = mesh, tuple(shape), tuple(grid)
-        # config is the source of truth; the mirrors keep every downstream
-        # consumer (tuner, planlint, benchmarks, tests) on its old surface
-        self.config = config
-        self.method, self.impl = config.method, config.impl
-        self.exchange_impl = config.exchange_impl
-        self.chunks, self.tuner_cache = config.chunks, config.tuner_cache
-        self.comm_dtype = config.comm_dtype
-        self.batch_fusion = config.batch_fusion
-        self.guard = config.guard
+        self.config = config if config is not None else PlanConfig()
         self.d, self.k = d, k
-        self._batched_sched_memo: dict[int, BatchedSchedule] = {}
-        self._batched_exec: dict = {}
-        self._guarded_exec: dict = {}
+        self._batched_sched_memo: dict[int, Schedule] = {}
+        self._executors: dict = {}
 
         sizes = [group_size(mesh, g) for g in grid]
         # Per-axis divisibility: every subgroup an axis is ever distributed
-        # over, in either direction of the plan (see DESIGN.md §7).
+        # over, in either direction of the plan: an exchange splits an axis
+        # into equal blocks, so its padded extent must divide by each.
         divisors = [1] * d
         for j in range(k):
             divisors[j] = math.lcm(divisors[j], sizes[j])  # initial placement
@@ -260,7 +197,6 @@ class ParallelFFT:
 
         placement: list[Group | None] = [grid[i] if i < k else None for i in range(d)]
         self.input_pencil = make_pencil(mesh, self.shape, tuple(placement), divisors=tuple(divisors))
-        self._divisors = tuple(divisors)
 
         # input/spectral dtypes: real input iff the first applied transform
         # that produces complex output is r2c (or no axis ever goes complex)
@@ -319,14 +255,14 @@ class ParallelFFT:
         method="auto", where ``comm_dtype`` is the per-stage payload the
         tuner picked within the plan's accuracy budget and ``impl`` is
         swept only within the plan's ``exchange_impl`` candidate budget."""
-        if self.method == "auto":
+        if self.config.method == "auto":
             from repro.core import tuner
 
-            return as_schedule(tuner.get_or_tune(self, cache_path=self.tuner_cache))
+            return as_schedule(tuner.get_or_tune(self, cache_path=self.config.tuner_cache))
         entry = self.config.stage_entry()._replace(batch_fusion="stacked")
         return (entry,) * self.n_exchanges
 
-    def batched_schedule(self, nfields: int) -> BatchedSchedule:
+    def batched_schedule(self, nfields: int) -> Schedule:
         """:class:`StageEntry` per exchange stage for an ``nfields``-field
         execution, forward order.  Explicit methods use the plan's uniform
         ``batch_fusion``; method="auto" tunes the full batch-aware
@@ -334,11 +270,11 @@ class ParallelFFT:
         if nfields <= 1:
             return tuple(e._replace(batch_fusion="stacked") for e in self.schedule)
         if nfields not in self._batched_sched_memo:
-            if self.method == "auto":
+            if self.config.method == "auto":
                 from repro.core import tuner
 
                 sched = as_schedule(tuner.get_or_tune(
-                    self, cache_path=self.tuner_cache, nfields=nfields))
+                    self, cache_path=self.config.tuner_cache, nfields=nfields))
             else:
                 sched = (self.config.stage_entry(),) * self.n_exchanges
             self._batched_sched_memo[nfields] = sched
@@ -346,103 +282,56 @@ class ParallelFFT:
 
     # -- executors ----------------------------------------------------------
 
-    @cached_property
-    def _forward_shard(self):
-        return partial(_run_stages, stages=self.stages, pencils=self.pencil_trace,
-                       schedule=self.schedule, impl=self.impl, sign=fftcore.FORWARD)
+    def _walk(self, direction: str, schedule=()) -> _Walk:
+        """The plan in ``direction``'s execution order, ``schedule`` (given
+        in forward order) included; backward is :func:`_reverse_plan`."""
+        if direction == "forward":
+            return _Walk(self.stages, self.pencil_trace, self.dtype_trace,
+                         self.input_pencil, self.output_pencil, fftcore.FORWARD,
+                         tuple(schedule))
+        if direction == "backward":
+            stages, pencils = _reverse_plan(self.stages, self.pencil_trace)
+            return _Walk(stages, pencils, self.dtype_trace[::-1],
+                         self.output_pencil, self.input_pencil, fftcore.BACKWARD,
+                         tuple(schedule)[::-1])
+        raise ValueError(f"unknown direction {direction!r}")
 
-    @cached_property
-    def _backward_shard(self):
-        stages, pencils = _reverse_plan(self.stages, self.pencil_trace)
-        return partial(_run_stages, stages=stages, pencils=pencils,
-                       schedule=self.schedule[::-1], impl=self.impl,
-                       sign=fftcore.BACKWARD)
+    def executor(self, direction: str, nfields: int | None = None, *,
+                 schedule=None):
+        """The ``shard_map``'d executor of one direction on *physical*
+        (padded) global blocks, built once per (direction, stacking,
+        schedule).
 
-    @cached_property
-    def forward_padded(self):
-        """shard_map'd forward on *physical* (padded) global arrays."""
-        return shard_map(
-            self._forward_shard, mesh=self.mesh,
-            in_specs=self.input_pencil.spec, out_specs=self.output_pencil.spec,
-            check_vma=False,
-        )
-
-    @cached_property
-    def backward_padded(self):
-        return shard_map(
-            self._backward_shard, mesh=self.mesh,
-            in_specs=self.output_pencil.spec, out_specs=self.input_pencil.spec,
-            check_vma=False,
-        )
-
-    def forward_many_padded(self, nfields: int):
-        """shard_map'd batched forward on a ``(nfields, *physical)`` stacked
-        block (leading batch axis replicated; built/cached per batch size)."""
-        return self._many_padded(nfields, "forward")
-
-    def backward_many_padded(self, nfields: int):
-        return self._many_padded(nfields, "backward")
-
-    def _many_padded(self, nfields: int, direction: str):
-        key = (nfields, direction)
-        if key not in self._batched_exec:
-            schedule = self.batched_schedule(nfields)
-            if direction == "forward":
-                stages, pencils = self.stages, self.pencil_trace
-                in_pen, out_pen, sign = self.input_pencil, self.output_pencil, fftcore.FORWARD
-            else:
-                stages, pencils = _reverse_plan(self.stages, self.pencil_trace)
-                schedule = schedule[::-1]
-                in_pen, out_pen, sign = self.output_pencil, self.input_pencil, fftcore.BACKWARD
-            fn = partial(_run_stages, stages=stages, pencils=pencils,
-                         schedule=schedule, impl=self.impl, sign=sign, nbatch=1)
-            self._batched_exec[key] = shard_map(
-                fn, mesh=self.mesh, in_specs=in_pen.batched_spec(),
-                out_specs=out_pen.batched_spec(), check_vma=False)
-        return self._batched_exec[key]
-
-    def guarded_padded(self, direction: str = "forward", *, schedule=None,
-                       nfields: int = 1):
-        """shard_map'd guarded executor on physical (padded) blocks:
-        returns ``fn(block) -> (block, stats)`` where ``stats`` carries
-        every shard's packed guard-stat partial (sharded out_spec, no
-        extra collective); :func:`repro.robustness.health.unpack_partials`
-        sums them for :func:`~repro.robustness.health.build_report`.
-        ``schedule`` overrides the plan's resolved schedule — the
-        degradation ladder re-executes through here with widened entries;
-        executors are cached per (direction, schedule, nfields)."""
+        ``nfields=None`` runs one unstacked block under :attr:`schedule`;
+        an integer runs a stacked ``(nfields, *physical)`` block (leading
+        field axis replicated) under :meth:`batched_schedule`.
+        ``schedule`` (forward order) overrides either — the degradation
+        ladder re-executes through here with widened entries.  A guarded
+        plan's executor returns ``(block, stats)``: ``stats`` carries every
+        shard's packed guard-stat partial (sharded out_spec, no extra
+        collective), which :func:`repro.robustness.health.unpack_partials`
+        sums for :func:`~repro.robustness.health.build_report`."""
+        nbatch = 0 if nfields is None else 1
         if schedule is None:
-            schedule = (self.batched_schedule(nfields) if nfields > 1
-                        else self.schedule)
-        schedule = as_schedule(schedule)
-        key = (direction, schedule, nfields)
-        if key not in self._guarded_exec:
-            nbatch = 1 if nfields > 1 else 0
-            if direction == "forward":
-                stages, pencils, sched = self.stages, self.pencil_trace, schedule
-                in_pen, out_pen, sign = self.input_pencil, self.output_pencil, fftcore.FORWARD
-            else:
-                stages, pencils = _reverse_plan(self.stages, self.pencil_trace)
-                sched = schedule[::-1]
-                in_pen, out_pen, sign = self.output_pencil, self.input_pencil, fftcore.BACKWARD
-            guard_axes = tuple(n for g in self.grid for n in group_names(g))
-
-            def guarded_fn(block, *, _stages=stages, _pencils=pencils,
-                           _sched=sched, _sign=sign):
-                return _run_stages(block, stages=_stages, pencils=_pencils,
-                                   schedule=_sched, impl=self.impl,
-                                   sign=_sign, nbatch=nbatch, guard=True)
-
-            # shard-local stat vectors concatenate along axis 0 — the
-            # runner sums the partials on the host, so the guarded hot
-            # path carries no stats collective at all
-            stats_spec = P(guard_axes) if guard_axes else P()
-            self._guarded_exec[key] = shard_map(
-                guarded_fn, mesh=self.mesh,
-                in_specs=in_pen.batched_spec(nbatch),
-                out_specs=(out_pen.batched_spec(nbatch), stats_spec),
-                check_vma=False)
-        return self._guarded_exec[key]
+            schedule = self.schedule if nfields is None else self.batched_schedule(nfields)
+        key = (direction, nbatch, as_schedule(schedule))
+        if key not in self._executors:
+            walk = self._walk(direction, key[2])
+            guard = self.config.guard != "off"
+            out_specs = walk.out_pencil.batched_spec(nbatch)
+            if guard:
+                # shard-local stat vectors concatenate along axis 0 — the
+                # runner sums the partials on the host, so the guarded hot
+                # path carries no stats collective at all
+                axes = tuple(n for g in self.grid for n in group_names(g))
+                out_specs = (out_specs, P(axes) if axes else P())
+            fn = partial(_run_stages, stages=walk.stages, pencils=walk.pencils,
+                         schedule=walk.schedule, impl=self.config.impl,
+                         sign=walk.sign, nbatch=nbatch, guard=guard)
+            self._executors[key] = shard_map(
+                fn, mesh=self.mesh, in_specs=walk.in_pencil.batched_spec(nbatch),
+                out_specs=out_specs, check_vma=False)
+        return self._executors[key]
 
     def warm(self, directions=("forward", "backward"), *,
              nfields: int = 1) -> int:
@@ -450,63 +339,35 @@ class ParallelFFT:
         direction once on a zero block — schedule resolution (including a
         tuner sweep for ``method="auto"``), tracing, compilation and
         weight transfer all happen here instead of on the first real
-        request (the serving registry's warm start).  Guarded plans warm
-        the guarded executor — the one :func:`~repro.robustness.runner.
-        run_guarded` dispatches to; ``nfields > 1`` warms the batched
-        multi-field executor for that batch size.  Returns the number of
-        executors exercised."""
+        request (the serving registry's warm start).  ``nfields > 1``
+        warms the stacked executor for that batch size; a guarded plan's
+        executor is the one :func:`~repro.robustness.runner.run_guarded`
+        dispatches to.  Returns the number of executors exercised."""
+        stack = nfields if nfields > 1 else None
         n = 0
         for direction in directions:
-            if direction == "forward":
-                pen, dt = self.input_pencil, self.input_dtype
-            elif direction == "backward":
-                pen, dt = self.output_pencil, self.spectral_dtype
-            else:
-                raise ValueError(f"unknown direction {direction!r}")
-            shape = ((nfields,) if nfields > 1 else ()) + pen.physical
-            shard = pen.batched_sharding(1) if nfields > 1 else pen.sharding
-            xpad = jax.device_put(jnp.zeros(shape, dt), shard)
-            if self.guard != "off":
-                out = self.guarded_padded(direction, nfields=nfields)(xpad)
-            elif nfields > 1:
-                out = self._many_padded(nfields, direction)(xpad)
-            elif direction == "forward":
-                out = self.forward_padded(xpad)
-            else:
-                out = self.backward_padded(xpad)
-            jax.block_until_ready(out)
+            walk = self._walk(direction)
+            pen, nbatch = walk.in_pencil, 0 if stack is None else 1
+            xpad = jax.device_put(jnp.zeros((nfields,) * nbatch + pen.physical, walk.dtypes[0]),
+                                  pen.batched_sharding(nbatch))
+            jax.block_until_ready(self.executor(direction, stack)(xpad))
             n += 1
         return n
 
     def forward(self, x: jax.Array) -> jax.Array:
         """Logical-shape convenience wrapper (pads, transforms, unpads).
         A ``d+1``-dim input is treated as a stack of fields along a leading
-        batch axis and routed through the batched executor.  When the plan
+        batch axis and routed through the stacked executor.  When the plan
         was built with ``guard != "off"`` this returns
         ``(result, HealthReport)`` instead (see :mod:`repro.robustness`)."""
         if x.ndim == self.d + 1:
-            return self.forward_many(x)
-        x = x.astype(self.input_dtype)
-        xpad = pad_global(x, self.input_pencil)
-        if self.guard != "off":
-            from repro.robustness import runner
-
-            y, report = runner.run_guarded(self, xpad, "forward")
-            return unpad_global(y, self.output_pencil), report
-        y = self.forward_padded(xpad)
-        return unpad_global(y, self.output_pencil)
+            return self._apply_many(x, "forward")
+        return self._apply(x, "forward")
 
     def backward(self, x: jax.Array) -> jax.Array:
         if x.ndim == self.d + 1:
-            return self.backward_many(x)
-        xpad = pad_global(x.astype(self.spectral_dtype), self.output_pencil)
-        if self.guard != "off":
-            from repro.robustness import runner
-
-            y, report = runner.run_guarded(self, xpad, "backward")
-            return unpad_global(y, self.input_pencil), report
-        y = self.backward_padded(xpad)
-        return unpad_global(y, self.input_pencil)
+            return self._apply_many(x, "backward")
+        return self._apply(x, "backward")
 
     def forward_many(self, xs):
         """Transform N fields through one batched plan execution.
@@ -523,40 +384,38 @@ class ParallelFFT:
         return self._apply_many(xs, "backward")
 
     def _apply_many(self, xs, direction: str):
-        if direction == "forward":
-            in_pen, out_pen, dt = self.input_pencil, self.output_pencil, self.input_dtype
-        else:
-            in_pen, out_pen, dt = self.output_pencil, self.input_pencil, self.spectral_dtype
+        """Run a stacked array, or a pytree of fields stacked here, as one
+        stack through :meth:`_apply`; a pytree comes back as a pytree."""
         if hasattr(xs, "ndim"):  # stacked array, not a pytree of fields
             if xs.ndim != self.d + 1:
-                raise ValueError(
-                    f"stacked {direction} input must be (nfields, *{in_pen.logical}); "
-                    f"got ndim={xs.ndim} for a d={self.d} plan")
-            stacked, treedef = xs.astype(dt), None
-        else:
-            leaves, treedef = jax.tree_util.tree_flatten(xs)
-            if not leaves:
-                raise ValueError(f"{direction}_many needs at least one field")
-            stacked = jnp.stack([jnp.asarray(leaf).astype(dt) for leaf in leaves])
-        nfields = stacked.shape[0]
-        xpad = pad_global(stacked, in_pen, nbatch=1)
-        report = None
-        if self.guard != "off":
-            from repro.robustness import runner
-
-            if nfields == 1:  # guarded executors key nbatch off nfields
-                y, report = runner.run_guarded(self, xpad[0], direction)
-                y = y[None]
-            else:
-                y, report = runner.run_guarded(self, xpad, direction,
-                                               nfields=nfields)
-        else:
-            y = self._many_padded(nfields, direction)(xpad)
-        y = unpad_global(y, out_pen, nbatch=1)
-        if treedef is not None:
-            y = jax.tree_util.tree_unflatten(
-                treedef, [y[i] for i in range(nfields)])
+                raise ValueError(f"stacked {direction} input must be (nfields, *field) with "
+                                 f"ndim={self.d + 1}; got ndim={xs.ndim} for a d={self.d} plan")
+            return self._apply(xs, direction, xs.shape[0])
+        leaves, treedef = jax.tree_util.tree_flatten(xs)
+        if not leaves:
+            raise ValueError(f"{direction}_many needs at least one field")
+        out = self._apply(jnp.stack([jnp.asarray(leaf) for leaf in leaves]), direction, len(leaves))
+        y, report = out if self.config.guard != "off" else (out, None)
+        y = jax.tree_util.tree_unflatten(treedef, [y[i] for i in range(len(leaves))])
         return y if report is None else (y, report)
+
+    def _apply(self, x, direction: str, nfields: int | None = None):
+        """Pad, execute ``direction`` and unpad one logical block, or a
+        stack of ``nfields`` of them; guarded plans also return the
+        :class:`~repro.robustness.health.HealthReport`."""
+        walk = self._walk(direction)
+        nbatch = 0 if nfields is None else 1
+        xpad = pad_global(x.astype(walk.dtypes[0]), walk.in_pencil, nbatch=nbatch)
+        if self.config.guard == "off":
+            return unpad_global(self.executor(direction, nfields)(xpad), walk.out_pencil, nbatch=nbatch)
+        from repro.robustness import runner
+
+        if nfields == 1:  # the guarded runner stacks only nfields > 1
+            y, report = runner.run_guarded(self, xpad[0], direction)
+            y = y[None]
+        else:
+            y, report = runner.run_guarded(self, xpad, direction, nfields=nfields or 1)
+        return unpad_global(y, walk.out_pencil, nbatch=nbatch), report
 
     # -- analysis -----------------------------------------------------------
 
@@ -618,10 +477,10 @@ class ParallelFFT:
                 # a resolved batched schedule carries the per-stage tuned
                 # payloads of *this* batch size
                 entries = [tuple(e)[:3] for e in as_schedule(batched)]
-            elif self.method == "auto" and "schedule" not in self.__dict__:
+            elif self.config.method == "auto" and "schedule" not in self.__dict__:
                 # stay pure arithmetic: a byte count must never trigger the
                 # tuner; price the uniform budget until a schedule exists
-                entries = [("fused", 1, self.comm_dtype)] * self.n_exchanges
+                entries = [("fused", 1, self.config.comm_dtype)] * self.n_exchanges
             else:
                 entries = [tuple(e)[:3] for e in self.schedule]
         else:
@@ -684,16 +543,8 @@ class ParallelFFT:
 
         if schedule is None:
             schedule = self.batched_schedule(nfields) if nfields > 1 else self.schedule
-        if direction == "forward":
-            stages, pencils, dtypes = self.stages, self.pencil_trace, self.dtype_trace
-        elif direction == "backward":
-            stages, pencils = _reverse_plan(self.stages, self.pencil_trace)
-            dtypes = self.dtype_trace[::-1]
-            schedule = schedule[::-1]
-        else:
-            raise ValueError(f"unknown direction {direction!r}")
-        ndev = group_size(self.mesh, tuple(n for g in self.grid for n in
-                                           ((g,) if isinstance(g, str) else g)))
+        stages, pencils, dtypes, *_, schedule = self._walk(direction, schedule)
+        ndev = group_size(self.mesh, tuple(n for g in self.grid for n in group_names(g)))
         total, ex_i, i = 0.0, 0, 0
         while i < len(stages):
             st = stages[i]
@@ -737,19 +588,16 @@ class ParallelFFT:
 
         if schedule is None:
             schedule = self.batched_schedule(nfields) if nfields > 1 else self.schedule
-        if direction == "backward":
-            schedule = schedule[::-1]
-        elif direction != "forward":
-            raise ValueError(f"unknown direction {direction!r}")
+        walk = self._walk(direction, schedule)
         total, ex_i = 0, 0
-        for i, st in enumerate(self.stages):
+        for i, st in enumerate(walk.stages):
             if not isinstance(st, ExchangeStage):
                 continue
-            entry = StageEntry.make(schedule[ex_i])
+            entry = StageEntry.make(walk.schedule[ex_i])
             ex_i += 1
             fusion = batch_fusion if batch_fusion is not None else entry.batch_fusion
             total += exchange_collective_launches(
-                self.pencil_trace[i], st.v, st.w, method=entry.method,
+                walk.pencils[i], st.v, st.w, method=entry.method,
                 chunks=entry.chunks, nfields=nfields, batch_fusion=fusion)
         return total
 
@@ -798,10 +646,10 @@ def _reverse_plan(stages, pencils):
 def _run_stages(block, *, stages, pencils, schedule, impl, sign, nbatch=0,
                 guard=False):
     """Execute the plan on one shard (inside shard_map).  ``schedule`` gives
-    a :class:`StageEntry` (or any legacy tuple form) per exchange stage, in
-    this plan's stage order; each exchange is emitted together with the FFT of
-    its newly-aligned axis (always the next stage in forward and backward
-    plans) so the engine can interleave collective and compute — per slice
+    a :class:`StageEntry` per exchange stage, in this plan's stage order;
+    each exchange is emitted together with the FFT of its newly-aligned
+    axis (always the next stage in forward and backward plans) so the
+    engine can interleave collective and compute — per slice
     for method="pipelined", per field for batch_fusion="pipelined-across-
     fields".  ``nbatch=1`` executes a stacked multi-field block: FFT stages
     transform all fields in one vectorized call and exchange stages follow

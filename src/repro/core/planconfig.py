@@ -2,27 +2,21 @@
 
 Two types live here:
 
-:class:`PlanConfig` — a frozen dataclass consolidating the execution
-    knobs that used to sprawl across ``ParallelFFT.__init__``'s keyword
-    list (``method`` / ``impl`` / ``exchange_impl`` / ``chunks`` /
+:class:`PlanConfig` — a frozen dataclass holding every execution knob of
+    a plan (``method`` / ``impl`` / ``exchange_impl`` / ``chunks`` /
     ``comm_dtype`` / ``batch_fusion`` / ``tuner_cache`` / ``guard``).
     All validation happens in one place (``__post_init__``), so every
     consumer — the plan itself, the tuner, the benchmarks — sees an
-    already-canonical config.  ``ParallelFFT(mesh, shape, grid,
-    config=PlanConfig(...))`` is the supported surface; the legacy
-    kwargs still work through a deprecation shim that forwards into a
-    PlanConfig and warns once per process.
+    already-canonical config: ``ParallelFFT(mesh, shape, grid,
+    config=PlanConfig(...))``, read back as ``plan.config``.
 
 :class:`StageEntry` — one exchange stage's tuned/selected execution
-    entry: ``(method, chunks, comm_dtype, impl, batch_fusion)``.  This
-    replaces the historical raw ``(method, chunks, comm_dtype[,
-    batch_fusion])`` 3-vs-4 tuples; being a NamedTuple it still unpacks
-    and indexes like one (``entry[2]`` is the comm_dtype everywhere it
-    always was), and :meth:`StageEntry.make` upgrades any legacy tuple —
-    the ``impl`` and ``batch_fusion`` vocabularies are disjoint, so a
-    4-tuple's last field is classified unambiguously.
+    entry: ``(method, chunks, comm_dtype, impl, batch_fusion)``.  Being a
+    NamedTuple it unpacks and indexes like the 5-field row the tuner's
+    cache stores (``entry[2]`` is the comm_dtype), and
+    :meth:`StageEntry.make` validates any such row.
 
-The new ``impl`` stage field selects the *exchange-local* implementation:
+The ``impl`` stage field selects the *exchange-local* implementation:
 
 ``"jnp"``    — the reference path: :mod:`repro.core.quant` codecs plus the
     engine's jnp pack/unpack copies (multiple HBM round-trips).
@@ -41,7 +35,7 @@ implementation, ``"jnp"`` | ``"matmul"``); the exchange impl is
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field, fields, replace
+from dataclasses import dataclass, fields, replace
 from typing import NamedTuple
 
 from repro.core.quant import canonical_comm_dtype
@@ -60,9 +54,9 @@ METHODS = ("fused", "traditional", "pipelined")
 class StageEntry(NamedTuple):
     """One exchange stage's execution entry.
 
-    Unpacks/indexes like the raw tuples it replaced: ``entry[0]`` method,
-    ``entry[1]`` chunks, ``entry[2]`` comm_dtype; the new ``impl`` field
-    sits at index 3 and ``batch_fusion`` at 4.
+    Unpacks/indexes like the cache's rows: ``entry[0]`` method,
+    ``entry[1]`` chunks, ``entry[2]`` comm_dtype, ``entry[3]`` impl and
+    ``entry[4]`` batch_fusion.
     """
 
     method: str
@@ -73,23 +67,15 @@ class StageEntry(NamedTuple):
 
     @classmethod
     def make(cls, entry) -> "StageEntry":
-        """Normalize any schedule-entry form — a StageEntry, a legacy
-        ``(method, chunks, comm_dtype)`` or ``(..., batch_fusion)`` tuple,
-        or a full 5-tuple — into a validated StageEntry.  A legacy
-        4-tuple's last field is classified by vocabulary (``impl`` and
-        ``batch_fusion`` values are disjoint)."""
+        """Normalize a StageEntry or a 5-field ``(method, chunks,
+        comm_dtype, impl, batch_fusion)`` row into a validated
+        StageEntry."""
         if isinstance(entry, cls):
             return entry.validate()
         t = tuple(entry)
-        if len(t) == 3:
-            return cls(t[0], int(t[1]), t[2]).validate()
-        if len(t) == 4:
-            if t[3] in BATCH_FUSIONS:
-                return cls(t[0], int(t[1]), t[2], "jnp", t[3]).validate()
-            return cls(t[0], int(t[1]), t[2], t[3]).validate()
-        if len(t) == 5:
-            return cls(t[0], int(t[1]), t[2], t[3], t[4]).validate()
-        raise ValueError(f"schedule entry {entry!r} has {len(t)} fields; expected 3-5")
+        if len(t) != 5:
+            raise ValueError(f"schedule entry {entry!r} has {len(t)} fields; expected 5")
+        return cls(t[0], int(t[1]), t[2], t[3], t[4]).validate()
 
     def validate(self) -> "StageEntry":
         if self.method not in METHODS:
@@ -106,8 +92,8 @@ class StageEntry(NamedTuple):
 
 
 def as_schedule(entries) -> tuple[StageEntry, ...]:
-    """Normalize an iterable of schedule entries (any legacy form) into a
-    tuple of :class:`StageEntry` — the one normalizer every consumer of a
+    """Normalize an iterable of schedule entries into a tuple of
+    :class:`StageEntry` — the one normalizer every consumer of a
     user/disk-provided schedule shares."""
     return tuple(StageEntry.make(e) for e in entries)
 
@@ -176,14 +162,3 @@ class PlanConfig:
 
     def to_dict(self) -> dict:
         return {f.name: getattr(self, f.name) for f in fields(self)}
-
-    @classmethod
-    def from_legacy_kwargs(cls, **kwargs) -> "PlanConfig":
-        """Build a config from the legacy ParallelFFT keyword set, keeping
-        each unset field at its default (the deprecation shim's helper)."""
-        return cls(**{k: v for k, v in kwargs.items() if v is not None})
-
-
-# make `field` referenced for linters that dislike unused imports via
-# dataclasses API surface changes
-_ = field

@@ -1,4 +1,4 @@
-"""Per-plan exchange-schedule autotuner (``ParallelFFT(method="auto")``).
+"""Per-plan exchange-schedule autotuner (``PlanConfig(method="auto")``).
 
 The paper's single-collective formulation leaves the *engine* of each
 exchange open — the MPI analogue is the library's freedom to implement
@@ -49,20 +49,16 @@ entry's schedule failing at runtime; a marked entry is never replayed —
 :func:`_parse_entry` rejects it, forcing a retune whose fresh timings
 (under whatever fault made the old winner lose) replace the mark.
 
-v5 entries (3/4-field schedule rows, ``schema: 5`` keys) are **migrated,
-not retuned**: a v6 default-candidate miss whose exchange-impl budget is
-"jnp" reconstructs the plan's exact v5 key, upgrades a healthy legacy
-entry through :func:`~repro.core.planconfig.StageEntry.make` (every old
-row gains ``impl="jnp"``), and re-saves it under the v6 key — the v5
-timings stay valid because the jnp-only candidate space is unchanged.  A
-"pallas" budget never migrates: its candidate set contains kernels the v5
-sweep never measured.  v1–v4 entries have incompatible keys and are simply
-never matched; stale entries are harmless and a corrupt or non-dict cache
-file is silently treated as empty and rewritten — a stale cache must never
-raise.  Writes are atomic (temp file + ``os.replace``) and **merge** by
-default: the writer re-reads the file and overlays only its own keys, so
-concurrent workers tuning *different* plans no longer clobber each other's
-entries (last-writer-wins now applies per key, not per file).
+Entries of older schemas (v1–v5) carry another ``schema`` in their key,
+so a lookup never matches them and the plan retunes; a row that is not a
+5-field :class:`~repro.core.planconfig.StageEntry` (v5 stored 3/4-field
+rows) fails :func:`_parse_entry` the same way.  Stale entries are harmless
+and a corrupt or non-dict cache file is silently treated as empty and
+rewritten — a stale cache must never raise.  Writes are atomic (temp
+file + ``os.replace``) and **merge** by default: the writer re-reads the
+file and overlays only its own keys, so concurrent workers tuning
+*different* plans no longer clobber each other's entries
+(last-writer-wins now applies per key, not per file).
 
 Cache location: ``$REPRO_TUNER_CACHE`` or ``~/.cache/repro/fft_tuner.json``;
 an in-process memo avoids re-reading the file per plan.
@@ -137,8 +133,7 @@ def batched_candidates_for(comm_dtype=None, exchange_impl: str = "jnp",
 
 
 def _default_candidates(plan, nfields: int):
-    budget = getattr(plan, "comm_dtype", None)
-    impl_budget = getattr(plan, "exchange_impl", "jnp")
+    budget, impl_budget = plan.config.comm_dtype, plan.config.exchange_impl
     return (candidates_for(budget, impl_budget) if nfields <= 1
             else batched_candidates_for(budget, impl_budget))
 
@@ -178,7 +173,7 @@ def _key_fields(plan, nfields: int = 1) -> dict:
     return {"schema": SCHEMA_VERSION, "mesh": mesh_sig, "shape": plan.shape,
             "grid": plan.grid,
             "transforms": tuple(sp.tag() for sp in plan.transforms),
-            "impl": plan.impl, "backend": jax.default_backend(),
+            "impl": plan.config.impl, "backend": jax.default_backend(),
             "device_kind": device_kind, "nfields": nfields}
 
 
@@ -272,14 +267,12 @@ def get_or_tune(plan, *, cache_path: str | None = None,
                 candidates=None, nfields: int = 1):
     """Return the tuned schedule for ``plan`` — a :class:`StageEntry` per
     exchange stage — consulting the in-process memo, then the disk cache
-    (including a v5-entry migration, see module docstring), then
-    benchmarking.  The default candidate set is every engine × every
+    then benchmarking.  The default candidate set is every engine × every
     payload within the plan's ``comm_dtype`` accuracy budget × every
     exchange impl within its ``exchange_impl`` budget (× every batch
     fusion mode for a batched plan).  A stale-schema or otherwise
     malformed cache entry is ignored and overwritten, never raised on."""
-    defaults = candidates is None
-    if defaults:
+    if candidates is None:
         candidates = _default_candidates(plan, nfields)
     candidates = as_schedule(candidates)
     path = Path(cache_path) if cache_path else default_cache_path()
@@ -289,13 +282,6 @@ def get_or_tune(plan, *, cache_path: str | None = None,
         return _MEMO[memo_key]
     disk = load_cache(path)
     sched = _parse_entry(disk.get(key), plan.n_exchanges, candidates=candidates)
-    if sched is None and defaults:
-        migrated = _migrate_v5_entry(plan, disk, nfields)
-        if migrated is not None:
-            sched, legacy = migrated
-            save_cache(path, {key: {"schedule": [list(s) for s in sched],
-                                    "timings": legacy.get("timings", {}),
-                                    "migrated_from_schema": 5}})
     if sched is None:
         sched, timings = tune_plan(plan, candidates=candidates, nfields=nfields)
         entry = {"schedule": [list(s) for s in sched], "timings": timings}
@@ -307,38 +293,6 @@ def get_or_tune(plan, *, cache_path: str | None = None,
         save_cache(path, {key: entry})  # delta write: merge keeps other plans
     _MEMO[memo_key] = sched
     return sched
-
-
-def _legacy_v5_candidates(plan, nfields: int):
-    """The exact (jnp-only) v5 candidate tuples for a plan's budget — the
-    raw 3/4-field rows v5 swept, for key reconstruction and entry
-    validation during migration."""
-    ladder = COMM_DTYPE_LADDER[canonical_comm_dtype(getattr(plan, "comm_dtype", None))]
-    flat = tuple((m, c, d) for d in ladder for m, c in ENGINE_CANDIDATES)
-    if nfields <= 1:
-        return flat
-    return tuple((m, c, d, f) for f in BATCH_FUSIONS for m, c, d in flat)
-
-
-def _migrate_v5_entry(plan, disk: dict, nfields: int):
-    """Look up this plan's schema-5 cache entry and upgrade it to a v6
-    schedule (``(schedule, legacy_entry)``), or ``None`` when there is
-    nothing migratable: no/unhealthy legacy entry, a legacy schedule
-    outside the legacy candidate set, or an ``exchange_impl="pallas"``
-    budget (whose v6 candidate set sweeps kernels v5 never measured — a
-    migrated winner could be stale, so that case retunes)."""
-    if getattr(plan, "exchange_impl", "jnp") != "jnp":
-        return None
-    legacy_cands = _legacy_v5_candidates(plan, nfields)
-    fields = _key_fields(plan, nfields)
-    fields["schema"] = 5
-    fields["candidates"] = sorted(_tag(c) for c in legacy_cands)
-    legacy_key = json.dumps(fields, sort_keys=True, default=str)
-    entry = disk.get(legacy_key)
-    sched = _parse_entry(entry, plan.n_exchanges, candidates=legacy_cands)
-    if sched is None:
-        return None
-    return sched, entry
 
 
 def quarantine(path, key: str, reason: str) -> int:
@@ -377,8 +331,8 @@ def _parse_entry(entry, n_exchanges: int, candidates=None):
     """Validate one disk-cache entry into a :class:`StageEntry` schedule,
     or ``None`` if missing/malformed — wrong stage count, junk types, or
     unknown engine/payload/impl/fusion *values* (a hand-edited or
-    bit-rotted entry must retune, never raise later inside the executor).
-    Legacy 3/4-field rows upgrade through :func:`StageEntry.make`.
+    bit-rotted entry must retune, never raise later inside the executor);
+    so does a row that is not a 5-field :class:`StageEntry`.
 
     When ``candidates`` is given, every stage entry must additionally be a
     member of that *live* candidate set: an entry naming an engine, chunk
@@ -516,7 +470,7 @@ def _time_stage(plan, si: int, method: str, chunks: int, comm_dtype: str,
     def run(block):
         out, _, _ = _run_exchange_stage(
             block, st, follow if has_fft else None, plan.pencil_trace[si + 1],
-            out_pen if has_fft else None, entry, impl=plan.impl,
+            out_pen if has_fft else None, entry, impl=plan.config.impl,
             sign=fftcore.FORWARD, nbatch=nbatch, at=si)
         return out
 

@@ -61,11 +61,10 @@ class GuardError(RuntimeError):
 
 
 def degrade_entry(entry):
-    """One ladder rung for a :class:`~repro.core.planconfig.StageEntry`
-    (any legacy tuple form upgrades first): widen the payload, then drop
-    a fused pallas kernel back to the jnp reference, then fall back
-    through the engines; None when the entry is already at the bottom
-    (traditional @ complex64 @ jnp)."""
+    """One ladder rung for a :class:`~repro.core.planconfig.StageEntry`:
+    widen the payload, then drop a fused pallas kernel back to the jnp
+    reference, then fall back through the engines; None when the entry is
+    already at the bottom (traditional @ complex64 @ jnp)."""
     from repro.core.planconfig import StageEntry
 
     e = StageEntry.make(entry)
@@ -107,7 +106,7 @@ def _quarantine_and_retune(plan, nfields: int, err) -> int:
     count (the retune happens lazily at the next schedule resolve)."""
     from repro.core import tuner
 
-    path = plan.tuner_cache or tuner.default_cache_path()
+    path = plan.config.tuner_cache or tuner.default_cache_path()
     key = tuner.plan_key(plan, nfields=nfields)
     n = tuner.quarantine(path, key, repr(err)[:300])
     plan.__dict__.pop("schedule", None)  # cached_property reset
@@ -129,7 +128,7 @@ def run_guarded(plan, xpad, direction: str, nfields: int = 1, *,
     quarantines the tuner cache (it is not the cache's schedule)."""
     from repro.core import tuner
 
-    strict = plan.guard == "strict"
+    strict = plan.config.guard == "strict"
     forced = schedule is not None
     if forced:
         from repro.core.planconfig import as_schedule
@@ -142,8 +141,8 @@ def run_guarded(plan, xpad, direction: str, nfields: int = 1, *,
         try:
             if schedule is None:
                 schedule = _resolve_schedule(plan, nfields)
-            fn = plan.guarded_padded(direction, schedule=schedule,
-                                     nfields=nfields)
+            fn = plan.executor(direction, nfields if nfields > 1 else None,
+                               schedule=schedule)
             y, raw = fn(xpad)
             # per-shard partial vectors; summing them happens here on the
             # host so the compiled executor stays collective-free
@@ -160,7 +159,7 @@ def run_guarded(plan, xpad, direction: str, nfields: int = 1, *,
             if strict:
                 raise GuardError(
                     f"schedule failed to execute: {err!r}") from err
-            if plan.method == "auto":
+            if plan.config.method == "auto":
                 n = _quarantine_and_retune(plan, nfields, err)
                 if n > tuner.MAX_QUARANTINE_RETUNES:
                     raise GuardError(
@@ -189,7 +188,7 @@ def run_guarded(plan, xpad, direction: str, nfields: int = 1, *,
 
         report = health.build_report(
             plan, direction=direction, nfields=nfields, schedule=schedule,
-            stats=stats, guard=plan.guard, transitions=transitions,
+            stats=stats, guard=plan.config.guard, transitions=transitions,
             attempts=attempt,
             fired_faults=tuple(faults._ACTIVE.fired) if faults._ACTIVE else ())
         if report.ok:

@@ -367,7 +367,7 @@ class SpectralServer:
 
         log.warning("circuit breaker tripped for plan %s...: %r",
                     key[:60], err)
-        if plan.method == "auto":
+        if plan.config.method == "auto":
             try:
                 runner._quarantine_and_retune(
                     plan, nfields if nfields > 1 else 1, err)

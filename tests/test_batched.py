@@ -12,32 +12,35 @@ def test_forward_many_matches_per_field_loop(subproc):
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.meshutil import make_mesh
 from repro.core.pfft import ParallelFFT
+from repro.core.planconfig import PlanConfig
 
 mesh = make_mesh((2, 4), ("p0", "p1"))
 rng = np.random.default_rng(0)
 shape = (16, 12, 20)
 N = 3
+R2C = ("c2c", "c2c", "r2c")
 cases = [
-    (("p0",), dict()),                               # slab c2c
-    (("p0", "p1"), dict()),                          # pencil c2c
-    (("p0", "p1"), dict(real=True)),                 # pencil r2c spec
-    (("p0", "p1"), dict(method="pipelined", chunks=2)),  # sliced exchange
+    (("p0",), None, dict()),                               # slab c2c
+    (("p0", "p1"), None, dict()),                          # pencil c2c
+    (("p0", "p1"), R2C, dict()),                           # pencil r2c spec
+    (("p0", "p1"), None, dict(method="pipelined", chunks=2)),  # sliced exchange
 ]
-for grid, kw in cases:
+for grid, tf, kw in cases:
     for fusion in ("stacked", "pipelined-across-fields", "per-field"):
-        plan = ParallelFFT(mesh, shape, grid, batch_fusion=fusion, **kw)
+        plan = ParallelFFT(mesh, shape, grid, transforms=tf,
+                           config=PlanConfig(batch_fusion=fusion, **kw))
         x = rng.standard_normal((N, *shape)).astype(np.float32)
         if plan.input_dtype == jnp.complex64:
             x = (x + 1j * rng.standard_normal((N, *shape))).astype(np.complex64)
         xs = jnp.asarray(x)
         ref = jnp.stack([plan.forward(xs[i]) for i in range(N)])
         got = plan.forward_many(xs)
-        assert jnp.array_equal(got, ref), (grid, kw, fusion, "forward")
+        assert jnp.array_equal(got, ref), (grid, tf, kw, fusion, "forward")
         back_ref = jnp.stack([plan.backward(ref[i]) for i in range(N)])
         back = plan.backward_many(got)
-        assert jnp.array_equal(back, back_ref), (grid, kw, fusion, "backward")
+        assert jnp.array_equal(back, back_ref), (grid, tf, kw, fusion, "backward")
         np.testing.assert_allclose(np.asarray(back), x, rtol=3e-4, atol=3e-3)
-    print("ok", grid, kw)
+    print("ok", grid, tf, kw)
 
 # pytree API mirrors structure; a d+1-dim forward() input routes batched
 plan = ParallelFFT(mesh, shape, ("p0", "p1"))
@@ -64,6 +67,7 @@ def test_batched_stacked_issues_one_collective_per_stage(subproc):
 import jax, jax.numpy as jnp
 from repro.core.meshutil import make_mesh
 from repro.core.pfft import ParallelFFT
+from repro.core.planconfig import PlanConfig
 
 mesh = make_mesh((2, 4), ("p0", "p1"))
 shape = (16, 12, 20)
@@ -73,14 +77,14 @@ def count_a2a(fn, shape, dtype):
 
 for grid in (("p0",), ("p0", "p1")):
     plan = ParallelFFT(mesh, shape, grid)  # stacked default, lossless payload
-    n_fwd = count_a2a(plan.forward_many_padded(N),
+    n_fwd = count_a2a(plan.executor("forward", N),
                       (N, *plan.input_pencil.physical), plan.input_dtype)
     assert n_fwd == plan.n_exchanges, (grid, n_fwd)
-    n_bwd = count_a2a(plan.backward_many_padded(N),
+    n_bwd = count_a2a(plan.executor("backward", N),
                       (N, *plan.output_pencil.physical), plan.spectral_dtype)
     assert n_bwd == plan.n_exchanges, (grid, n_bwd)
-    pf = ParallelFFT(mesh, shape, grid, batch_fusion="per-field")
-    n_pf = count_a2a(pf.forward_many_padded(N),
+    pf = ParallelFFT(mesh, shape, grid, config=PlanConfig(batch_fusion="per-field"))
+    n_pf = count_a2a(pf.executor("forward", N),
                      (N, *pf.input_pencil.physical), pf.input_dtype)
     assert n_pf == N * pf.n_exchanges, (grid, n_pf)
     print("ok", grid, n_fwd, n_pf)
@@ -176,10 +180,11 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.core import tuner
 from repro.core.meshutil import make_mesh
 from repro.core.pfft import ParallelFFT
+from repro.core.planconfig import PlanConfig
 
 cache = {str(cache)!r}
 mesh = make_mesh((2, 2), ("p0", "p1"))
-plan = ParallelFFT(mesh, (16, 8, 8), ("p0", "p1"), method="auto", tuner_cache=cache)
+plan = ParallelFFT(mesh, (16, 8, 8), ("p0", "p1"), config=PlanConfig(method="auto", tuner_cache=cache))
 bs = plan.batched_schedule(3)
 assert len(bs) == plan.n_exchanges == 2
 for method, chunks, comm_dtype, impl, fusion in bs:
@@ -203,7 +208,7 @@ assert tuner.plan_key(plan, nfields=1) != bkey
 # fresh memo must reload from disk, not re-benchmark
 tuner._MEMO.clear()
 tuner.tune_plan = None
-plan2 = ParallelFFT(mesh, (16, 8, 8), ("p0", "p1"), method="auto", tuner_cache=cache)
+plan2 = ParallelFFT(mesh, (16, 8, 8), ("p0", "p1"), config=PlanConfig(method="auto", tuner_cache=cache))
 assert plan2.batched_schedule(3) == bs
 
 rng = np.random.default_rng(0)

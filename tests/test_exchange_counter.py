@@ -78,16 +78,16 @@ one = ParallelFFT(make_mesh((1, 1), ("p0", "p1"), devices=jax.devices()[:1]),
                   (16, 8, 8), ("p0", "p1"))
 x1 = jax.ShapeDtypeStruct(one.input_pencil.physical, one.input_dtype,
                           sharding=one.input_pencil.sharding)
-out["one_device"] = recorded(lambda: jax.jit(one.forward_padded).lower(x1).compile())
+out["one_device"] = recorded(lambda: jax.jit(one.executor("forward")).lower(x1).compile())
 
 # the counter read between two lowerings of one executor changes nothing
 mesh, grid = GRIDS["pencil2x2"]
 plan = ParallelFFT(mesh, (16, 8, 8), grid, config=PlanConfig(comm_dtype="int8"))
 x = jax.ShapeDtypeStruct(plan.input_pencil.physical, plan.input_dtype,
                          sharding=plan.input_pencil.sharding)
-first = jax.jit(plan.forward_padded).lower(x).as_text(debug_info=True)
+first = jax.jit(plan.executor("forward")).lower(x).as_text(debug_info=True)
 read = spans.exchange_totals()
-second = jax.jit(plan.forward_padded).lower(x).as_text(debug_info=True)
+second = jax.jit(plan.executor("forward")).lower(x).as_text(debug_info=True)
 out["relowered"] = {"same": first == second, "read": len(read),
                     "after": len(spans.exchange_totals())}
 print("COUNTS=" + json.dumps(out))

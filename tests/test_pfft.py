@@ -1,6 +1,7 @@
 """Distributed FFT vs np.fft oracles on 8 virtual devices."""
 
 import numpy as np
+import pytest
 from _hyp import given, settings, strategies as st
 
 from repro.core.pfft import ParallelFFT
@@ -11,6 +12,10 @@ def test_pfft_all_decompositions(subproc):
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.meshutil import make_mesh
 from repro.core.pfft import ParallelFFT
+from repro.core.planconfig import PlanConfig
+
+def tf(shape, real):  # r2c on the last axis, c2c elsewhere
+    return ("c2c",) * (len(shape) - 1) + ("r2c",) if real else None
 
 mesh = make_mesh((2, 4), ("p0", "p1"))
 rng = np.random.default_rng(0)
@@ -27,7 +32,8 @@ cases = [
     ((8, 6, 10, 12), ("p0", "p1"), False, "fused"),   # 4-D on 2-D grid
 ]
 for shape, grid, real, method in cases:
-    plan = ParallelFFT(mesh, shape, grid, real=real, method=method)
+    plan = ParallelFFT(mesh, shape, grid, transforms=tf(shape, real),
+                       config=PlanConfig(method=method))
     x = rng.standard_normal(shape).astype(np.float32)
     if not real:
         x = (x + 1j * rng.standard_normal(shape)).astype(np.complex64)
@@ -57,6 +63,7 @@ import tempfile
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.meshutil import make_mesh
 from repro.core.pfft import ParallelFFT
+from repro.core.planconfig import PlanConfig
 
 mesh = make_mesh((2, 4), ("p0", "p1"))
 rng = np.random.default_rng(0)
@@ -64,15 +71,18 @@ cache = tempfile.mktemp(suffix=".json")
 shape = (16, 12, 20)
 for grid in (("p0",), ("p0", "p1")):
     for real in (False, True):
-        ref = ParallelFFT(mesh, shape, grid, real=real, method="fused")
+        tf = ("c2c", "c2c", "r2c") if real else None
+        ref = ParallelFFT(mesh, shape, grid, transforms=tf,
+                          config=PlanConfig(method="fused"))
         x = rng.standard_normal(shape).astype(np.float32)
         if not real:
             x = (x + 1j * rng.standard_normal(shape)).astype(np.complex64)
         want = np.asarray(ref.forward(jnp.asarray(x)))
-        variants = [ParallelFFT(mesh, shape, grid, real=real,
-                                method="pipelined", chunks=c) for c in (1, 2, 4)]
-        variants.append(ParallelFFT(mesh, shape, grid, real=real,
-                                    method="auto", tuner_cache=cache))
+        variants = [ParallelFFT(mesh, shape, grid, transforms=tf,
+                                config=PlanConfig(method="pipelined", chunks=c))
+                    for c in (1, 2, 4)]
+        variants.append(ParallelFFT(mesh, shape, grid, transforms=tf,
+                                    config=PlanConfig(method="auto", tuner_cache=cache)))
         for plan in variants:
             assert plan.output_pencil == ref.output_pencil   # identical pencils
             y = np.asarray(plan.forward(jnp.asarray(x)))
@@ -95,6 +105,7 @@ def test_pfft_comm_dtype_accuracy(subproc):
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.meshutil import make_mesh
 from repro.core.pfft import ParallelFFT
+from repro.core.planconfig import PlanConfig
 
 mesh = make_mesh((2, 4), ("p0", "p1"))
 rng = np.random.default_rng(0)
@@ -105,8 +116,8 @@ for grid in (("p0",), ("p0", "p1")):
     want = np.asarray(ref.forward(jnp.asarray(x)))
     for method in ("fused", "traditional", "pipelined"):
         for comm_dtype in (None, "complex64", "bf16", "int8"):
-            plan = ParallelFFT(mesh, shape, grid, method=method, chunks=2,
-                               comm_dtype=comm_dtype)
+            plan = ParallelFFT(mesh, shape, grid,
+                               config=PlanConfig(method=method, chunks=2, comm_dtype=comm_dtype))
             y = plan.forward(jnp.asarray(x))
             back = np.asarray(plan.backward(y))
             if comm_dtype in (None, "complex64"):
@@ -121,45 +132,54 @@ print("PFFT COMM DTYPE OK")
 
 
 def test_backward_consumes_reversed_tuned_schedule(subproc):
-    """method="auto" backward pass: backward_padded must consume the tuned
-    schedule in reversed stage order, and backward(forward(x)) must
+    """method="auto" backward pass: the backward executor must consume the
+    tuned schedule in reversed stage order, and backward(forward(x)) must
     round-trip to the identity for a *mixed* per-stage schedule (different
     engine, chunks and comm_dtype per exchange)."""
     subproc("""
 import json, tempfile
 from pathlib import Path
 import jax, jax.numpy as jnp, numpy as np
-from repro.core import tuner
+from repro.core import fftcore, pfft, tuner
 from repro.core.meshutil import make_mesh
 from repro.core.pfft import ExchangeStage, ParallelFFT
+from repro.core.planconfig import PlanConfig, as_schedule
 
 cache = tempfile.mktemp(suffix=".json")
 mesh = make_mesh((2, 4), ("p0", "p1"))
 shape = (16, 12, 20)
-plan = ParallelFFT(mesh, shape, ("p0", "p1"), method="auto",
-                   comm_dtype="int8", tuner_cache=cache)
+plan = ParallelFFT(mesh, shape, ("p0", "p1"),
+                   config=PlanConfig(method="auto", comm_dtype="int8", tuner_cache=cache))
 # seed the disk cache with a hand-mixed schedule BEFORE plan.schedule is
 # first read: the plan must consume it instead of benchmarking
-mixed = [["traditional", 1, "complex64"], ["pipelined", 2, "bf16"]]
+mixed = [["traditional", 1, "complex64", "jnp", "stacked"],
+         ["pipelined", 2, "bf16", "jnp", "stacked"]]
 Path(cache).write_text(json.dumps(
     {tuner.plan_key(plan): {"schedule": mixed, "timings": {}}}))
-# legacy 3-field disk rows upgrade to full StageEntry rows on load
-from repro.core.planconfig import as_schedule
 assert plan.schedule == as_schedule(mixed)
 
-# backward executor: same schedule, reversed stage order
-bwd_sched = plan._backward_shard.keywords["schedule"]
-assert bwd_sched == plan.schedule[::-1]
-# and its exchange stages are the forward ones reversed with v/w swapped
-fwd_ex = [s for s in plan.stages if isinstance(s, ExchangeStage)]
-bwd_ex = [s for s in plan._backward_shard.keywords["stages"]
-          if isinstance(s, ExchangeStage)]
-assert [(s.v, s.w) for s in bwd_ex] == [(s.w, s.v) for s in reversed(fwd_ex)]
+# what each executor hands the shard function, recorded as it is traced
+traced = {}
+run_stages = pfft._run_stages
+def spy(block, **kw):
+    traced[kw["sign"]] = kw
+    return run_stages(block, **kw)
+pfft._run_stages = spy
 
 # mixed-schedule round trip: backward(forward(x)) ~= x (bf16-stage lossy)
 rng = np.random.default_rng(0)
 x = (rng.standard_normal(shape) + 1j * rng.standard_normal(shape)).astype(np.complex64)
 back = np.asarray(plan.backward(plan.forward(jnp.asarray(x))))
+
+# backward executor: same schedule, reversed stage order
+bwd = traced[fftcore.BACKWARD]
+assert bwd["schedule"] == plan.schedule[::-1]
+assert traced[fftcore.FORWARD]["schedule"] == plan.schedule
+# and its exchange stages are the forward ones reversed with v/w swapped
+fwd_ex = [s for s in plan.stages if isinstance(s, ExchangeStage)]
+bwd_ex = [s for s in bwd["stages"] if isinstance(s, ExchangeStage)]
+assert [(s.v, s.w) for s in bwd_ex] == [(s.w, s.v) for s in reversed(fwd_ex)]
+
 rel = np.linalg.norm(back - x) / np.linalg.norm(x)
 assert rel < 1e-2, rel
 print("BACKWARD AUTO OK", rel)
@@ -167,7 +187,7 @@ print("BACKWARD AUTO OK", rel)
 
 
 def test_r2c_backward_odd_trailing_extents(subproc):
-    """real=True backward transforms with odd trailing extents: the c2r
+    """r2c-plan backward transforms with odd trailing extents: the c2r
     stage must irfft at the explicit logical length (n=), which the
     Hermitian-reduced extent alone cannot recover (n//2+1 maps both n and
     n-1 onto the same spectrum length).  Feeds np.fft.rfftn oracles
@@ -182,7 +202,7 @@ rng = np.random.default_rng(0)
 # odd trailing extents, including odd == even+1 aliasing pairs (11 vs 10)
 for shape in ((8, 6, 11), (12, 10, 9), (13, 9, 7)):
     for grid in (("p0",), ("p0", "p1")):
-        plan = ParallelFFT(mesh, shape, grid, real=True)
+        plan = ParallelFFT(mesh, shape, grid, transforms=("c2c", "c2c", "r2c"))
         assert plan.output_pencil.logical[-1] == shape[-1] // 2 + 1
         x = rng.standard_normal(shape).astype(np.float32)
         # backward of the numpy oracle spectrum reproduces x: proves the
@@ -210,7 +230,8 @@ def test_model_flops_known_shapes():
     # r2c (8,8,8): r2c stage at half, then two c2c stages with the last
     # axis reduced to 8//2+1 = 5 in their batches
     want = 0.5 * 5 * 8 * 3 * 64 + 2 * (5 * 8 * 3 * (8 * 5))
-    assert ParallelFFT(mesh, (8, 8, 8), ("p0",), real=True).model_flops() == want
+    r2c = ParallelFFT(mesh, (8, 8, 8), ("p0",), transforms=("c2c", "c2c", "r2c"))
+    assert r2c.model_flops() == want
     # non-power-of-two length uses log2 of the true logical n
     import math
     got = ParallelFFT(mesh, (6, 4), ("p0",)).model_flops()
@@ -223,10 +244,11 @@ def test_pfft_matmul_impl(subproc):
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.meshutil import make_mesh
 from repro.core.pfft import ParallelFFT
+from repro.core.planconfig import PlanConfig
 mesh = make_mesh((4,), ("p0",))
 rng = np.random.default_rng(0)
 shape = (16, 8, 12)
-plan = ParallelFFT(mesh, shape, ("p0",), impl="matmul")
+plan = ParallelFFT(mesh, shape, ("p0",), config=PlanConfig(impl="matmul"))
 x = (rng.standard_normal(shape) + 1j * rng.standard_normal(shape)).astype(np.complex64)
 y = plan.forward(jnp.asarray(x))
 np.testing.assert_allclose(np.asarray(y), np.fft.fftn(x), rtol=3e-4, atol=5e-3)
@@ -256,3 +278,47 @@ def test_plan_structure_properties(d, seed):
     # paper Eq. 14: output is x-aligned (axis 0 local), axis 1 distributed
     assert plan.output_pencil.placement[0] is None
     assert plan.output_pencil.placement[1] == "p0" 
+
+
+@pytest.mark.parametrize("guard", ["off", "strict"])
+@pytest.mark.parametrize("nfields", [None, 3], ids=["unstacked", "stacked3"])
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_executor_one_per_key(subproc, direction, nfields, guard):
+    """One executor per (direction, stacking, schedule): ``warm()`` builds
+    the executor that ``forward``/``forward_many`` (or their backward
+    twins) then run, a repeat ``executor`` call returns that same object,
+    no call adds a second one, and the result matches numpy's FFT."""
+    subproc(f"""
+import jax.numpy as jnp, numpy as np
+from repro.core.meshutil import make_mesh
+from repro.core.pfft import ParallelFFT
+from repro.core.planconfig import PlanConfig
+
+direction, nfields, guard = {direction!r}, {nfields!r}, {guard!r}
+mesh = make_mesh((2, 2), ("p0", "p1"))
+shape = (8, 6, 10)
+plan = ParallelFFT(mesh, shape, ("p0", "p1"), config=PlanConfig(guard=guard))
+assert plan.warm((direction,), nfields=nfields or 1) == 1
+assert len(plan._executors) == 1
+fn = plan.executor(direction, nfields)
+assert plan.executor(direction, nfields) is fn
+sched = plan.schedule if nfields is None else plan.batched_schedule(nfields)
+assert plan.executor(direction, nfields, schedule=list(sched)) is fn
+
+rng = np.random.default_rng(0)
+full = ((nfields,) if nfields else ()) + shape
+x = (rng.standard_normal(full) + 1j * rng.standard_normal(full)).astype(np.complex64)
+if nfields:
+    run = plan.forward_many if direction == "forward" else plan.backward_many
+else:
+    run = plan.forward if direction == "forward" else plan.backward
+out = run(jnp.asarray(x))
+if guard != "off":
+    out, report = out
+    assert report.ok, report
+assert len(plan._executors) == 1, list(plan._executors)
+axes = (-3, -2, -1)
+want = (np.fft.fftn if direction == "forward" else np.fft.ifftn)(x, axes=axes)
+np.testing.assert_allclose(np.asarray(out), want, rtol=3e-4, atol=3e-3)
+print("EXECUTOR OK")
+""", ndev=4)

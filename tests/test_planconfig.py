@@ -1,22 +1,12 @@
-"""PlanConfig / StageEntry surface tests (repro.core.planconfig) and the
-ParallelFFT legacy-kwarg deprecation shim.
+"""PlanConfig / StageEntry surface tests (repro.core.planconfig).
 
-These are pure construction/validation tests — 1 in-process device, no
+These are pure construction/validation tests — no devices, no
 collectives — so they pin the API contract cheaply: StageEntry.make's
-legacy-tuple upgrades (including the 4-tuple impl-vs-batch_fusion
-disambiguation), PlanConfig validation/canonicalization round-trips, and
-the guarantee that a legacy-kwarg plan and its config= equivalent build
-identical plans while warning exactly once per process.
+5-field rows, and PlanConfig validation/canonicalization round-trips.
 """
 
-import warnings
-
-import numpy as np
 import pytest
 
-from repro.core import pfft as pfft_mod
-from repro.core.meshutil import make_mesh
-from repro.core.pfft import ParallelFFT
 from repro.core.planconfig import PlanConfig, StageEntry, as_schedule
 
 
@@ -27,15 +17,10 @@ from repro.core.planconfig import PlanConfig, StageEntry, as_schedule
 def test_stage_entry_make_all_forms():
     full = StageEntry("fused", 1, "bf16", "pallas", "per-field")
     assert StageEntry.make(full) == full
-    # legacy 3-tuple: defaults fill in
-    e = StageEntry.make(("traditional", 2, "int8"))
+    # a StageEntry's defaults fill in impl and batch_fusion
+    e = StageEntry.make(StageEntry("traditional", 2, "int8"))
     assert e == ("traditional", 2, "int8", "jnp", "stacked")
-    # 4-tuple disambiguation: the vocabularies are disjoint
-    e = StageEntry.make(("fused", 1, "bf16", "pipelined-across-fields"))
-    assert (e.impl, e.batch_fusion) == ("jnp", "pipelined-across-fields")
-    e = StageEntry.make(("fused", 1, "bf16", "pallas"))
-    assert (e.impl, e.batch_fusion) == ("pallas", "stacked")
-    # 5-tuple passes straight through
+    # a 5-field row (the tuner cache's form) passes straight through
     e = StageEntry.make(("pipelined", 4, "int8", "pallas", "stacked"))
     assert e == full._replace(method="pipelined", chunks=4, comm_dtype="int8",
                               batch_fusion="stacked")
@@ -55,27 +40,30 @@ def test_stage_entry_indexing_contract():
 
 def test_stage_entry_validation_and_canonicalization():
     # comm_dtype canonicalizes (None -> complex64) through validate()
-    assert StageEntry.make(("fused", 1, None)).comm_dtype == "complex64"
+    assert StageEntry.make(("fused", 1, None, "jnp", "stacked")).comm_dtype == "complex64"
     with pytest.raises(ValueError, match="unknown method"):
-        StageEntry.make(("auto", 1, "bf16"))  # "auto" is plan-level only
+        StageEntry.make(("auto", 1, "bf16", "jnp", "stacked"))  # "auto" is plan-level only
     with pytest.raises(ValueError, match="chunks"):
-        StageEntry.make(("fused", 0, "bf16"))
+        StageEntry.make(("fused", 0, "bf16", "jnp", "stacked"))
     with pytest.raises(ValueError, match="exchange impl"):
         StageEntry.make(("fused", 1, "bf16", "cuda", "stacked"))
     with pytest.raises(ValueError, match="batch_fusion"):
         StageEntry.make(("fused", 1, "bf16", "jnp", "interleaved"))
-    with pytest.raises(ValueError, match="3-5"):
-        StageEntry.make(("fused", 1))
-    with pytest.raises(ValueError, match="3-5"):
+    # only the 5-field row is a schedule entry (v5's 3/4-field rows are not)
+    for short in (("fused", 1), ("fused", 1, "bf16"), ("fused", 1, "bf16", "pallas")):
+        with pytest.raises(ValueError, match="expected 5"):
+            StageEntry.make(short)
+    with pytest.raises(ValueError, match="expected 5"):
         StageEntry.make(("fused", 1, "bf16", "jnp", "stacked", "extra"))
 
 
 def test_as_schedule_normalizes_mixed_forms():
-    sched = as_schedule([("fused", 1, "bf16"),
-                         ("pipelined", 4, "int8", "pallas"),
-                         StageEntry("traditional", 1, "complex64")])
+    sched = as_schedule([["fused", 1, "bf16", "jnp", "stacked"],
+                         ("pipelined", 4, "int8", "pallas", "per-field"),
+                         StageEntry("traditional", 1, None)])
     assert all(isinstance(e, StageEntry) and len(e) == 5 for e in sched)
     assert [e.impl for e in sched] == ["jnp", "pallas", "jnp"]
+    assert sched[2].comm_dtype == "complex64"
 
 
 # ---------------------------------------------------------------------------
@@ -110,81 +98,3 @@ def test_planconfig_stage_entry():
     assert e == ("fused", 1, "bf16", "pallas", "stacked")
     e = PlanConfig(method="pipelined", chunks=4, comm_dtype="int8").stage_entry()
     assert e == ("pipelined", 4, "int8", "jnp", "stacked")
-
-
-def test_from_legacy_kwargs_drops_nones():
-    cfg = PlanConfig.from_legacy_kwargs(method="traditional", impl=None,
-                                        chunks=None, comm_dtype="bf16")
-    assert (cfg.method, cfg.impl, cfg.chunks) == ("traditional", "jnp", 4)
-    assert cfg.comm_dtype == "bf16"
-
-
-# ---------------------------------------------------------------------------
-# ParallelFFT shim: legacy kwargs == config=, warn once, conflict errors
-# ---------------------------------------------------------------------------
-
-MESH = make_mesh((1,), ("p0",))
-
-
-def _reset_warn_flags():
-    pfft_mod._legacy_kwargs_warned = False
-    pfft_mod._real_kwarg_warned = False
-
-
-def test_legacy_kwargs_equivalent_and_warn_once():
-    _reset_warn_flags()
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        legacy = ParallelFFT(MESH, (8, 6, 4), ("p0",), method="pipelined",
-                             chunks=2, comm_dtype="bf16")
-        dep = [w for w in rec if issubclass(w.category, DeprecationWarning)]
-        assert len(dep) == 1 and "config=PlanConfig" in str(dep[0].message)
-        # second legacy construction: silent (once per process)
-        ParallelFFT(MESH, (8, 6, 4), ("p0",), method="pipelined", chunks=2,
-                    comm_dtype="bf16")
-        assert sum(issubclass(w.category, DeprecationWarning) for w in rec) == 1
-    cfg = ParallelFFT(MESH, (8, 6, 4), ("p0",),
-                      config=PlanConfig(method="pipelined", chunks=2,
-                                        comm_dtype="bf16"))
-    assert legacy.config == cfg.config
-    assert legacy.schedule == cfg.schedule
-    x = (np.arange(8 * 6 * 4).reshape(8, 6, 4) % 7 + 1j).astype(np.complex64)
-    np.testing.assert_array_equal(np.asarray(legacy.forward(x)),
-                                  np.asarray(cfg.forward(x)))
-
-
-def test_config_plus_legacy_kwarg_conflict():
-    with pytest.raises(ValueError, match="not both"):
-        ParallelFFT(MESH, (8, 6, 4), ("p0",), config=PlanConfig(),
-                    method="fused")
-
-
-def test_real_kwarg_deprecated_but_equivalent():
-    _reset_warn_flags()
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        legacy = ParallelFFT(MESH, (8, 6, 4), ("p0",), real=True)
-        assert any(issubclass(w.category, DeprecationWarning)
-                   and "transforms=" in str(w.message) for w in rec)
-    new = ParallelFFT(MESH, (8, 6, 4), ("p0",),
-                      transforms=("c2c", "c2c", "r2c"))
-    assert [s.kind for s in legacy.transforms] == [s.kind for s in new.transforms]
-    x = np.arange(8 * 6 * 4, dtype=np.float32).reshape(8, 6, 4) % 5
-    np.testing.assert_array_equal(np.asarray(legacy.forward(x)),
-                                  np.asarray(new.forward(x)))
-    with pytest.raises(ValueError, match="not both"):
-        ParallelFFT(MESH, (8, 6, 4), ("p0",), real=True,
-                    transforms=("c2c", "c2c", "r2c"))
-
-
-def test_plan_mirrors_config():
-    plan = ParallelFFT(MESH, (8, 6, 4), ("p0",),
-                       config=PlanConfig(method="traditional",
-                                         comm_dtype="int8",
-                                         exchange_impl="pallas",
-                                         guard="off"))
-    assert plan.method == "traditional"
-    assert plan.comm_dtype == "int8"
-    assert plan.exchange_impl == "pallas"
-    assert plan.guard == "off"
-    assert plan.config.stage_entry().impl == "pallas"

@@ -24,6 +24,7 @@ import json
 from repro.analysis.planlint import audit_plan
 from repro.core.meshutil import make_mesh
 from repro.core.pfft import ParallelFFT
+from repro.core.planconfig import PlanConfig, StageEntry
 
 mesh = make_mesh((2, 2), ("p0", "p1"))
 PENCIL, SLAB = ("p0", "p1"), ("p0",)
@@ -42,8 +43,9 @@ SPECS = {"c2c": None, "r2c": ("c2c", "c2c", "r2c"),
          "mixed": ("dct2", "c2c", "r2c")}
 for method in ("fused", "traditional", "pipelined"):
     for sname, transforms in SPECS.items():
-        plan = ParallelFFT(mesh, (8, 8, 8), PENCIL, method=method, chunks=2,
-                           transforms=transforms)
+        plan = ParallelFFT(mesh, (8, 8, 8), PENCIL,
+                           transforms=transforms,
+                           config=PlanConfig(method=method, chunks=2))
         rep = audit_plan(plan, label=f"{method}/{sname}")
         assert rep.ok, (method, sname, codes(rep), rep.violations)
         if method == "fused":
@@ -64,15 +66,16 @@ for method in ("fused", "traditional", "pipelined"):
 
 # backward direction walks the reversed plan
 for method in ("fused", "traditional"):
-    plan = ParallelFFT(mesh, (8, 8, 8), PENCIL, method=method,
-                       transforms=("dct2", "c2c", "r2c"))
+    plan = ParallelFFT(mesh, (8, 8, 8), PENCIL,
+                       transforms=("dct2", "c2c", "r2c"),
+                       config=PlanConfig(method=method))
     rep = audit_plan(plan, direction="backward")
     assert rep.ok, (method, codes(rep))
     if method == "fused":
         assert rep.observed["engine_transposes"] == 0
 
 # slab decomposition: one exchange stage
-slab = ParallelFFT(mesh, (8, 8, 8), SLAB, method="fused")
+slab = ParallelFFT(mesh, (8, 8, 8), SLAB, config=PlanConfig(method="fused"))
 rep = audit_plan(slab)
 assert rep.ok and slab.n_exchanges == 1
 assert rep.observed["jaxpr_all_to_alls"] == 1
@@ -97,8 +100,8 @@ BW = 1e9
 for grid in (PENCIL, SLAB):
     for method in ("fused", "traditional", "pipelined"):
         for cd in (None, "bf16", "int8"):
-            plan = ParallelFFT(mesh, (8, 8, 8), grid, method=method,
-                               chunks=2, comm_dtype=cd)
+            plan = ParallelFFT(mesh, (8, 8, 8), grid,
+                               config=PlanConfig(method=method, chunks=2, comm_dtype=cd))
             rep = audit_plan(plan, label=f"{grid}/{method}/{cd}")
             assert rep.ok, (grid, method, cd, codes(rep), rep.violations)
             wire = rep.expected["wire_bytes"]
@@ -126,8 +129,8 @@ def test_audit_batched_fusions(subproc):
     restack with exactly one engine concatenate per stage."""
     code = _PRELUDE + """
 for fusion in ("stacked", "per-field", "pipelined-across-fields"):
-    plan = ParallelFFT(mesh, (8, 8, 8), PENCIL, method="fused",
-                       batch_fusion=fusion)
+    plan = ParallelFFT(mesh, (8, 8, 8), PENCIL,
+                       config=PlanConfig(method="fused", batch_fusion=fusion))
     rep = audit_plan(plan, nfields=3, label=f"fused/{fusion}")
     assert rep.ok, (fusion, codes(rep), rep.violations)
     want = plan.n_exchanges if fusion == "stacked" else plan.n_exchanges * 3
@@ -138,14 +141,14 @@ for fusion in ("stacked", "per-field", "pipelined-across-fields"):
         assert rep.observed["engine_concats"] == plan.n_exchanges
 
 # traditional batched: per-field pack/unpack copies scale with nfields
-plan = ParallelFFT(mesh, (8, 8, 8), PENCIL, method="traditional",
-                   batch_fusion="per-field")
+plan = ParallelFFT(mesh, (8, 8, 8), PENCIL,
+                   config=PlanConfig(method="traditional", batch_fusion="per-field"))
 rep = audit_plan(plan, nfields=3)
 assert rep.ok, (codes(rep), rep.violations)
 assert rep.observed["engine_transposes"] == rep.expected["engine_transposes"] > 0
 
 # batched backward + a narrowed batched payload
-plan = ParallelFFT(mesh, (8, 8, 8), PENCIL, method="fused", comm_dtype="bf16")
+plan = ParallelFFT(mesh, (8, 8, 8), PENCIL, config=PlanConfig(method="fused", comm_dtype="bf16"))
 for direction in ("forward", "backward"):
     rep = audit_plan(plan, nfields=3, direction=direction)
     assert rep.ok, (direction, codes(rep), rep.violations)
@@ -158,49 +161,49 @@ def test_audit_negative_claims(subproc):
     """The auditor must reject artifacts whose claimed schedule lies: each
     mis-claim is caught with the violation code that names the lie."""
     code = _PRELUDE + """
-SCHED_FUSED = (("fused", 1, "complex64"),) * 2
-SCHED_BF16 = (("fused", 1, "bf16"),) * 2
+SCHED_FUSED = (StageEntry("fused", 1, "complex64"),) * 2
+SCHED_BF16 = (StageEntry("fused", 1, "bf16"),) * 2
 
 # 1) traditional artifact claiming fused: realignment transposes appear
-rep = audit_plan(ParallelFFT(mesh, (8, 8, 8), PENCIL, method="traditional"),
+rep = audit_plan(ParallelFFT(mesh, (8, 8, 8), PENCIL, config=PlanConfig(method="traditional")),
                  schedule=SCHED_FUSED)
 assert "PLAN003" in codes(rep), codes(rep)
 
 # 2) pipelined artifact claiming fused: launch count betrays the slices
-rep = audit_plan(ParallelFFT(mesh, (8, 8, 8), PENCIL, method="pipelined",
-                             chunks=2), schedule=SCHED_FUSED)
+rep = audit_plan(ParallelFFT(mesh, (8, 8, 8), PENCIL,
+                             config=PlanConfig(method="pipelined", chunks=2)), schedule=SCHED_FUSED)
 assert "PLAN001" in codes(rep), codes(rep)
 
 # 3) lossless artifact claiming bf16: no quantize converts in the jaxpr
 #    (the CPU widening acceptance must NOT let this one through)
-rep = audit_plan(ParallelFFT(mesh, (8, 8, 8), PENCIL, method="fused"),
+rep = audit_plan(ParallelFFT(mesh, (8, 8, 8), PENCIL, config=PlanConfig(method="fused")),
                  schedule=SCHED_BF16)
 assert "PLAN006" in codes(rep), codes(rep)
 
 # 4) bf16 artifact claiming lossless: converts present but unclaimed
-rep = audit_plan(ParallelFFT(mesh, (8, 8, 8), PENCIL, method="fused",
-                             comm_dtype="bf16"), schedule=SCHED_FUSED)
+rep = audit_plan(ParallelFFT(mesh, (8, 8, 8), PENCIL,
+                             config=PlanConfig(method="fused", comm_dtype="bf16")), schedule=SCHED_FUSED)
 assert "PLAN006" in codes(rep), codes(rep)
 
 # 5) int8 artifact claiming lossless: scale exchanges double the launch
 #    count and the payload bytes shrink 4x
-rep = audit_plan(ParallelFFT(mesh, (8, 8, 8), PENCIL, method="fused",
-                             comm_dtype="int8"), schedule=SCHED_FUSED)
+rep = audit_plan(ParallelFFT(mesh, (8, 8, 8), PENCIL,
+                             config=PlanConfig(method="fused", comm_dtype="int8")), schedule=SCHED_FUSED)
 got = set(codes(rep))
 assert {"PLAN001", "PLAN006"} <= got, got
 json.dumps(rep.to_dict(), default=str)  # failing reports serialize too
 
 # 6) jnp artifact claiming the fused pallas kernels: zero kernel
 #    launches in the artifact betray the claim
-SCHED_PALLAS = (("fused", 1, "int8", "pallas"),) * 2
-rep = audit_plan(ParallelFFT(mesh, (8, 8, 8), PENCIL, method="fused",
-                             comm_dtype="int8"), schedule=SCHED_PALLAS)
+SCHED_PALLAS = (StageEntry("fused", 1, "int8", "pallas"),) * 2
+rep = audit_plan(ParallelFFT(mesh, (8, 8, 8), PENCIL,
+                             config=PlanConfig(method="fused", comm_dtype="int8")), schedule=SCHED_PALLAS)
 assert "PLAN009" in codes(rep), codes(rep)
 
 # a claimed schedule with the wrong stage count is a usage error
 try:
     audit_plan(ParallelFFT(mesh, (8, 8, 8), PENCIL),
-               schedule=(("fused", 1, "complex64"),))
+               schedule=(StageEntry("fused", 1, "complex64"),))
 except ValueError as e:
     assert "exchange stages" in str(e)
 else:
@@ -248,8 +251,8 @@ def test_audit_auto_schedule_and_cli(subproc, tmp_path):
     report = tmp_path / "plan_audit.json"
     code = _PRELUDE + f"""
 cache = {str(cache)!r}
-plan = ParallelFFT(mesh, (8, 8, 8), PENCIL, method="auto", comm_dtype="bf16",
-                   tuner_cache=cache)
+plan = ParallelFFT(mesh, (8, 8, 8), PENCIL,
+                   config=PlanConfig(method="auto", comm_dtype="bf16", tuner_cache=cache))
 sched = plan.schedule  # resolves via the tuner sweep
 rep = audit_plan(plan, label="auto")
 assert rep.ok, (sched, codes(rep), rep.violations)

@@ -18,6 +18,7 @@ import pytest
 from _hyp import given, settings, strategies as st
 
 from repro.core import quant, tuner
+from repro.core.planconfig import StageEntry
 from repro.checkpoint import load_checkpoint, save_checkpoint
 from repro.robustness import FaultPlan, faults, health
 from repro.robustness.runner import degrade_entry, degrade_schedule
@@ -45,31 +46,34 @@ def test_degrade_entry_walks_payload_then_impl_then_engine():
         ("fused", 1, "complex64", "jnp", "stacked"),
         ("traditional", 1, "complex64", "jnp", "stacked"),
     ]
-    # legacy 4-tuple entries upgrade in place (jnp impl) and walk the
-    # same ladder
-    assert tuple(degrade_entry(("pipelined", 4, "int8", "stacked"))) == (
+    # a StageEntry's default impl and fusion walk the same ladder; a row
+    # that is not a 5-field entry (v5's 4-tuples) is refused
+    assert tuple(degrade_entry(StageEntry("pipelined", 4, "int8"))) == (
         "pipelined", 4, "bf16", "jnp", "stacked")
+    with pytest.raises(ValueError, match="expected 5"):
+        degrade_entry(("pipelined", 4, "int8", "stacked"))
 
 
 def test_degrade_schedule_targets_only_named_stages():
-    sched = (("fused", 1, "int8", "stacked"), ("fused", 1, "int8", "stacked"))
+    sched = (("fused", 1, "int8", "jnp", "stacked"),) * 2
     new = degrade_schedule(sched, stages=(1,))
     # untargeted entries pass through as-is; degraded ones come back as
-    # full 5-field StageEntry rows
-    assert new == (("fused", 1, "int8", "stacked"),
+    # StageEntry rows
+    assert new == (("fused", 1, "int8", "jnp", "stacked"),
                    ("fused", 1, "bf16", "jnp", "stacked"))
+    assert new[0] is sched[0] and isinstance(new[1], StageEntry)
 
 
 def test_degrade_schedule_exhaustion():
-    bottom = (("traditional", 1, "complex64", "stacked"),)
+    bottom = (("traditional", 1, "complex64", "jnp", "stacked"),)
     assert degrade_schedule(bottom) is None
-    mixed = (("traditional", 1, "complex64", "stacked"),
-             ("fused", 1, "int8", "stacked"))
+    mixed = (("traditional", 1, "complex64", "jnp", "stacked"),
+             ("fused", 1, "int8", "jnp", "stacked"))
     # the targeted stage has no rung left -> exhausted, even though the
     # untargeted one does
     assert degrade_schedule(mixed, stages=(0,)) is None
     assert degrade_schedule(mixed) == (
-        ("traditional", 1, "complex64", "stacked"),
+        ("traditional", 1, "complex64", "jnp", "stacked"),
         ("fused", 1, "bf16", "jnp", "stacked"))
 
 
@@ -77,10 +81,11 @@ def test_guard_mode_validated():
     from jax.sharding import Mesh
 
     from repro.core.pfft import ParallelFFT
+    from repro.core.planconfig import PlanConfig
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("p0",))
     with pytest.raises(ValueError, match="unknown guard"):
-        ParallelFFT(mesh, (4, 4), grid=("p0",), guard="paranoid")
+        ParallelFFT(mesh, (4, 4), grid=("p0",), config=PlanConfig(guard="paranoid"))
 
 
 # ---------------------------------------------------------------------------
@@ -193,10 +198,11 @@ import json, math
 import numpy as np, jax
 from jax.sharding import Mesh
 from repro.core.pfft import ParallelFFT
+from repro.core.planconfig import PlanConfig, StageEntry
 from repro.robustness import health
 
 mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("p0", "p1"))
-plan = ParallelFFT(mesh, (16, 8, 8), grid=("p0", "p1"), method="fused")
+plan = ParallelFFT(mesh, (16, 8, 8), grid=("p0", "p1"), config=PlanConfig(method="fused"))
 S = plan.n_exchanges
 N = math.prod(plan.shape)
 
@@ -271,12 +277,13 @@ import json
 import numpy as np, jax
 from jax.sharding import Mesh
 from repro.core.pfft import ParallelFFT
+from repro.core.planconfig import PlanConfig, StageEntry
 from repro.analysis import planlint
 
 mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("p0", "p1"))
 def mk(guard):
-    return ParallelFFT(mesh, (16, 8, 8), grid=("p0", "p1"), method="fused",
-                       guard=guard)
+    return ParallelFFT(mesh, (16, 8, 8), grid=("p0", "p1"),
+                       config=PlanConfig(method="fused", guard=guard))
 a_off = planlint.audit_plan(mk("off"))
 a_on = planlint.audit_plan(mk("strict"))
 print("PLAN008=" + json.dumps({
@@ -309,6 +316,7 @@ import json, pathlib, tempfile
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh
 from repro.core.pfft import ParallelFFT
+from repro.core.planconfig import PlanConfig, StageEntry
 from repro.robustness import FaultPlan
 from repro.robustness.runner import GuardError
 
@@ -317,13 +325,13 @@ SHAPE, GRID = (16, 8, 8), ("p0", "p1")
 rng = np.random.default_rng(0)
 x = jnp.asarray((rng.standard_normal(SHAPE)
                  + 1j * rng.standard_normal(SHAPE)).astype(np.complex64))
-base = ParallelFFT(mesh, SHAPE, grid=GRID, method="fused")
+base = ParallelFFT(mesh, SHAPE, grid=GRID, config=PlanConfig(method="fused"))
 y_ref = base.forward(x)
 x_back_ref = base.backward(y_ref)
 
 def plan(**kw):
     kw.setdefault("method", "fused")
-    return ParallelFFT(mesh, SHAPE, grid=GRID, **kw)
+    return ParallelFFT(mesh, SHAPE, grid=GRID, config=PlanConfig(**kw))
 
 def rel(a, b):
     a, b = np.asarray(a), np.asarray(b)
@@ -387,7 +395,7 @@ cache = pathlib.Path(tempfile.mkdtemp()) / "tuner.json"
 p = plan(method="auto", guard="degrade", tuner_cache=str(cache))
 # must be inside the live candidate set or the entry is rejected as
 # malformed (and simply retuned) instead of replayed and quarantined
-poisoned = tuple(("pipelined", 2, "complex64") for _ in range(p.n_exchanges))
+poisoned = tuple(StageEntry("pipelined", 2, "complex64") for _ in range(p.n_exchanges))
 FaultPlan.poison_cache(cache, p, poisoned)
 with FaultPlan().fail_compile(engine="pipelined"):
     y, rep = p.forward(x)

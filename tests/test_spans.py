@@ -52,12 +52,7 @@ def lowered(case, direction, nfields=1):
     plan = plan_of(case)
     pen, dt = ((plan.input_pencil, plan.input_dtype) if direction == "forward"
                else (plan.output_pencil, plan.spectral_dtype))
-    if plan.guard != "off":
-        fn = plan.guarded_padded(direction, nfields=nfields)
-    elif nfields > 1:
-        fn = plan._many_padded(nfields, direction)
-    else:
-        fn = plan.forward_padded if direction == "forward" else plan.backward_padded
+    fn = plan.executor(direction, nfields if nfields > 1 else None)
     shape = ((nfields,) if nfields > 1 else ()) + pen.physical
     shard = pen.batched_sharding(1) if nfields > 1 else pen.sharding
     x = jax.ShapeDtypeStruct(shape, dt, sharding=shard)

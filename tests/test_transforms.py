@@ -57,8 +57,12 @@ def test_plan_transforms_validation():
     mesh = make_mesh((1,), ("p0",))
     with pytest.raises(ValueError):  # wrong arity
         ParallelFFT(mesh, (8, 8, 8), ("p0",), transforms=("c2c", "c2c"))
-    with pytest.raises(ValueError):  # real= and transforms= are exclusive
-        ParallelFFT(mesh, (8, 8), ("p0",), real=True, transforms=("c2c", "r2c"))
+    # the constructor's whole surface: no real= shorthand, no execution
+    # keywords beside config=
+    import inspect
+
+    assert list(inspect.signature(ParallelFFT).parameters) == [
+        "mesh", "shape", "grid", "config", "transforms"]
     # r2c must be applied while the data is still real: every axis after it
     # (higher index, applied earlier) must be dct/dst
     with pytest.raises(ValueError):
@@ -78,7 +82,8 @@ def test_plan_transforms_validation():
 
 def test_pruned_plan_structure():
     """Pruned axes shrink the pencil trace (exchanges after a truncation
-    carry only the retained modes) and real= sugar equals the spec form."""
+    carry only the retained modes) and the default transforms equal the
+    explicit all-c2c spec."""
     from repro.core.meshutil import make_mesh
     from repro.core.pfft import ParallelFFT
 
@@ -92,7 +97,8 @@ def test_pruned_plan_structure():
     import numpy as np
     from repro.core.pfft import ExchangeStage
 
-    full = ParallelFFT(mesh, (12, 12, 12), ("p0", "p1"), real=True)
+    full = ParallelFFT(mesh, (12, 12, 12), ("p0", "p1"),
+                       transforms=("c2c", "c2c", "r2c"))
     pruned_elems = sum(int(np.prod(p.logical)) for st, p in
                        zip(plan.stages, plan.pencil_trace)
                        if isinstance(st, ExchangeStage))
@@ -100,11 +106,11 @@ def test_pruned_plan_structure():
                      zip(full.stages, full.pencil_trace)
                      if isinstance(st, ExchangeStage))
     assert pruned_elems < full_elems
-    sugar = ParallelFFT(mesh, (12, 12, 12), ("p0", "p1"), real=True)
+    default = ParallelFFT(mesh, (12, 12, 12), ("p0", "p1"))
     spec = ParallelFFT(mesh, (12, 12, 12), ("p0", "p1"),
-                       transforms=("c2c", "c2c", "r2c"))
-    assert sugar.transforms == spec.transforms
-    assert sugar.output_pencil == spec.output_pencil
+                       transforms=("c2c", "c2c", "c2c"))
+    assert default.transforms == spec.transforms
+    assert default.output_pencil == spec.output_pencil
 
 
 def test_trig_matrices_are_mutual_inverses():
@@ -363,12 +369,13 @@ from repro.core import tuner
 from repro.core.fftcore import TransformSpec
 from repro.core.meshutil import make_mesh
 from repro.core.pfft import ParallelFFT
+from repro.core.planconfig import PlanConfig
 
 cache = {str(cache)!r}
 mesh = make_mesh((2, 2), ("p0", "p1"))
 specs = (TransformSpec.pruned(8), TransformSpec.c2c(), TransformSpec.r2c())
 plan = ParallelFFT(mesh, (12, 8, 8), ("p0", "p1"), transforms=specs,
-                   method="auto", tuner_cache=cache)
+                   config=PlanConfig(method="auto", tuner_cache=cache))
 sched = plan.schedule
 assert len(sched) == plan.n_exchanges == 2
 
@@ -383,7 +390,7 @@ assert json.loads(key)["transforms"] == ["c2c[8]", "c2c", "r2c"]
 tuner._MEMO.clear()
 tuner.tune_plan = None
 plan2 = ParallelFFT(mesh, (12, 8, 8), ("p0", "p1"), transforms=specs,
-                    method="auto", tuner_cache=cache)
+                    config=PlanConfig(method="auto", tuner_cache=cache))
 assert plan2.schedule == sched
 
 # and the tuned mixed-transform plan is still correct

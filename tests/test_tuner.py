@@ -1,6 +1,6 @@
 """Exchange-schedule autotuner: candidate sweep (engines × comm_dtype
 payloads × exchange impls × batch fusions), schema-v6 disk cache
-round-trip, stale-cache migration, atomic merge writes, quarantine
+round-trip, stale caches retuned, atomic merge writes, quarantine
 marks."""
 
 import json
@@ -21,10 +21,11 @@ import jax, numpy as np
 from repro.core import tuner
 from repro.core.meshutil import make_mesh
 from repro.core.pfft import ParallelFFT
+from repro.core.planconfig import PlanConfig
 
 cache = {str(cache)!r}
 mesh = make_mesh((2, 2), ("p0", "p1"))
-plan = ParallelFFT(mesh, (16, 8, 8), ("p0", "p1"), method="auto", tuner_cache=cache)
+plan = ParallelFFT(mesh, (16, 8, 8), ("p0", "p1"), config=PlanConfig(method="auto", tuner_cache=cache))
 sched = plan.schedule
 assert len(sched) == plan.n_exchanges == 2
 for method, chunks, comm_dtype, impl, fusion in sched:
@@ -54,7 +55,7 @@ tuner._MEMO.clear()
 def boom(*a, **k):
     raise AssertionError("cache miss: tune_plan re-ran")
 tuner.tune_plan = boom
-plan2 = ParallelFFT(mesh, (16, 8, 8), ("p0", "p1"), method="auto", tuner_cache=cache)
+plan2 = ParallelFFT(mesh, (16, 8, 8), ("p0", "p1"), config=PlanConfig(method="auto", tuner_cache=cache))
 assert plan2.schedule == sched
 print("TUNER CACHE OK", json.dumps([list(s) for s in sched]))
 """
@@ -72,11 +73,12 @@ import json
 from repro.core import tuner
 from repro.core.meshutil import make_mesh
 from repro.core.pfft import ParallelFFT
+from repro.core.planconfig import PlanConfig
 
 cache = {str(cache)!r}
 mesh = make_mesh((2, 2), ("p0", "p1"))
-plan = ParallelFFT(mesh, (16, 8, 8), ("p0", "p1"), method="auto",
-                   comm_dtype="int8", tuner_cache=cache)
+plan = ParallelFFT(mesh, (16, 8, 8), ("p0", "p1"),
+                   config=PlanConfig(method="auto", comm_dtype="int8", tuner_cache=cache))
 sched = plan.schedule
 assert len(sched) == 2
 for method, chunks, comm_dtype, impl, fusion in sched:
@@ -91,8 +93,8 @@ for per in disk[key]["timings"].values():
 # a fresh process (memo empty) must reload the same schedule
 tuner._MEMO.clear()
 tuner.tune_plan = None  # cache hit must not benchmark
-plan2 = ParallelFFT(mesh, (16, 8, 8), ("p0", "p1"), method="auto",
-                    comm_dtype="int8", tuner_cache=cache)
+plan2 = ParallelFFT(mesh, (16, 8, 8), ("p0", "p1"),
+                    config=PlanConfig(method="auto", comm_dtype="int8", tuner_cache=cache))
 assert plan2.schedule == sched
 print("BUDGET CACHE OK", json.dumps([list(s) for s in sched]))
 """
@@ -105,7 +107,8 @@ def test_stale_or_corrupt_cache_ignored_and_rewritten(subproc, tmp_path):
     file dropped in the cache path before ``method="auto"`` must be
     silently ignored and rewritten with a valid current-schema entry —
     never raise.  Covers: invalid JSON, a JSON non-dict, a stale v3-style
-    entry set, and a matching current key whose entry body is malformed."""
+    entry set, a matching current key whose entry body is malformed, and a
+    schema-5 cache with 3-field rows."""
     cache = tmp_path / "fft_tuner.json"
     code = f"""
 import json
@@ -113,6 +116,7 @@ from pathlib import Path
 from repro.core import tuner
 from repro.core.meshutil import make_mesh
 from repro.core.pfft import ParallelFFT
+from repro.core.planconfig import PlanConfig
 
 cache = Path({str(cache)!r})
 mesh = make_mesh((2, 2), ("p0", "p1"))
@@ -125,8 +129,8 @@ stale_payloads = [
 for payload in stale_payloads:
     cache.write_text(payload)
     tuner._MEMO.clear()
-    plan = ParallelFFT(mesh, (16, 8, 8), ("p0", "p1"), method="auto",
-                       tuner_cache=str(cache))
+    plan = ParallelFFT(mesh, (16, 8, 8), ("p0", "p1"),
+                       config=PlanConfig(method="auto", tuner_cache=str(cache)))
     sched = plan.schedule  # must tune and rewrite, not raise
     assert len(sched) == plan.n_exchanges == 2
     disk = json.loads(cache.read_text())  # rewritten as valid JSON
@@ -137,21 +141,20 @@ for payload in stale_payloads:
 
 # a *matching* v4 key whose entry body is junk must also fall back to
 # retuning instead of raising or replaying garbage
-plan = ParallelFFT(mesh, (16, 8, 8), ("p0", "p1"), method="auto",
-                   tuner_cache=str(cache))
+plan = ParallelFFT(mesh, (16, 8, 8), ("p0", "p1"),
+                   config=PlanConfig(method="auto", tuner_cache=str(cache)))
 key = tuner.plan_key(plan)
+ok_row = ["fused", 1, "complex64", "jnp", "stacked"]
 for bad_entry in ("garbage", {{"schedule": "garbage"}}, {{"schedule": [["x"]]}},
-                  {{"schedule": [["fused", 1, "complex64"]]}},  # wrong stage count
+                  {{"schedule": [ok_row]}},  # wrong stage count
                   # structurally valid but unknown engine / payload values:
                   # must retune, not raise later inside the executor
-                  {{"schedule": [["bogus", 1, "complex64"],
-                                 ["fused", 1, "complex64"]]}},
-                  {{"schedule": [["fused", 1, "float8"],
-                                 ["fused", 1, "complex64"]]}}):
+                  {{"schedule": [["bogus", 1, "complex64", "jnp", "stacked"], ok_row]}},
+                  {{"schedule": [["fused", 1, "float8", "jnp", "stacked"], ok_row]}}):
     cache.write_text(json.dumps({{key: bad_entry}}))
     tuner._MEMO.clear()
-    p = ParallelFFT(mesh, (16, 8, 8), ("p0", "p1"), method="auto",
-                    tuner_cache=str(cache))
+    p = ParallelFFT(mesh, (16, 8, 8), ("p0", "p1"),
+                    config=PlanConfig(method="auto", tuner_cache=str(cache)))
     sched = p.schedule
     assert len(sched) == 2 and all(len(e) == 5 for e in sched)
     disk = json.loads(cache.read_text())
@@ -160,16 +163,43 @@ for bad_entry in ("garbage", {{"schedule": "garbage"}}, {{"schedule": [["x"]]}},
 # entries that parse fine but name candidates OUTSIDE the live sweep (a
 # cache written by a different build, or hand-edited) must retune too —
 # replaying them would execute a schedule the tuner never timed
-for poisoned in ([["pipelined", 16, "complex64"], ["fused", 1, "complex64"]],
-                 [["fused", 1, "int8"], ["fused", 1, "complex64"]]):
+for poisoned in ([["pipelined", 16, "complex64", "jnp", "stacked"], ok_row],
+                 [["fused", 1, "int8", "jnp", "stacked"], ok_row]):
     cache.write_text(json.dumps({{key: {{"schedule": poisoned, "timings": {{}}}}}}))
     tuner._MEMO.clear()
-    p = ParallelFFT(mesh, (16, 8, 8), ("p0", "p1"), method="auto",
-                    tuner_cache=str(cache))
+    p = ParallelFFT(mesh, (16, 8, 8), ("p0", "p1"),
+                    config=PlanConfig(method="auto", tuner_cache=str(cache)))
     sched = p.schedule
     live = set(tuner.candidates_for(None))
     assert all(tuple(e) in live for e in sched), (poisoned, sched)
     assert list(map(list, sched)) != poisoned
+
+# a schema-5 cache (3-field rows, under its v5 key and under the live key)
+# is a stale cache like any other: it retunes, never replays or raises
+fields = tuner._key_fields(plan, 1)
+fields["schema"] = 5  # the key v5 wrote: its jnp-only 3-field candidate tags
+fields["candidates"] = sorted(f"{{m}}@{{c}}@complex64" for m, c in tuner.ENGINE_CANDIDATES)
+v5_key = json.dumps(fields, sort_keys=True, default=str)
+v5_rows = [["fused", 1, "complex64"], ["traditional", 1, "complex64"]]
+tuned = []
+tune_plan = tuner.tune_plan
+def counting(*a, **k):
+    tuned.append(1)
+    return tune_plan(*a, **k)
+tuner.tune_plan = counting
+for entries in ({{v5_key: {{"schedule": v5_rows, "timings": {{}}}}}},
+                {{key: {{"schedule": v5_rows, "timings": {{}}}}}}):
+    cache.write_text(json.dumps(entries))
+    tuner._MEMO.clear()
+    p = ParallelFFT(mesh, (16, 8, 8), ("p0", "p1"),
+                    config=PlanConfig(method="auto", tuner_cache=str(cache)))
+    sched = p.schedule
+    assert all(len(e) == 5 for e in sched), sched
+    disk = json.loads(cache.read_text())
+    assert [tuple(s) for s in disk[key]["schedule"]] == list(sched)
+    assert "migrated_from_schema" not in disk[key]
+assert len(tuned) == 2, tuned
+tuner.tune_plan = tune_plan
 print("STALE CACHE MIGRATION OK")
 """
     out = subproc(code, ndev=4)
@@ -180,17 +210,20 @@ def test_plan_key_discriminates():
     """Key must change with anything that changes stage shapes/engines."""
     from repro.core.meshutil import make_mesh
     from repro.core.pfft import ParallelFFT
+    from repro.core.planconfig import PlanConfig
 
     mesh = make_mesh((1, 1), ("p0", "p1"))
-    base = ParallelFFT(mesh, (8, 8, 8), ("p0",), method="auto")
+    auto = PlanConfig(method="auto")
+    base = ParallelFFT(mesh, (8, 8, 8), ("p0",), config=auto)
     keys = {tuner.plan_key(base)}
     for plan in (
-        ParallelFFT(mesh, (8, 8, 16), ("p0",), method="auto"),
-        ParallelFFT(mesh, (8, 8, 8), ("p0", "p1"), method="auto"),
-        ParallelFFT(mesh, (8, 8, 8), ("p0",), real=True, method="auto"),
-        ParallelFFT(mesh, (8, 8, 8), ("p0",), impl="matmul", method="auto"),
-        ParallelFFT(mesh, (8, 8, 8), ("p0",), method="auto", comm_dtype="bf16"),
-        ParallelFFT(mesh, (8, 8, 8), ("p0",), method="auto", comm_dtype="int8"),
+        ParallelFFT(mesh, (8, 8, 16), ("p0",), config=auto),
+        ParallelFFT(mesh, (8, 8, 8), ("p0", "p1"), config=auto),
+        ParallelFFT(mesh, (8, 8, 8), ("p0",), config=auto,
+                    transforms=("c2c", "c2c", "r2c")),
+        ParallelFFT(mesh, (8, 8, 8), ("p0",), config=auto.replace(impl="matmul")),
+        ParallelFFT(mesh, (8, 8, 8), ("p0",), config=auto.replace(comm_dtype="bf16")),
+        ParallelFFT(mesh, (8, 8, 8), ("p0",), config=auto.replace(comm_dtype="int8")),
     ):
         keys.add(tuner.plan_key(plan))
     assert len(keys) == 7
@@ -270,121 +303,6 @@ def test_save_cache_atomic(tmp_path):
     # serialized the read-merge-write cycles)
     final = json.loads(path.read_text())
     assert {f"key{i}" for i in range(8)} <= set(final)
-
-
-def test_v5_entry_migrates_without_retune(subproc, tmp_path):
-    """A healthy schema-5 cache entry (3-field jnp rows) must be *migrated*
-    to v6 — upgraded through StageEntry.make and re-saved under the v6 key
-    with ``migrated_from_schema: 5`` — never re-benchmarked: the jnp-only
-    candidate space is unchanged, so the v5 timings stay valid.  An
-    ``exchange_impl="pallas"`` budget must refuse the migration (its v6
-    candidate set sweeps kernels the v5 run never measured) and retune."""
-    cache = tmp_path / "fft_tuner.json"
-    code = f"""
-import json
-from pathlib import Path
-from repro.core import tuner
-from repro.core.meshutil import make_mesh
-from repro.core.pfft import ParallelFFT
-from repro.core.planconfig import PlanConfig
-
-cache = Path({str(cache)!r})
-mesh = make_mesh((2, 2), ("p0", "p1"))
-mk = lambda **kw: ParallelFFT(mesh, (16, 8, 8), ("p0", "p1"),
-                              config=PlanConfig(method="auto",
-                                                tuner_cache=str(cache), **kw))
-plan = mk()
-
-# hand-build the v5 cache file: schema-5 key, 3-field schedule rows, the
-# legacy jnp-only candidate tags
-fields = tuner._key_fields(plan, 1)
-fields["schema"] = 5
-fields["candidates"] = sorted(
-    tuner._tag(c) for c in tuner._legacy_v5_candidates(plan, 1))
-legacy_key = json.dumps(fields, sort_keys=True, default=str)
-v5_sched = [["fused", 1, "complex64"], ["traditional", 1, "complex64"]]
-v5_timings = {{"stage1": {{"fused@1@complex64": 1e-4}}}}
-cache.write_text(json.dumps(
-    {{legacy_key: {{"schedule": v5_sched, "timings": v5_timings}}}}))
-
-# poison tune_plan: a migration that falls back to benchmarking is a bug
-real_tune = tuner.tune_plan
-def boom(*a, **k):
-    raise AssertionError("v5 migration fell back to retuning")
-tuner.tune_plan = boom
-tuner._MEMO.clear()
-sched = mk().schedule
-assert [list(s) for s in sched] == [s + ["jnp", "stacked"] for s in v5_sched]
-
-disk = json.loads(cache.read_text())
-v6_key = tuner.plan_key(plan)
-assert v6_key in disk and legacy_key in disk  # migrated copy, original kept
-assert disk[v6_key]["migrated_from_schema"] == 5
-assert disk[v6_key]["timings"] == v5_timings  # timings carried over
-assert [tuple(s) for s in disk[v6_key]["schedule"]] == list(sched)
-
-# a quarantined v5 entry must NOT migrate (the mark is the whole point)
-cache.write_text(json.dumps({{legacy_key: {{
-    "schedule": v5_sched, "timings": {{}}, "bad": {{"reason": "x"}}}}}}))
-tuner._MEMO.clear()
-try:
-    mk().schedule
-    raise SystemExit("quarantined v5 entry was replayed")
-except AssertionError as e:
-    assert "retuning" in str(e)
-
-# pallas budget: v5 never measured the kernel candidates -> must retune
-cache.write_text(json.dumps(
-    {{legacy_key: {{"schedule": v5_sched, "timings": v5_timings}}}}))
-tuner._MEMO.clear()
-try:
-    mk(exchange_impl="pallas").schedule
-    raise SystemExit("pallas budget migrated a jnp-only v5 entry")
-except AssertionError as e:
-    assert "retuning" in str(e)
-tuner.tune_plan = real_tune
-print("V5 MIGRATION OK")
-"""
-    out = subproc(code, ndev=4)
-    assert "V5 MIGRATION OK" in out
-
-
-def test_committed_v5_fixture_migrates(subproc, tmp_path):
-    """The committed v5 cache fixture (tests/data/fft_tuner_v5.json,
-    generated on the cpu backend the CI matrix runs) must resolve its
-    plan's schedule by migration alone — tune_plan poisoned — proving old
-    user caches survive the v6 schema bump without a retune."""
-    import shutil
-    from pathlib import Path
-
-    fixture = Path(__file__).parent / "data" / "fft_tuner_v5.json"
-    cache = tmp_path / "fft_tuner.json"
-    shutil.copy(fixture, cache)
-    code = f"""
-import json
-from pathlib import Path
-from repro.core import tuner
-from repro.core.meshutil import make_mesh
-from repro.core.pfft import ParallelFFT
-from repro.core.planconfig import PlanConfig
-
-cache = Path({str(cache)!r})
-def boom(*a, **k):
-    raise AssertionError("committed v5 cache was not migrated: tune_plan ran")
-tuner.tune_plan = boom
-mesh = make_mesh((2, 2), ("p0", "p1"))
-plan = ParallelFFT(mesh, (16, 8, 8), ("p0", "p1"),
-                   config=PlanConfig(method="auto", tuner_cache=str(cache)))
-sched = plan.schedule
-assert [list(s) for s in sched] == [["fused", 1, "complex64", "jnp", "stacked"],
-                                    ["traditional", 1, "complex64", "jnp", "stacked"]]
-disk = json.loads(cache.read_text())
-v6 = disk[tuner.plan_key(plan)]
-assert v6["migrated_from_schema"] == 5 and v6["timings"]
-print("COMMITTED V5 FIXTURE OK")
-"""
-    out = subproc(code, ndev=4)
-    assert "COMMITTED V5 FIXTURE OK" in out
 
 
 def test_quarantine_locks_without_self_deadlock(tmp_path):
