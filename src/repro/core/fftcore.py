@@ -39,9 +39,12 @@ Local FFT "vendors":
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from repro.core import spans
@@ -174,8 +177,9 @@ def local_transform(x, axis: int, sign: int, spec: TransformSpec, *, n: int,
     here is axis-generic, so the batch rides for free).
 
     The work is named in the program (:mod:`repro.core.spans`): the
-    transform ``xform``, the pruning ``prune``, the c2r's spectrum
-    building and unpacking ``c2r_extend``.
+    transform ``xform`` (the dense c2r's ops too, under ``jit(irfft)``),
+    the pruning ``prune``, the spectrum building and unpacking around a
+    c2r's inverse FFT ``c2r_extend``.
     """
     axis = axis + nbatch
     if spec.kind == "c2c":
@@ -237,19 +241,93 @@ def _irfft(x, axis, n, impl, nbatch):
     pruned r2c axis's zero-pad, folded in).  The imaginary parts of the DC
     and Nyquist bins drop out, as in ``numpy.fft.irfft``.
 
-    Two real rows share one complex inverse FFT: the block is split in
-    halves A and B along the axis :func:`_c2r_split` picks, and
-    ``ifft(A_ext + i·B_ext)`` is ``a + i·b`` for the Hermitian extensions
-    of A and B.  A block with no such axis takes one inverse FFT per row
-    of its own extension.
+    The form is the shape's alone, whatever ``impl``: up to
+    :data:`_C2R_DFT_MAX_N` points (:func:`_c2r_dense`) the dense real DFT
+    from the kept bins (:func:`_c2r_dft`); past it the Hermitian extension
+    and a complex inverse FFT, two real rows to one (:func:`_c2r_packed`)
+    where the block has an axis to split them along (:func:`_c2r_split`),
+    else one per row (:func:`_c2r_full`).
 
     XLA's own IRFFT is not used: on a TPU v5e it returned a (384, 384,
     193) -> n=384 c2r along the last axis at relative L2 error 0.35, where
     the extension form gives 1.3e-7."""
+    if _c2r_dense(n):
+        return _c2r_dft(x, axis, n, nbatch)
     split = _c2r_split(x.shape, axis, nbatch)
     if split is None:
         return _c2r_full(x, axis, n, impl)
     return _c2r_packed(x, axis, n, impl, split)
+
+
+#: output length up to which the c2r is the dense real DFT.  On a TPU v5e
+#: it beat the packed inverse FFT at every n measured up to here, dealiased
+#: (n//3+1 kept bins) and unpruned (n//2+1), so at every m; its cost grows
+#: as n·m, the FFT's as n log n, and past here nothing was measured
+#: (PERF.md, the crossover table)
+_C2R_DFT_MAX_N = 1024
+
+
+def _c2r_dense(n):
+    """Whether the c2r to ``n`` points is the dense DFT from the kept bins."""
+    return n <= _C2R_DFT_MAX_N
+
+
+@spans.under("xform")
+def _c2r_dft(x, axis, n, nbatch=0):
+    """The c2r as a dense real inverse DFT from the kept bins: one
+    contraction of their real and imaginary parts side by side with
+    :func:`_c2r_dft_matrix`, with no spectrum of ``n`` bins ever built."""
+    return irfft(x, axis=axis, n=n, nbatch=nbatch)
+
+
+#: the MXU's width: the DFT's contraction is padded to whole passes of it
+_MXU_WIDTH = 128
+
+
+@functools.partial(jax.jit, static_argnames=("axis", "n", "nbatch"))
+def irfft(x, *, axis, n, nbatch):
+    """The real inverse DFT of :func:`_c2r_dft`, its own jitted function:
+    the program names its ops ``jit(irfft)``, the inverse real FFT they
+    compute.  The contraction runs on the MXU at HIGHEST precision (the
+    chip's default float32 dot is one bf16 pass).
+
+    Two choices keep every output bit independent of what else the block
+    holds.  The ``nbatch`` leading axes are the dot's batch axes, so a
+    field's rows go through the same dot as when it is transformed alone.
+    The kept bins past DC are zero-padded to whole MXU passes, so the bins
+    a pruned axis drops and the zeros an unpruned spectrum holds there add
+    the same exact zeros to the same contraction."""
+    m = x.shape[axis]
+    weights = _c2r_dft_matrix(n, m)
+    pads = [(0, 0, 0)] * x.ndim
+    pads[axis] = (0, weights.shape[0] // 2 - m, 0)
+    zero = jnp.zeros((), jnp.float32)
+    z = jnp.concatenate([lax.pad(jnp.real(x), zero, pads), lax.pad(jnp.imag(x), zero, pads)],
+                        axis=axis)
+    batch = tuple(range(nbatch))
+    y = lax.dot_general(z, jnp.broadcast_to(weights, x.shape[:nbatch] + weights.shape),
+                        (((axis,), (nbatch,)), (batch, batch)),
+                        precision=lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
+    # the batch axes, the other free axes of ``x`` in order, then the n points
+    return jnp.moveaxis(y, -1, axis)
+
+
+@functools.lru_cache(maxsize=None)
+def _c2r_dft_matrix(n, m):
+    """The weights of :func:`irfft` for ``m`` kept bins to ``n`` points,
+    computed in float64 and rounded once: ``w_k cos(2πjk/n)/n`` in row
+    ``k`` and ``-w_k sin(2πjk/n)/n`` in row ``r + k``, for the kept bins
+    ``k < m`` and ``r`` rows a half (DC and whole MXU passes), the rest
+    zero.  ``w_k`` is 2, and 1 for DC and for the Nyquist bin of an even
+    ``n``, whose imaginary parts drop out (their sine rows are zero)."""
+    rows = 1 + -(-(m - 1) // _MXU_WIDTH) * _MXU_WIDTH
+    k = np.arange(rows)[:, None]
+    phase = 2 * np.pi * ((k * np.arange(n)[None, :]) % n) / n
+    edge = (k == 0) | (2 * k == n)
+    w = np.where(k < m, np.where(edge, 1.0, 2.0) / n, 0.0)
+    cos = w * np.cos(phase)
+    sin = np.where(edge, 0.0, -w * np.sin(phase))
+    return np.concatenate([cos, sin]).astype(np.float32)
 
 
 def _c2r_split(shape, axis, nbatch):

@@ -7,11 +7,13 @@ Every op a plan emits sits under one direction scope, ``pfft.fwd`` or
 no single stage: the guard's bracket around the whole plan, or an exchange
 called on its own).  The kinds:
 
-``xform``      the 1-D transform proper (FFT, its inverse, DCT/DST pre- and
-               post-processing, the four-step kernel);
+``xform``      the 1-D transform proper (FFT, its inverse, the c2r's dense
+               real DFT, DCT/DST pre- and post-processing, the four-step
+               kernel);
 ``prune``      the truncated spectrum's keep and zero-scatter, the r2c keep
                (its zero-pad is the c2r's);
-``c2r_extend`` the c2r's work around its inverse FFT: building the
+``c2r_extend`` the c2r's work around its inverse FFT, where it takes one
+               (more kept bins than the dense DFT's bound): building the
                spectrum from the kept bins (the folded zero-pad, the
                Hermitian mirror, the packing of two real rows into one
                complex row) and unpacking the result (real and imaginary

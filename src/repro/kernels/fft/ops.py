@@ -2,9 +2,10 @@
 
 ``fft_matmul(x, axis, inverse)``   — complex-to-complex, any axis.
 ``rfft_matmul(x, axis)``           — real input, Hermitian-reduced output
-                                     (its inverse is ``fftcore``'s c2r, a
-                                     Hermitian extension + inverse
-                                     ``fft_matmul``).
+                                     (its inverse is ``fftcore``'s c2r: a
+                                     dense real DFT from the kept bins, or
+                                     past its bound a Hermitian extension
+                                     + inverse ``fft_matmul``).
 
 Factorization policy (``plan_factors``): N = n1·n2 with n1 ≥ n2, both as
 close to √N (and MXU-friendly multiples of 8/128) as possible; prime or tiny

@@ -73,8 +73,8 @@ def test_rfft_irfft_matmul(n):
 @pytest.mark.parametrize("impl", ["jnp", "matmul"])
 @pytest.mark.parametrize("n", [12, 384, 385])
 def test_c2r_matches_numpy_irfft(impl, n):
-    """The plan's c2r (Hermitian extension + inverse complex DFT) equals
-    numpy's irfft, including dropping the imaginary parts of the DC and
+    """The plan's c2r (whichever form its shape takes) equals numpy's
+    irfft, including dropping the imaginary parts of the DC and
     Nyquist bins of a non-Hermitian input, on a non-last axis."""
     rng = np.random.default_rng(n)
     shape = (n // 2 + 1, 3, 5)
@@ -85,6 +85,19 @@ def test_c2r_matches_numpy_irfft(impl, n):
     want = np.fft.irfft(spec.astype(np.complex128), n=n, axis=0)
     assert got.shape == want.shape and got.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-5)
+
+
+def _extension_c2r(x, axis, n, impl, nbatch=0):
+    """The c2r past the dense form's bound, at any shape: the Hermitian
+    extension and a complex inverse FFT, packed where the block has an axis
+    to split (``fftcore._c2r_split``), else one per row."""
+    from repro.core import fftcore
+
+    axis += nbatch
+    split = fftcore._c2r_split(x.shape, axis, nbatch)
+    if split is None:
+        return fftcore._c2r_full(x, axis, n, impl)
+    return fftcore._c2r_packed(x, axis, n, impl, split)
 
 
 #: (other axes with the c2r axis at ``axis``, n, n_keep, axis, nbatch,
@@ -109,12 +122,12 @@ C2R_CASES = {
 @pytest.mark.parametrize("impl", ["jnp", "matmul"])
 @pytest.mark.parametrize("case", sorted(C2R_CASES))
 def test_packed_c2r_matches_numpy_irfft(impl, case):
-    """The c2r packs two real rows into one complex inverse FFT where the
-    block has a spatial axis of even length besides the c2r axis (the
-    outermost; batch axes are never split), and falls back to one per row
-    where it has none: both equal numpy's irfft of the kept bins
-    zero-padded to ``n//2+1``, on a non-Hermitian spectrum (complex DC and
-    Nyquist bins)."""
+    """Past the dense form's bound the c2r packs two real rows into one
+    complex inverse FFT where the block has a spatial axis of even length
+    besides the c2r axis (the outermost; batch axes are never split), and
+    falls back to one per row where it has none: both equal numpy's irfft
+    of the kept bins zero-padded to ``n//2+1``, on a non-Hermitian spectrum
+    (complex DC and Nyquist bins)."""
     from repro.core import fftcore
 
     others, n, n_keep, axis, nbatch, split = C2R_CASES[case]
@@ -125,8 +138,7 @@ def test_packed_c2r_matches_numpy_irfft(impl, case):
     rng = np.random.default_rng(len(case) * n)
     spec = (rng.standard_normal(shape) + 1j * rng.standard_normal(shape)
             ).astype(np.complex64)
-    got = local_transform(jnp.asarray(spec), axis, BACKWARD, TransformSpec.r2c(n_keep),
-                          n=n, impl=impl, nbatch=nbatch)
+    got = _extension_c2r(jnp.asarray(spec), axis, n, impl, nbatch)
     pads = [(0, 0)] * spec.ndim
     pads[axis + nbatch] = (0, n // 2 + 1 - m)
     want = np.fft.irfft(np.pad(spec.astype(np.complex128), pads), n=n, axis=axis + nbatch)
@@ -146,8 +158,7 @@ def test_c2r_keeps_each_fields_precision(impl, others):
     spec = rng.standard_normal(shape) + 1j * rng.standard_normal(shape)
     spec[1] *= 1e-5
     spec = spec.astype(np.complex64)
-    got = np.asarray(local_transform(jnp.asarray(spec), 2, BACKWARD, TransformSpec.r2c(),
-                                     n=n, impl=impl, nbatch=1))
+    got = np.asarray(_extension_c2r(jnp.asarray(spec), 2, n, impl, nbatch=1))
     want = np.fft.irfft(spec.astype(np.complex128), n=n, axis=3)
     for f in range(2):
         err = np.linalg.norm(got[f] - want[f]) / np.linalg.norm(want[f])
@@ -164,8 +175,7 @@ def test_packed_c2r_drops_edge_imaginary_parts(impl, n):
     spec = np.zeros((2, m), np.complex64)
     spec[0, 0] = 1.5 + 2.0j
     spec[0, m - 1] = -0.5 + 3.0j  # Nyquist for even n, an ordinary bin for odd
-    got = np.asarray(local_transform(jnp.asarray(spec), 1, BACKWARD, TransformSpec.r2c(),
-                                     n=n, impl=impl))
+    got = np.asarray(_extension_c2r(jnp.asarray(spec), 1, n, impl))
     want = np.fft.irfft(spec.astype(np.complex128), n=n, axis=1)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
     assert np.abs(got[1]).max() < 1e-6

@@ -145,12 +145,15 @@ def test_every_op_of_an_exchange_sits_under_its_kind(compiled, case):
                 assert kind == "a2a", (direction, name, op_name)
             if case == "pruned-r2c":
                 # the pruning is static slices: no gather, no loop, and each
-                # slice, concatenate or pad is the pruning's, the c2r's
-                # Hermitian extension's or the padded axis's, under its kind
+                # slice, concatenate or pad is the pruning's, the padded
+                # axis's or the dense c2r's own (its kept bins, inside
+                # ``jit(irfft)``), under its kind
                 assert opcode not in ("gather", "while"), (direction, name, op_name)
                 assert not op_name.endswith("/gather"), (direction, name, op_name)
                 if op_name.split("/")[-1] in ("slice", "concatenate", "pad"):
-                    assert kind in ("prune", "c2r_extend", "repad"), (direction, name, op_name)
+                    allowed = ("prune", "repad") + (
+                        ("xform",) if "jit(irfft)" in op_name.split("/") else ())
+                    assert kind in allowed, (direction, name, op_name)
                     pruned += kind == "prune"
             if op_name and opcode != "constant" and re.match(r"\(?(bf16|s8)\[", rtype):
                 # the narrow wire payload (the CPU compiler's own widening
@@ -172,10 +175,14 @@ def test_every_op_of_an_exchange_sits_under_its_kind(compiled, case):
             assert kinds.get("prune", 0) > 0
             assert pruned > 0, direction
             if direction == "backward":
-                assert kinds.get("c2r_extend", 0) > 0
+                # the c2r is the dense DFT (5 kept bins to 12 points): no
+                # spectrum is built around an inverse FFT
+                assert kinds.get("c2r_extend", 0) == 0
+                assert any("jit(irfft)" in op.split("/") and innermost_kind(op) == "xform"
+                           for *_, op in instructions(text))
                 # the slice to the r2c axis's logical extent sits under
                 # ``repad`` in the program as traced; compiled, it merges
-                # into the packed c2r's slices of the kept bins
+                # into the dense c2r's slices of the kept bins
                 assert re.search(r"stage\d+\.repad/slice", compiled["lowered"])
             else:
                 assert kinds.get("repad", 0) > 0

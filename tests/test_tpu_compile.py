@@ -189,43 +189,88 @@ def _cost(compiled):
     return cost[0] if isinstance(cost, list) else cost
 
 
-def test_packed_c2r_halves_the_work(one_chip):
+@pytest.fixture(scope="module")
+def dns_c2r(one_chip):
     """The DNS cell's 9-field c2r block, (9, 384, 384, 129) kept bins to
-    n = 384 along the last axis: packed two rows to a complex inverse FFT,
-    it costs at most 0.55x the FLOPs of one inverse FFT per row of the
-    full Hermitian extension, in fewer temporaries."""
+    n = 384 along the last axis, compiled in each form: the plan's (dense),
+    packed two rows to a complex inverse FFT, and one inverse FFT per row."""
     from repro.core import fftcore
 
     x = jax.ShapeDtypeStruct((9, 384, 384, 129), jnp.complex64, sharding=one_chip)
-    packed = jax.jit(lambda b: fftcore._irfft(b, 3, 384, "jnp", 1)).lower(x).compile()
-    full = jax.jit(lambda b: fftcore._c2r_full(b, 3, 384, "jnp")).lower(x).compile()
+    forms = {"plan": lambda b: fftcore._irfft(b, 3, 384, "jnp", 1),
+             "packed": lambda b: fftcore._c2r_packed(b, 3, 384, "jnp", 1),
+             "full": lambda b: fftcore._c2r_full(b, 3, 384, "jnp")}
+    lowered = {k: jax.jit(f).lower(x) for k, f in forms.items()}
+    return {"lowered": {k: v.as_text() for k, v in lowered.items()},
+            "compiled": {k: v.compile() for k, v in lowered.items()}}
+
+
+def test_packed_c2r_halves_the_work(dns_c2r):
+    """Packed two rows to a complex inverse FFT, the DNS block's c2r costs
+    at most 0.55x the FLOPs of one inverse FFT per row of the full
+    Hermitian extension, in fewer temporaries."""
+    packed, full = dns_c2r["compiled"]["packed"], dns_c2r["compiled"]["full"]
     assert _cost(packed)["flops"] <= 0.55 * _cost(full)["flops"]
     assert (packed.memory_analysis().temp_size_in_bytes
             < full.memory_analysis().temp_size_in_bytes)
 
 
+def test_dense_c2r_builds_no_spectrum(dns_c2r):
+    """The DNS block's c2r takes the dense real DFT: no inverse FFT as
+    traced, no ``reverse`` and no ``concatenate`` once compiled (no
+    Hermitian mirror, no 384-bin spectrum), and fewer temporaries than the
+    packed form."""
+    from test_spans import instructions
+
+    dense = dns_c2r["compiled"]["plan"]
+    assert "stablehlo.fft" not in dns_c2r["lowered"]["plan"]
+    assert "stablehlo.fft" in dns_c2r["lowered"]["packed"]
+    opcodes = {opcode for _, _, opcode, _ in instructions(dense.as_text())}
+    assert "convolution" in opcodes
+    assert not opcodes & {"reverse", "concatenate", "fft"}, opcodes
+    assert (dense.memory_analysis().temp_size_in_bytes
+            < dns_c2r["compiled"]["packed"].memory_analysis().temp_size_in_bytes)
+
+
 @pytest.mark.parametrize("direction", ["backward", "forward"])
 def test_c2c_plan_has_no_c2r_extend(one_chip, direction):
     """A pure c2c plan, compiled for the chip, holds no op under
-    ``c2r_extend``; the dealiased plan's backward does."""
+    ``c2r_extend``, and neither does the dealiased plan, whose backward's
+    c2r is the dense DFT: its dots sit under ``jit(irfft)`` in ``xform``
+    and are classed ``fft``.  A plan whose r2c axis is longer than the
+    dense form's bound builds its c2r spectrum under ``c2r_extend``."""
     from test_spans import innermost_kind, instructions
 
+    from bench.tracereduce import opcode_class
+    from repro.core import fftcore
     from repro.core.fftcore import TransformSpec
     from repro.core.meshutil import make_mesh
     from repro.core.pfft import ParallelFFT
 
+    past = fftcore._C2R_DFT_MAX_N + 2
     mesh = make_mesh((1, 1), ("p0", "p1"), devices=list(one_chip.device_set))
-    kinds = {}
-    for name, m, transforms in (
-            ("c2c", 32, (TransformSpec.c2c(),) * 3),
-            ("dealiased", 48, (TransformSpec.pruned(32), TransformSpec.pruned(32),
-                               TransformSpec.r2c(17)))):
-        plan = ParallelFFT(mesh, (m, m, m), ("p0", "p1"), transforms=transforms)
+    kinds, dots = {}, {}
+    for name, shape, transforms in (
+            ("c2c", (32, 32, 32), (TransformSpec.c2c(),) * 3),
+            ("dealiased", (48, 48, 48), (TransformSpec.pruned(32), TransformSpec.pruned(32),
+                                         TransformSpec.r2c(17))),
+            ("past", (8, 8, past), (TransformSpec.c2c(), TransformSpec.c2c(),
+                                    TransformSpec.r2c(past // 3 + 1)))):
+        plan = ParallelFFT(mesh, shape, ("p0", "p1"), transforms=transforms)
         pen, dt = ((plan.input_pencil, plan.input_dtype) if direction == "forward"
                    else (plan.output_pencil, plan.spectral_dtype))
         x = jax.ShapeDtypeStruct((3, *pen.physical), dt, sharding=pen.batched_sharding(1))
         text = jax.jit(getattr(plan, direction)).lower(x).compile().as_text()
-        kinds[name] = {innermost_kind(op) for *_, op in instructions(text)}
+        ops = list(instructions(text))
+        kinds[name] = {innermost_kind(op) for *_, op in ops}
+        dots[name] = [(innermost_kind(op), opcode_class(opcode, op_name=op))
+                      for _, _, opcode, op in ops
+                      if opcode == "convolution" and "jit(irfft)" in op.split("/")]
     assert "xform" in kinds["c2c"]
-    assert "c2r_extend" not in kinds["c2c"]
-    assert ("c2r_extend" in kinds["dealiased"]) == (direction == "backward")
+    assert "c2r_extend" not in kinds["c2c"] | kinds["dealiased"]
+    assert ("c2r_extend" in kinds["past"]) == (direction == "backward")
+    assert not dots["c2c"] and not dots["past"]
+    if direction == "backward":
+        assert dots["dealiased"] and set(dots["dealiased"]) == {("xform", "fft")}
+    else:
+        assert not dots["dealiased"]
